@@ -22,7 +22,7 @@ from .utils.log import Log
 # EX_TEMPFAIL (75) = a peer died; restarting the job auto-resumes from
 # the last checkpoint.  EX_IOERR (74) = a collective or the distributed
 # bootstrap timed out with peers apparently alive (lost collective,
-# dead tunnel) — also retryable, but worth alerting on.
+# blackholed link) — also retryable, but worth alerting on.
 EXIT_PEER_FAILURE = 75
 EXIT_NET_TIMEOUT = 74
 
@@ -351,18 +351,13 @@ def _net_exit(code: int) -> int:
     shutdown barrier blocks ~100 s against the dead peer and then kills
     the process with a fatal log — so exit through ``net.hard_exit``.
     Single-process (bootstrap timeouts) returns normally."""
-    try:
-        from jax._src import distributed as _dist
+    from .parallel import net
 
-        from .parallel.net import hard_exit
+    if net._client() is not None:
+        import jax
 
-        if _dist.global_state.client is not None:
-            import jax
-
-            if jax.process_count() > 1:
-                hard_exit(code)  # never returns
-    except Exception:  # pragma: no cover - private-API drift tolerated
-        pass
+        if jax.process_count() > 1:
+            net.hard_exit(code)  # never returns
     return code
 
 

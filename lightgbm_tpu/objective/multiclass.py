@@ -7,6 +7,7 @@ indexing reshaped; the softmax runs across the class axis on device.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -57,8 +58,12 @@ class MulticlassSoftmax(ObjectiveFunction):
         the raw class index; returns ((K, n), (K, n))."""
         p = jnp.exp(scores - jnp.max(scores, axis=0, keepdims=True))
         p = p / jnp.sum(p, axis=0, keepdims=True)
-        classes = jnp.arange(self.num_class, dtype=jnp.float32)
-        onehot = (label.reshape(1, -1) == classes[:, None]).astype(jnp.float32)
+        # runs inside the fused update kernel: Mosaic's iota is integer and
+        # at least 2-D (a 1-D float arange is refused: "'tpu.iota' op
+        # result must be vector of integer")
+        classes = jax.lax.broadcasted_iota(
+            jnp.int32, (self.num_class, 1), 0).astype(jnp.float32)
+        onehot = (label.reshape(1, -1) == classes).astype(jnp.float32)
         onehot = onehot.reshape(p.shape)
         grad = p - onehot
         hess = 2.0 * p * (1.0 - p)

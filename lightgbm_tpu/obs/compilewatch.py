@@ -8,7 +8,7 @@ provides two layers:
 
 1. A process-global compile counter fed by ``jax.monitoring`` duration
    events (``/jax/core/compile/backend_compile_duration`` fires once per
-   XLA backend compilation, on every jax version we target).  Each
+   XLA backend compilation, persistent-cache hits included).  Each
    compile also lands in the trace as a ``jax_compile`` event.
 
 2. ``JitWatch`` — a wrapper for jitted entry points that tracks the
@@ -31,7 +31,13 @@ from typing import Any, Dict
 
 from ..utils.log import Log
 
-_counts = {"backend_compiles": 0, "backend_compile_secs": 0.0}
+_counts = {"backend_compiles": 0, "backend_compile_secs": 0.0,
+           "cache_hits": 0, "cache_misses": 0}
+# persistent compilation cache outcomes (jax/_src/compiler.py): a hit is
+# a compile request answered from the cache directory, a miss is one
+# that compiled and was written there
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_misses"}
 _installed = False
 _watches = []
 
@@ -44,7 +50,14 @@ def install() -> None:
     import jax.monitoring
 
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
     _installed = True
+
+
+def _on_event(name: str, **kwargs) -> None:
+    key = _CACHE_EVENTS.get(name)
+    if key is not None:
+        _counts[key] += 1
 
 
 def _on_duration(name: str, secs: float, **kwargs) -> None:
@@ -67,6 +80,8 @@ def snapshot() -> Dict[str, Any]:
     return {
         "backend_compiles": _counts["backend_compiles"],
         "backend_compile_secs": round(_counts["backend_compile_secs"], 3),
+        "cache_hits": _counts["cache_hits"],
+        "cache_misses": _counts["cache_misses"],
         "watched": {
             w.name: {
                 "calls": w.calls,
@@ -124,46 +139,33 @@ class JitWatch:
         install()
         _watches.append(self)
 
-    def _cache_size(self):
-        cs = getattr(self._fn, "_cache_size", None)
-        if cs is None:
-            return None
-        try:
-            return cs()
-        except Exception:  # pragma: no cover - jax internals moved
-            return None
-
     def __call__(self, *args, **kwargs):
-        from jax.core import trace_state_clean
+        from jax.core import trace_ctx
 
         # called while an OUTER jit is tracing: this program is inlined
         # into the caller's jaxpr — no backend compile happens here, and
         # the cache bookkeeping below would misread the outer trace's
         # state.  Call straight through (the module-level kernel watches
         # in ops/pgrow.py and ops/histogram.py hit this constantly).
-        if not trace_state_clean():
+        if not trace_ctx.is_top_level():
             return self._fn(*args, **kwargs)
         with self._lock:
             return self._call_locked(args, kwargs)
 
     def _call_locked(self, args, kwargs):
         self.calls += 1
-        before = self._cache_size()
+        before = self._fn._cache_size()
         # a shrunken cache means jax.clear_caches() (or a backend
         # teardown) emptied the jit cache out from under us: every seen
         # signature will legitimately compile again, so the seen set is
         # from a dead cache lifetime — forget it instead of flagging the
         # whole re-warm as retraces
-        if before is not None and before < self._last_cache_size:
+        if before < self._last_cache_size:
             self._sigs.clear()
         csecs0 = _counts["backend_compile_secs"]
         out = self._fn(*args, **kwargs)
-        if before is None:
-            return out
-        after = self._cache_size()
-        if after is not None:
-            self._last_cache_size = after
-        if after is not None and after > before:
+        after = self._last_cache_size = self._fn._cache_size()
+        if after > before:
             self.compiles += 1
             sig = _sig_of(args, kwargs)
             from .trace import tracer

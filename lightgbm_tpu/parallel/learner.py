@@ -22,12 +22,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # JAX >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from ..ops.grow import GrowParams, GrowResult, grow_tree
 
@@ -39,16 +35,12 @@ def make_mesh(n_devices: Optional[int] = None) -> Mesh:
     return Mesh(np.array(devs[:d]), ("data",))
 
 
-def _shard_map_compat(fn, mesh, in_specs, out_specs):
+def _shard_map_unchecked(fn, mesh, in_specs, out_specs):
     """shard_map with replication checking off (the grower's collective
     results are replicated by construction; the checker can't always
     prove it)."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-    except TypeError:  # older kwarg name
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False)
+    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False)
 
 
 class ShardedLearner:
@@ -109,7 +101,7 @@ class ShardedLearner:
             rec_internal_value=P(),
         )
         self._fn = jax.jit(
-            _shard_map_compat(body, mesh, in_specs, out_specs)
+            _shard_map_unchecked(body, mesh, in_specs, out_specs)
         )
         self._row_sharded = row_sharded
         self._rep_consts = None  # cached replicated meta/hyper (multi-process)

@@ -603,6 +603,9 @@ class PredictServer(ThreadingHTTPServer):
             "raw_batcher": self.raw_batcher.stats(),
             "compiles": {
                 "backend_compiles": cw["backend_compiles"],
+                "backend_compile_secs": cw["backend_compile_secs"],
+                "cache_hits": cw["cache_hits"],
+                "cache_misses": cw["cache_misses"],
                 "predict_calls": watched.get("calls", 0),
                 "predict_compiles": watched.get("compiles", 0),
                 "predict_retraces": watched.get("retraces", 0),
@@ -1045,10 +1048,14 @@ def make_server(model_path: Optional[str] = None, host: str = "127.0.0.1",
 
 def main(argv: List[str]) -> int:
     """``python -m lightgbm_tpu serve model=... [key=value ...]``."""
+    from .. import enable_compile_cache
     from ..cli import parse_argv
 
     tracer.refresh_from_env()
     faults.refresh_from_env()  # LIGHTGBM_TPU_SERVE_FAULT chaos drills
+    # the bucket-ladder warm-up below is the whole start-up cost: keep
+    # its programs across server starts
+    enable_compile_cache()
     params = parse_argv(argv)
     model_path = params.get("model") or params.get("input_model")
     registry_dir = params.get("registry")

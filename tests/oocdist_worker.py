@@ -53,7 +53,6 @@ from lightgbm_tpu.parallel.distributed import ensure_initialized  # noqa: E402
 assert ensure_initialized() is True
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
 assert jax.process_count() == nproc
 
 import numpy as np  # noqa: E402

@@ -17,6 +17,7 @@ import lightgbm_tpu as lgb
 from lightgbm_tpu.obs import costmodel, report
 from lightgbm_tpu.obs.compilewatch import JitWatch
 from lightgbm_tpu.obs.trace import Tracer
+from lightgbm_tpu.utils.log import LightGBMError
 
 
 # pf/pb chosen so the arithmetic is checkable by hand: ridge AI = 10
@@ -84,10 +85,10 @@ class TestPeakSpecs:
         assert spec["flops_per_s"] == pytest.approx(197e12)
         assert costmodel.resolve_peak_spec("TPU v4")["key"] == "tpu v4"
 
-    def test_unknown_kind_falls_back_to_cpu(self):
-        spec = costmodel.resolve_peak_spec("Weird FPGA rev7")
-        assert spec["key"] == "cpu"
-        assert spec["device_kind"] == "Weird FPGA rev7"
+    def test_unknown_kind_raises(self):
+        # a device that is not in the table is an error, never the cpu row
+        with pytest.raises(LightGBMError, match="no such chip"):
+            costmodel.resolve_peak_spec("no such chip")
 
     def test_env_override_merges_and_marks_source(self, monkeypatch):
         monkeypatch.setenv(
@@ -337,7 +338,15 @@ class TestReportCostsCli:
         assert report.costs_main(["/no/such/trace.jsonl"]) == 1
         assert report.costs_main([]) == 2
 
-    def test_main_dispatches_costs(self, tmp_path, capsys):
+    def test_unknown_device_is_an_error(self, tmp_path, capsys):
+        # the trace's device kind ("synthetic") has no peak-spec row
+        assert report.costs_main([self._write_trace(tmp_path)]) == 1
+        assert "synthetic" in capsys.readouterr().err
+
+    def test_main_dispatches_costs(self, tmp_path, capsys, monkeypatch):
+        monkeypatch.setenv(
+            "LIGHTGBM_TPU_PEAK_SPECS",
+            '{"synthetic": {"flops_per_s": 100, "hbm_bytes_per_s": 10}}')
         assert report.main(["costs", self._write_trace(tmp_path)]) == 0
         assert "cost-model report" in capsys.readouterr().out
 
@@ -389,11 +398,10 @@ class TestTraceRotation:
 class TestBenchTrend:
     def _write_rounds(self, d):
         docs = {
-            # ungated first capture, dead-tunnel fallback
+            # ungated first capture
             "BENCH_r1.json": {"n": 1, "rc": 0, "parsed": {
                 "metric": "train.s_per_iter", "value": 2.0, "unit": "s",
-                "vs_baseline": 1.0, "device": "cpu",
-                "backend_fallback": True}},
+                "vs_baseline": 1.0, "device": "TPU v4"}},
             # gated and passing
             "BENCH_r2.json": {"n": 2, "rc": 0, "parsed": {
                 "metric": "train.s_per_iter", "value": 1.0, "unit": "s",
@@ -424,7 +432,7 @@ class TestBenchTrend:
             "BENCH_r4.json"]
         t = report.bench_trend_summary(rounds)
         r1, r2, r3, r4 = t["rounds"]
-        assert r1["gate_verdict"] == "-" and r1["backend_fallback"]
+        assert r1["gate_verdict"] == "-"
         assert r2["gate_verdict"] == "pass"
         assert r3["parsed"] is False and r3["rc"] == 1
         assert r4["gate_verdict"] == "FAIL:s_per_iter,comms_payload"
@@ -439,7 +447,6 @@ class TestBenchTrend:
         assert report.bench_trend_main([d]) == 0
         out = capsys.readouterr().out
         assert "bench trend" in out
-        assert "[fallback]" in out
         assert "trend [train.s_per_iter]" in out
         assert "best r2" in out
         assert report.main(["bench-trend", d, "--json"]) == 0

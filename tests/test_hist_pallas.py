@@ -105,6 +105,31 @@ class TestHistSegment:
                                    rtol=1e-6, atol=1e-6)
 
 
+class TestHistSegmentQ:
+    """Quantized twin: exact int32 sums of int16 levels (the kernel
+    carries them as bf16-exact base-256 digits — Mosaic has no int32
+    matmul), extremes of the level range included."""
+
+    @pytest.mark.parametrize("qmax", [15, 16383])
+    def test_exact_vs_numpy(self, qmax):
+        n, f, b, lo, hi = 4096, 11, 32, 100, 3900
+        rng = np.random.default_rng(3)
+        bins = rng.integers(0, b, size=(n, f), dtype=np.uint8)
+        qg = rng.integers(-qmax, qmax + 1, n).astype(np.int32)
+        qh = rng.integers(0, 2 * qmax + 2, n).astype(np.int32)
+        qg[:4] = [-qmax, qmax, -qmax - 1, 0]
+        sel = (rng.random(n) < 0.8).astype(np.int32)
+        P = hp.pack_columns_q(jnp.asarray(bins), jnp.asarray(qg),
+                              jnp.asarray(qh), jnp.asarray(sel))
+        got = np.asarray(hp.hist_segment_q(P, jnp.int32(lo), jnp.int32(hi),
+                                           f, b, interpret=INTERP))
+        want = np.zeros((f, b, 3), np.int64)
+        for j in range(f):
+            for c, v in enumerate((qg * sel, qh * sel, sel)):
+                np.add.at(want[j, :, c], bins[lo:hi, j], v[lo:hi])
+        np.testing.assert_array_equal(got, want)
+
+
 class TestHistSegments:
     """Multi-leaf variant: one launch covers all active leaves."""
 

@@ -243,12 +243,11 @@ class TestKvGather:
         assert out == [b"from-rank-0", b"from-rank-1"]
 
     def test_empty_blob_roundtrip(self):
-        # barrier payloads are b""; the KV frame keeps values >= 2 bytes
-        # (jaxlib's bytes API segfaults below that)
+        # barrier payloads are b""
         c = FakeClient()
-        net._kv_put(c, "k", b"")
-        assert len(c.store["k"]) >= 2
-        assert net._kv_get(c, "k", 100) == b""
+        net.configure(deadline_s=2.0)
+        net._kv_put_payload(c, 0, 1, "ltpu_collect/0/1", b"", 2.0, "test")
+        assert net.kv_gather(0, b"", client=c, rank=0, nproc=2) == [b"", b""]
 
     def test_lazy_gc_deletes_own_previous_uid(self):
         c = FakeClient()

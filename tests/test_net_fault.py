@@ -108,8 +108,7 @@ def test_sigkill_mid_allgather_detected_by_all_survivors(tmp_path):
 @pytest.mark.faultinject
 @pytest.mark.netfault
 def test_bootstrap_timeout_is_loud_and_bounded(tmp_path):
-    """Nothing listens at the coordinator address (the BENCH_r05 dead
-    tunnel): the watchdogged initialize must raise a typed timeout
+    """Nothing listens at the coordinator address: the watchdogged initialize must raise a typed timeout
     within the retry budget instead of hanging forever."""
     out = str(tmp_path / "i")
     port = _free_port()  # bound+closed: nothing will ever listen

@@ -30,15 +30,22 @@ def _digests():
         return json.load(fh)
 
 
+def _recorded():
+    import jax
+
+    return (f" (goldens recorded under {_digests()['_recorded_under']}, "
+            f"running jax {jax.__version__})")
+
+
 @pytest.mark.parametrize("name", sorted(lib.BOOSTER_CONFIGS))
 def test_booster_config_parity(name, tmp_path):
     audit_path = str(tmp_path / f"{name}.audit.jsonl")
     model, trail = lib.run_booster_config(name, audit_path)
     want = _digests()[name]
     assert hashlib.sha256(model.encode()).hexdigest() == \
-        want["model_sha256"], f"{name}: model bytes drifted vs pre-refactor"
+        want["model_sha256"], f"{name}: model bytes drifted" + _recorded()
     assert hashlib.sha256(trail).hexdigest() == want["audit_sha256"], \
-        f"{name}: split-decision audit trail drifted vs pre-refactor"
+        f"{name}: split-decision audit trail drifted" + _recorded()
     # the user-facing check the issue names: `report diff` over the
     # golden trail and this run's trail must say identical (rc 0)
     proc = subprocess.run(
@@ -55,7 +62,7 @@ def test_booster_config_parity(name, tmp_path):
 def test_hostlearner_parity(mode):
     got = lib.run_hostlearner_mode(mode)
     assert got == _digests()[f"hostlearner_{mode}"]["grow_sha256"], \
-        f"hostlearner {mode}: GrowResult bytes drifted vs pre-refactor"
+        f"hostlearner {mode}: GrowResult bytes drifted" + _recorded()
 
 
 def test_model_bytes_match_golden_files():
