@@ -793,17 +793,23 @@ class GBDT:
             self.scores = scores_orig[None, :] if K == 1 else scores_orig
             fence(self.scores)
         chunk_trees = [[] for _ in range(K)]
-        for t in range(n_done):
-            for k in range(K):
-                view = pt.grow_result_view(recs, t, k)
-                if int(view.num_splits) > 0:
-                    tree = Tree.from_grow_result(view, self.train_set)
-                    tree.shrinkage(self.shrinkage_rate)
-                    audit.record_tree(self.iter + t, k, view, tree)
-                    chunk_trees[k].append(tree)
-                else:
-                    tree = Tree(2)  # empty tree, kept for class alignment
-                self.models.append(tree)
+        # host work between two chunk programs; `splits` is what the
+        # trace's readers divide the replay's launches by
+        counts = ({"trees": n_done * K,
+                   "splits": int(recs["num_splits"][:n_done].sum())}
+                  if tracer.enabled and n_done > 0 else {})
+        with tracer.span("trees_from_records", **counts):
+            for t in range(n_done):
+                for k in range(K):
+                    view = pt.grow_result_view(recs, t, k)
+                    if int(view.num_splits) > 0:
+                        tree = Tree.from_grow_result(view, self.train_set)
+                        tree.shrinkage(self.shrinkage_rate)
+                        audit.record_tree(self.iter + t, k, view, tree)
+                        chunk_trees[k].append(tree)
+                    else:
+                        tree = Tree(2)  # empty tree, kept for class alignment
+                    self.models.append(tree)
         # valid scores advance ONCE per chunk per class: a single stacked
         # predict_binned over all of the chunk's trees (vs one dispatch
         # per tree; per-dispatch cost not measured on this machine)

@@ -32,11 +32,16 @@ matrix, so nothing is ever gathered back to original row order during
 training; the original-order score vectors are rebuilt ONCE per chunk
 (one scatter per class through the rowid channel) for metrics/eval.
 
-Why every channel write goes through a Pallas kernel: ANY XLA-level
-write to the 64 MB matrix — even a one-element ``.at[].set`` on a
-donated loop carry — was seen to trigger a whole-array copy (retired
-runtime; not re-measured on this machine); only
-``input_output_aliases`` mutate truly in place.
+Why every channel write goes through a Pallas kernel: an XLA-level
+write to the packed matrix copies all of it; only
+``input_output_aliases`` mutate truly in place.  That keeps THIS file
+free of such copies, and the compiled program says so: of the six
+static sites at which the 21M-row chunk program copies the whole matrix
+(``JitWatch.phase_map()["matrix_copies"]``, compiled for the v5e, PR
+26), none sits in a phase of this file — three are in the grower's
+replay, two in its level phase (ops/pgrow.py has the per-site account)
+and one in the no-op branch a stopped chunk takes.  The canonical
+reorder's ``dynamic_update_slice`` below writes in place.
 
 Row-order-free semantics this relies on: histograms, leaf statistics and
 elementwise objectives are permutation-invariant.  Ranking objectives
@@ -67,6 +72,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import JitWatch, fence, tracer
+from ..obs.phases import (
+    CANON_REORDER,
+    CHUNK_EPILOGUE,
+    LEAF_DELTA,
+    SAMPLE,
+    SCORE_ADD,
+    UPDATE_ROOT_HIST,
+)
 from ..ops.pgrow import (
     BundleMeta,
     PGrowParams,
@@ -354,30 +367,32 @@ class PartitionedTrainer:
                 # below to original rows).  The positional carries
                 # (pending delta, rollback snapshot) are re-mapped
                 # through the SAME rowid so they stay aligned.
-                rowid = p[lay.ROWID, :n]
-                delta = jnp.zeros((n,), jnp.float32).at[rowid].set(delta)
-                last_kept = jnp.zeros((n,), jnp.float32).at[rowid].set(last_kept)
-                inv = jnp.zeros((n,), jnp.int32).at[rowid].set(
-                    jnp.arange(n, dtype=jnp.int32))
-                p = jax.lax.dynamic_update_slice(
-                    p, jnp.take(p[:, :n], inv, axis=1), (0, 0))
+                with jax.named_scope(CANON_REORDER):
+                    rowid = p[lay.ROWID, :n]
+                    delta = jnp.zeros((n,), jnp.float32).at[rowid].set(delta)
+                    last_kept = jnp.zeros((n,), jnp.float32).at[rowid].set(last_kept)
+                    inv = jnp.zeros((n,), jnp.int32).at[rowid].set(
+                        jnp.arange(n, dtype=jnp.int32))
+                    p = jax.lax.dynamic_update_slice(
+                        p, jnp.take(p[:, :n], inv, axis=1), (0, 0))
                 # disjoint purpose-tagged key streams: fold a purpose
                 # constant (0=bagging, 1=feature, 2=GOSS) before the
                 # iteration number so no two draws share a subkey
-                if bag_on:
-                    bkey = jax.random.fold_in(
-                        jax.random.fold_in(key, 0), it // bag_freq
-                    )
-                    sel = jax.random.bernoulli(bkey, bag_frac, (n,)).astype(jnp.float32)
-                else:
-                    sel = None
-                if used_features < F:
-                    fkey = jax.random.fold_in(jax.random.fold_in(key, 1), it)
-                    u = jax.random.uniform(fkey, (F,))
-                    _, idx = jax.lax.top_k(u, used_features)
-                    fmask = jnp.zeros((F,), jnp.float32).at[idx].set(1.0)
-                else:
-                    fmask = jnp.ones((F,), jnp.float32)
+                with jax.named_scope(SAMPLE):
+                    if bag_on:
+                        bkey = jax.random.fold_in(
+                            jax.random.fold_in(key, 0), it // bag_freq
+                        )
+                        sel = jax.random.bernoulli(bkey, bag_frac, (n,)).astype(jnp.float32)
+                    else:
+                        sel = None
+                    if used_features < F:
+                        fkey = jax.random.fold_in(jax.random.fold_in(key, 1), it)
+                        u = jax.random.uniform(fkey, (F,))
+                        _, idx = jax.lax.top_k(u, used_features)
+                        fmask = jnp.zeros((F,), jnp.float32).at[idx].set(1.0)
+                    else:
+                        fmask = jnp.ones((F,), jnp.float32)
 
                 ns_t = recs["num_splits"][t]
                 raw_t = recs["raw"][t]
@@ -391,31 +406,34 @@ class PartitionedTrainer:
                         # + a Bernoulli sample of the rest up-weighted
                         # into g/h, then the real pass computes the root
                         # histogram of the selected/scaled gradients.
-                        p, _ = update_and_root_hist(
-                            p, lay, grad_fn, delta=delta,
-                            num_rows=n, num_features=G, num_bins=BH,
-                            bits=params.bits, with_hist=False,
-                            interpret=interpret,
-                        )
-                        gv = _i2f(p[lay.G, :n])
-                        hv = _i2f(p[lay.H, :n])
-                        gscore = jnp.abs(gv * hv)
-                        _, top_idx = jax.lax.top_k(gscore, top_cnt)
-                        is_top = jnp.zeros((n,), bool).at[top_idx].set(True)
-                        gkey = jax.random.fold_in(jax.random.fold_in(key, 2), it)
-                        sampled = (~is_top) & (
-                            jax.random.uniform(gkey, (n,)) < goss_prob
-                        )
-                        warm = it < goss_warm
-                        selv = jnp.where(
-                            warm, 1.0, (is_top | sampled).astype(jnp.float32)
-                        )
-                        mulv = jnp.where(warm | (~sampled), 1.0, goss_mult)
-                        p, root_hist = update_and_root_hist(
-                            p, lay, grad_fn, sel=selv, mul=mulv,
-                            num_rows=n, num_features=G, num_bins=BH,
-                            bits=params.bits, interpret=interpret,
-                        )
+                        with jax.named_scope(UPDATE_ROOT_HIST):
+                            p, _ = update_and_root_hist(
+                                p, lay, grad_fn, delta=delta,
+                                num_rows=n, num_features=G, num_bins=BH,
+                                bits=params.bits, with_hist=False,
+                                interpret=interpret,
+                            )
+                        with jax.named_scope(SAMPLE):
+                            gv = _i2f(p[lay.G, :n])
+                            hv = _i2f(p[lay.H, :n])
+                            gscore = jnp.abs(gv * hv)
+                            _, top_idx = jax.lax.top_k(gscore, top_cnt)
+                            is_top = jnp.zeros((n,), bool).at[top_idx].set(True)
+                            gkey = jax.random.fold_in(jax.random.fold_in(key, 2), it)
+                            sampled = (~is_top) & (
+                                jax.random.uniform(gkey, (n,)) < goss_prob
+                            )
+                            warm = it < goss_warm
+                            selv = jnp.where(
+                                warm, 1.0, (is_top | sampled).astype(jnp.float32)
+                            )
+                            mulv = jnp.where(warm | (~sampled), 1.0, goss_mult)
+                        with jax.named_scope(UPDATE_ROOT_HIST):
+                            p, root_hist = update_and_root_hist(
+                                p, lay, grad_fn, sel=selv, mul=mulv,
+                                num_rows=n, num_features=G, num_bins=BH,
+                                bits=params.bits, interpret=interpret,
+                            )
                         delta = jnp.zeros((n,), jnp.float32)
                     else:
                         # in-place channel refresh (score += previous
@@ -425,11 +443,12 @@ class PartitionedTrainer:
                         # the previous iteration: the row layout did not
                         # change in between, so it applies against the
                         # current partition order.
-                        p, root_hist = update_and_root_hist(
-                            p, lay, grad_fn, delta=delta, sel=sel,
-                            num_rows=n, num_features=G, num_bins=BH,
-                            bits=params.bits, interpret=interpret,
-                        )
+                        with jax.named_scope(UPDATE_ROOT_HIST):
+                            p, root_hist = update_and_root_hist(
+                                p, lay, grad_fn, delta=delta, sel=sel,
+                                num_rows=n, num_features=G, num_bins=BH,
+                                bits=params.bits, interpret=interpret,
+                            )
                     tree, p = grow_tree_partitioned(
                         p, fmask, meta, hyper, params, bmeta=bmeta,
                         interpret=interpret, root_hist=root_hist,
@@ -440,13 +459,14 @@ class PartitionedTrainer:
                     # stored model.  Once an iteration produces an empty
                     # tree, training has logically stopped and later
                     # in-program iterations must not touch the scores.
-                    keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
-                    lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
-                    delta = segment_values(tree, n, keep * lval)
-                    # rollback needs the last KEPT tree's delta: an empty
-                    # tree zeroes the pending carry but must not clobber
-                    # what rollback_last would subtract
-                    last_kept = jnp.where(keep > 0, delta, last_kept)
+                    with jax.named_scope(LEAF_DELTA):
+                        keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
+                        lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
+                        delta = segment_values(tree, n, keep * lval)
+                        # rollback needs the last KEPT tree's delta: an empty
+                        # tree zeroes the pending carry but must not clobber
+                        # what rollback_last would subtract
+                        last_kept = jnp.where(keep > 0, delta, last_kept)
                     any_split = tree.num_splits > 0
                     ns_t = ns_t.at[0].set(tree.num_splits)
                     raw_t = raw_t.at[0].set(tree.recs_raw)
@@ -458,11 +478,12 @@ class PartitionedTrainer:
                     # IMMEDIATELY after the tree (while its partition
                     # layout is still current), which the precomputed
                     # gradient planes make snapshot-safe.
-                    p, hists = update_multi_and_hists(
-                        p, lay, grad_all_fn, sel=sel, num_rows=n,
-                        num_features=G, num_bins=BH, bits=params.bits,
-                        interpret=interpret,
-                    )
+                    with jax.named_scope(UPDATE_ROOT_HIST):
+                        p, hists = update_multi_and_hists(
+                            p, lay, grad_all_fn, sel=sel, num_rows=n,
+                            num_features=G, num_bins=BH, bits=params.bits,
+                            interpret=interpret,
+                        )
                     any_split = jnp.array(False)
                     for k in range(K):
                         tree, p = grow_tree_partitioned(
@@ -470,11 +491,13 @@ class PartitionedTrainer:
                             interpret=interpret, root_hist=hists[k],
                             rows=lay.class_rows(k),
                         )
-                        keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
-                        lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
-                        dk = segment_values(tree, n, keep * lval)
-                        p = score_add(p, lay, dk, k, num_rows=n,
-                                      interpret=interpret)
+                        with jax.named_scope(LEAF_DELTA):
+                            keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
+                            lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
+                            dk = segment_values(tree, n, keep * lval)
+                        with jax.named_scope(SCORE_ADD):
+                            p = score_add(p, lay, dk, k, num_rows=n,
+                                          interpret=interpret)
                         any_split = any_split | (tree.num_splits > 0)
                         ns_t = ns_t.at[k].set(tree.num_splits)
                         raw_t = raw_t.at[k].set(tree.recs_raw)
@@ -500,26 +523,27 @@ class PartitionedTrainer:
             p, recs, _, last_delta, last_kept = jax.lax.fori_loop(
                 0, jnp.minimum(t_run, T), one_iter, carry0
             )
-            if K == 1:
-                # settle the last tree's delta into the channel so the
-                # score channel is consistent at chunk boundaries (the
-                # in-loop update applies tree t-1's delta at iteration
-                # t).  Score-only band stream: the old settle ran a full
-                # update_and_root_hist — a whole-matrix pass plus an
-                # F*B histogram that was discarded — purely to add the
-                # delta.  The g/h channels stay stale until the next
-                # chunk's first update pass recomputes them from the
-                # settled scores (nothing reads them in between; the
-                # checkpoint exports scores + perm, never g/h).
-                p = score_add(p, lay, last_delta, 0, num_rows=n,
-                              interpret=interpret)
-            # original-order scores for eval (K scatters per chunk)
-            rowid = p[lay.ROWID, :n]
-            outs = []
-            for k in range(K):
-                sc = _i2f(p[lay.SCORE + k, :n])
-                outs.append(jnp.zeros((n,), jnp.float32).at[rowid].set(sc))
-            scores_orig = outs[0] if K == 1 else jnp.stack(outs)
+            with jax.named_scope(CHUNK_EPILOGUE):
+                if K == 1:
+                    # settle the last tree's delta into the channel so the
+                    # score channel is consistent at chunk boundaries (the
+                    # in-loop update applies tree t-1's delta at iteration
+                    # t).  Score-only band stream: the old settle ran a full
+                    # update_and_root_hist — a whole-matrix pass plus an
+                    # F*B histogram that was discarded — purely to add the
+                    # delta.  The g/h channels stay stale until the next
+                    # chunk's first update pass recomputes them from the
+                    # settled scores (nothing reads them in between; the
+                    # checkpoint exports scores + perm, never g/h).
+                    p = score_add(p, lay, last_delta, 0, num_rows=n,
+                                  interpret=interpret)
+                # original-order scores for eval (K scatters per chunk)
+                rowid = p[lay.ROWID, :n]
+                outs = []
+                for k in range(K):
+                    sc = _i2f(p[lay.SCORE + k, :n])
+                    outs.append(jnp.zeros((n,), jnp.float32).at[rowid].set(sc))
+                scores_orig = outs[0] if K == 1 else jnp.stack(outs)
             return p, recs, scores_orig, last_kept
 
         return prog
@@ -565,8 +589,15 @@ class PartitionedTrainer:
                     self.p, jnp.float32(lr), self._base_key,
                     jnp.int32(iter0 + n_done), jnp.int32(step),
                 )
+            # chunk_program wraps an asynchronous dispatch and
+            # records_fetch absorbs the wait for it (readers take their
+            # sum); nested inside, each right alone: the device's run
+            # (fenced only when tracing) and the copy to the host
             with tracer.span("records_fetch"):
-                part = jax.device_get(recs)
+                with tracer.span("device_wait"):
+                    fence(recs)
+                with tracer.span("records_d2h"):
+                    part = jax.device_get(recs)
             ns = part["num_splits"][:step]  # (step, K)
             stop = np.nonzero(np.all(ns == 0, axis=1))[0]
             done_here = int(stop[0]) if stop.size else step
@@ -1302,24 +1333,25 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                 # validity must travel WITH the row: split_stream permutes
                 # shard columns, so padding is identified by the preserved
                 # ROWID channel (local rowid >= nreal), never by position
-                valid = (p[lay.ROWID, :nl] < nreal).astype(jnp.float32)
-                if bag_on:
-                    bkey = jax.random.fold_in(
-                        jax.random.fold_in(
-                            jax.random.fold_in(key, 0), it // bag_freq
-                        ), ax
-                    )
-                    sel = jax.random.bernoulli(bkey, bag_frac, (nl,)).astype(jnp.float32)
-                    sel = sel * valid
-                else:
-                    sel = None
-                if used_features < F:
-                    fkey = jax.random.fold_in(jax.random.fold_in(key, 1), it)
-                    u = jax.random.uniform(fkey, (F,))
-                    _, idx = jax.lax.top_k(u, used_features)
-                    fmask = jnp.zeros((F,), jnp.float32).at[idx].set(1.0)
-                else:
-                    fmask = jnp.ones((F,), jnp.float32)
+                with jax.named_scope(SAMPLE):
+                    valid = (p[lay.ROWID, :nl] < nreal).astype(jnp.float32)
+                    if bag_on:
+                        bkey = jax.random.fold_in(
+                            jax.random.fold_in(
+                                jax.random.fold_in(key, 0), it // bag_freq
+                            ), ax
+                        )
+                        sel = jax.random.bernoulli(bkey, bag_frac, (nl,)).astype(jnp.float32)
+                        sel = sel * valid
+                    else:
+                        sel = None
+                    if used_features < F:
+                        fkey = jax.random.fold_in(jax.random.fold_in(key, 1), it)
+                        u = jax.random.uniform(fkey, (F,))
+                        _, idx = jax.lax.top_k(u, used_features)
+                        fmask = jnp.zeros((F,), jnp.float32).at[idx].set(1.0)
+                    else:
+                        fmask = jnp.ones((F,), jnp.float32)
 
                 ns_t = recs["num_splits"][t]
                 raw_t = recs["raw"][t]
@@ -1329,64 +1361,70 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                         # (histogram-free pass), then local top-k +
                         # Bernoulli rest-sample (goss.hpp:126-198 over
                         # the shard's rows)
-                        p, _ = update_and_root_hist(
-                            p, lay, grad_fn, delta=delta, num_rows=nl,
-                            num_features=G, num_bins=BH, bits=params.bits,
-                            with_hist=False, interpret=interpret,
-                        )
-                        gv = _i2f(p[lay.G, :nl])
-                        hv = _i2f(p[lay.H, :nl])
-                        gscore = jnp.abs(gv * hv) * valid
-                        top_c = jnp.maximum(jnp.floor(top_rate * nreal), 1.0)
-                        other_c = jnp.maximum(jnp.floor(other_rate * nreal), 1.0)
-                        goss_mult = (nreal - top_c) / other_c
-                        goss_prob = other_c / jnp.maximum(nreal - top_c, 1.0)
-                        # exactly top_c rows marked top via the top_k
-                        # INDICES (ADVICE r5: a >= threshold test admits
-                        # every tie — common with integer features — and
-                        # can never admit zero-gradient rows, so the
-                        # nominal-count goss_mult was biased).  Padding
-                        # rows are pushed below every valid row so ties
-                        # at zero resolve to real rows first.
-                        topc_i = jnp.clip(top_c.astype(jnp.int32), 1, top_cnt_max)
-                        _, top_idx = jax.lax.top_k(
-                            jnp.where(valid > 0, gscore, -1.0), top_cnt_max
-                        )
-                        rank_ok = jnp.arange(top_cnt_max) < topc_i
-                        is_top = (jnp.zeros((nl,), bool).at[top_idx].set(rank_ok)
-                                  & (valid > 0))
-                        gkey = jax.random.fold_in(
-                            jax.random.fold_in(jax.random.fold_in(key, 2), it), ax
-                        )
-                        sampled = ((~is_top)
-                                   & (jax.random.uniform(gkey, (nl,)) < goss_prob)
-                                   & (valid > 0))
-                        warm = it < goss_warm
-                        selv = jnp.where(
-                            warm, valid, (is_top | sampled).astype(jnp.float32)
-                        )
-                        mulv = jnp.where(warm | (~sampled), 1.0, goss_mult)
-                        p, root_hist = update_and_root_hist(
-                            p, lay, grad_fn, sel=selv, mul=mulv,
-                            num_rows=nl, num_features=G, num_bins=BH,
-                            bits=params.bits, interpret=interpret,
-                        )
+                        with jax.named_scope(UPDATE_ROOT_HIST):
+                            p, _ = update_and_root_hist(
+                                p, lay, grad_fn, delta=delta, num_rows=nl,
+                                num_features=G, num_bins=BH, bits=params.bits,
+                                with_hist=False, interpret=interpret,
+                            )
+                        with jax.named_scope(SAMPLE):
+                            gv = _i2f(p[lay.G, :nl])
+                            hv = _i2f(p[lay.H, :nl])
+                            gscore = jnp.abs(gv * hv) * valid
+                            top_c = jnp.maximum(jnp.floor(top_rate * nreal), 1.0)
+                            other_c = jnp.maximum(jnp.floor(other_rate * nreal), 1.0)
+                            goss_mult = (nreal - top_c) / other_c
+                            goss_prob = other_c / jnp.maximum(nreal - top_c, 1.0)
+                            # exactly top_c rows marked top via the top_k
+                            # INDICES (ADVICE r5: a >= threshold test admits
+                            # every tie — common with integer features — and
+                            # can never admit zero-gradient rows, so the
+                            # nominal-count goss_mult was biased).  Padding
+                            # rows are pushed below every valid row so ties
+                            # at zero resolve to real rows first.
+                            topc_i = jnp.clip(top_c.astype(jnp.int32), 1, top_cnt_max)
+                            _, top_idx = jax.lax.top_k(
+                                jnp.where(valid > 0, gscore, -1.0), top_cnt_max
+                            )
+                            rank_ok = jnp.arange(top_cnt_max) < topc_i
+                            is_top = (jnp.zeros((nl,), bool).at[top_idx].set(rank_ok)
+                                      & (valid > 0))
+                            gkey = jax.random.fold_in(
+                                jax.random.fold_in(jax.random.fold_in(key, 2), it), ax
+                            )
+                            sampled = ((~is_top)
+                                       & (jax.random.uniform(gkey, (nl,)) < goss_prob)
+                                       & (valid > 0))
+                            warm = it < goss_warm
+                            selv = jnp.where(
+                                warm, valid, (is_top | sampled).astype(jnp.float32)
+                            )
+                            mulv = jnp.where(warm | (~sampled), 1.0, goss_mult)
+                        with jax.named_scope(UPDATE_ROOT_HIST):
+                            p, root_hist = update_and_root_hist(
+                                p, lay, grad_fn, sel=selv, mul=mulv,
+                                num_rows=nl, num_features=G, num_bins=BH,
+                                bits=params.bits, interpret=interpret,
+                            )
                         delta = jnp.zeros((nl,), jnp.float32)
                     else:
-                        p, root_hist = update_and_root_hist(
-                            p, lay, grad_fn, delta=delta, sel=sel, num_rows=nl,
-                            num_features=G, num_bins=BH, bits=params.bits,
-                            interpret=interpret,
-                        )
-                    root_hist = jax.lax.psum(root_hist, "data")
+                        with jax.named_scope(UPDATE_ROOT_HIST):
+                            p, root_hist = update_and_root_hist(
+                                p, lay, grad_fn, delta=delta, sel=sel, num_rows=nl,
+                                num_features=G, num_bins=BH, bits=params.bits,
+                                interpret=interpret,
+                            )
+                    with jax.named_scope(UPDATE_ROOT_HIST):
+                        root_hist = jax.lax.psum(root_hist, "data")
                     tree, p = grow_tree_partitioned(
                         p, fmask, meta, hyper, params, bmeta=bmeta,
                         interpret=interpret, root_hist=root_hist,
                     )
-                    keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
-                    lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
-                    delta = segment_values(tree, nl, keep * lval)
-                    last_kept = jnp.where(keep > 0, delta, last_kept)
+                    with jax.named_scope(LEAF_DELTA):
+                        keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
+                        lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
+                        delta = segment_values(tree, nl, keep * lval)
+                        last_kept = jnp.where(keep > 0, delta, last_kept)
                     any_split = tree.num_splits > 0
                     ns_t = ns_t.at[0].set(tree.num_splits)
                     raw_t = raw_t.at[0].set(tree.recs_raw)
@@ -1395,12 +1433,13 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                     # class's delta lands on its score row immediately
                     # after its tree (mirrors the serial K > 1 branch,
                     # with per-level hist psums inside the grower)
-                    p, hists = update_multi_and_hists(
-                        p, lay, grad_all_fn, sel=sel, num_rows=nl,
-                        num_features=G, num_bins=BH, bits=params.bits,
-                        interpret=interpret,
-                    )
-                    hists = jax.lax.psum(hists, "data")
+                    with jax.named_scope(UPDATE_ROOT_HIST):
+                        p, hists = update_multi_and_hists(
+                            p, lay, grad_all_fn, sel=sel, num_rows=nl,
+                            num_features=G, num_bins=BH, bits=params.bits,
+                            interpret=interpret,
+                        )
+                        hists = jax.lax.psum(hists, "data")
                     any_split = jnp.array(False)
                     for k in range(K):
                         tree, p = grow_tree_partitioned(
@@ -1408,11 +1447,13 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                             interpret=interpret, root_hist=hists[k],
                             rows=lay.class_rows(k),
                         )
-                        keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
-                        lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
-                        dk = segment_values(tree, nl, keep * lval)
-                        p = score_add(p, lay, dk, k, num_rows=nl,
-                                      interpret=interpret)
+                        with jax.named_scope(LEAF_DELTA):
+                            keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
+                            lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
+                            dk = segment_values(tree, nl, keep * lval)
+                        with jax.named_scope(SCORE_ADD):
+                            p = score_add(p, lay, dk, k, num_rows=nl,
+                                          interpret=interpret)
                         any_split = any_split | (tree.num_splits > 0)
                         ns_t = ns_t.at[k].set(tree.num_splits)
                         raw_t = raw_t.at[k].set(tree.recs_raw)
@@ -1434,17 +1475,18 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
             p, recs, _, last_delta, last_kept = jax.lax.fori_loop(
                 0, jnp.minimum(t_run, T), one_iter, carry0
             )
-            if K == 1:
-                # score-only chunk-end settle (see the serial trainer)
-                p = score_add(p, lay, last_delta, 0, num_rows=nl,
-                              interpret=interpret)
-            rowid = p[lay.ROWID, :nl]
-            scores_local = jnp.stack([
-                jnp.zeros((nl,), jnp.float32).at[rowid].set(
-                    _i2f(p[lay.SCORE + k, :nl])
-                )
-                for k in range(K)
-            ])  # (K, nl)
+            with jax.named_scope(CHUNK_EPILOGUE):
+                if K == 1:
+                    # score-only chunk-end settle (see the serial trainer)
+                    p = score_add(p, lay, last_delta, 0, num_rows=nl,
+                                  interpret=interpret)
+                rowid = p[lay.ROWID, :nl]
+                scores_local = jnp.stack([
+                    jnp.zeros((nl,), jnp.float32).at[rowid].set(
+                        _i2f(p[lay.SCORE + k, :nl])
+                    )
+                    for k in range(K)
+                ])  # (K, nl)
             return p[None], recs, scores_local, last_kept
 
         mapped = self._shard_map(
@@ -1505,8 +1547,15 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                     self.p, self._nreal_global, jnp.float32(lr), self._base_key,
                     jnp.int32(iter0 + n_done), jnp.int32(step),
                 )
+            # chunk_program wraps an asynchronous dispatch and
+            # records_fetch absorbs the wait for it (readers take their
+            # sum); nested inside, each right alone: the device's run
+            # (fenced only when tracing) and the copy to the host
             with tracer.span("records_fetch"):
-                part = jax.device_get(recs)
+                with tracer.span("device_wait"):
+                    fence(recs)
+                with tracer.span("records_d2h"):
+                    part = jax.device_get(recs)
             ns = part["num_splits"][:step]  # (step, K)
             stop = np.nonzero(np.all(ns == 0, axis=1))[0]
             done_here = int(stop[0]) if stop.size else step

@@ -94,6 +94,44 @@ def snapshot() -> Dict[str, Any]:
     }
 
 
+def phase_maps(modules=None) -> list:
+    """The phase map (``JitWatch.phase_map``) of every watched program that
+    has compiled; ``modules`` keeps only the programs whose XLA module name
+    (``jit_prog``) is in it.  On demand only: each map lowers and compiles
+    its program again (a load, with the persistent compile cache), so this
+    is for after a run, never for the training path."""
+    maps = []
+    for w in list(_watches):
+        if modules is not None and w.module not in modules:
+            continue
+        try:
+            maps.append(w.phase_map())
+        except Exception as e:  # one program that will not lower hides no other
+            Log.warning("phase map failed for %s: %s", w.name, e)
+    return [m for m in maps if m is not None]
+
+
+def _abstract(args, kwargs):
+    """(args, kwargs) with every array leaf replaced by its
+    ``ShapeDtypeStruct`` (a sharding over a mesh kept, since it is part of
+    the program) and every other leaf (static values, Python scalars) as
+    it was: what ``lower`` needs to rebuild the same program, in a few
+    hundred bytes and holding no buffer."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    def leaf(x):
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        sharding = getattr(x, "sharding", None)
+        if isinstance(sharding, SingleDeviceSharding):
+            sharding = None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding,
+                                    weak_type=getattr(x, "weak_type", False))
+
+    return jax.tree_util.tree_map(leaf, (args, kwargs))
+
+
 def _sig_of(args, kwargs):
     """Array signature: (shape, dtype, sharding) per array leaf;
     non-array leaves are deliberately EXCLUDED so a cache key that
@@ -132,6 +170,8 @@ class JitWatch:
         self.retraces = 0
         self._sigs = set()
         self._last_cache_size = 0
+        # abstract (args, kwargs) of the last compile, for phase_map()
+        self._compiled_spec = None
         # serialize calls so a concurrent caller's compile can't land
         # inside another caller's before/after window and read as that
         # caller's (false) retrace — the serving batchers share one watch
@@ -168,6 +208,7 @@ class JitWatch:
         if after > before:
             self.compiles += 1
             sig = _sig_of(args, kwargs)
+            self._compiled_spec = _abstract(args, kwargs)
             from .trace import tracer
 
             if sig in self._sigs:
@@ -188,6 +229,25 @@ class JitWatch:
                     args, kwargs,
                     _counts["backend_compile_secs"] - csecs0, sig)
         return out
+
+    @property
+    def module(self) -> str:
+        """The XLA module name of the wrapped program, as a profiler
+        trace's ``XLA Modules`` line and the compiled text have it."""
+        return "jit_" + getattr(self._fn, "__name__", "")
+
+    def phase_map(self) -> Dict[str, Any]:
+        """``{"name", "module", "matrix", "ops": {instruction: phase}}`` of
+        the program as last compiled (obs/phases.py): lowered and compiled
+        again from the remembered abstract signature, and its text parsed.
+        None before the first compile."""
+        if self._compiled_spec is None:
+            return None
+        from .phases import parse_hlo_phases
+
+        args, kwargs = self._compiled_spec
+        text = self._fn.lower(*args, **kwargs).compile().as_text()
+        return {"name": self.name, **parse_hlo_phases(text)}
 
     def _record_cost(self, args, kwargs, compile_secs, sig=None):
         """First compile per signature: scrape HLO cost/memory analysis

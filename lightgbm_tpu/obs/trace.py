@@ -20,6 +20,7 @@ Record schema (all records carry ``ev`` and ``ts`` = time.time()):
   {"ev":"span", "name":..., "dur_s":..., "depth":..., "parent":..., ...attrs}
   {"ev":"counter"|"gauge", "name":..., "value":..., ...attrs}
   {"ev":"event", "name":..., ...attrs}
+  {"ev":"program", "name":..., "module":..., "matrix":..., "ops":{instr: phase}}
   {"ev":"iter", "iter":i, "wall_s":..., "phases":{name: secs},
    "compiles":n, "host_rss_mb":..., "dev_mb":..., ...fields}
 
@@ -53,6 +54,8 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+# the program's spans on a profiler trace: "lgbm:<span name>"
+ANNOTATION_PREFIX = "lgbm:"
 
 
 def _max_bytes_from_env() -> int:
@@ -83,7 +86,14 @@ _FLIGHT = None
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "attrs", "_t0")
+    """An enabled span.  Besides its JSONL record it is a
+    ``jax.profiler.TraceAnnotation("lgbm:<name>")``: under a profiler
+    session the span lands on the device trace's own clock, so a reader of
+    the trace needs no wall-clock bridge to lay the program's host spans
+    over the device's idle gaps.  (Without a session the annotation is a
+    flag check.)"""
+
+    __slots__ = ("_tr", "name", "attrs", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tr = tr
@@ -91,12 +101,17 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        import jax
+
+        self._ann = jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + self.name)
+        self._ann.__enter__()
         self._tr._stack.append(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
         tr = self._tr
         stack = tr._stack
         if stack and stack[-1] is self.name:
@@ -319,6 +334,22 @@ class Tracer:
         rec = {"ev": "event", "name": name}
         rec.update(attrs)
         self._emit(rec)
+
+    def write_program_maps(self, modules=None) -> int:
+        """Write the phase map of every watched program that has compiled
+        (``compilewatch.phase_maps``) to the sink as ``{"ev": "program",
+        "name", "module", "matrix", "ops": {instruction: phase}}`` records,
+        and return how many.  For an operator who asks: it compiles each
+        program again, so nothing on the training path calls it, and
+        neither does ``close()``."""
+        if not self.enabled:
+            return 0
+        from . import compilewatch
+
+        maps = compilewatch.phase_maps(modules)
+        for m in maps:
+            self._emit({"ev": "program", **m})
+        return len(maps)
 
     # -- per-iteration records -----------------------------------------
     @contextlib.contextmanager

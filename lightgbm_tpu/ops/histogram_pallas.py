@@ -201,6 +201,7 @@ def hist_segment(
         out_shape=jax.ShapeDtypeStruct((fb, 7), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="hist_segment",
     )(lohi, p)
     # re-sum the 3-term splits: (sum_g, sum_h, count)
     hist = jnp.stack(
@@ -376,6 +377,7 @@ def hist_segments(
         ),
         out_shape=jax.ShapeDtypeStruct((smax, 8, fbp), jnp.float32),
         interpret=interpret,
+        name="hist_segments",
     )(sv, p)
     out = out[:, :, :fb]
     hist = jnp.stack(
@@ -501,6 +503,7 @@ def hist_segment_q(
         out_shape=jax.ShapeDtypeStruct((fb, 5), jnp.int32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="hist_segment_q",
     )(lohi, p)
     hist = jnp.stack(
         [out[:, 0] + (out[:, 1] << 8), out[:, 2] + (out[:, 3] << 8), out[:, 4]],

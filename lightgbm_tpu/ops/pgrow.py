@@ -27,6 +27,17 @@ Design notes (v2, measured on v5e):
   measured ~150 us/split tax.
 - Left/right split search runs as ONE vmapped call over the stacked
   (2, F, B, 3) children histograms.
+- Where the whole-matrix copies are (the top operation of every trace on
+  record, PERF.md section 5): the compiled 21M-row chunk program has six
+  static ``copy s32[16,21001024]`` sites (compiled for the v5e, PR 26;
+  ``JitWatch.phase_map()["matrix_copies"]``).  Three sit in the replay
+  (phase 2 below): one at the top of the ``while`` body before the
+  ``gain > 0`` conditional, one after it, and one in ``take_pre``, the
+  branch that returns ``p`` untouched — a conditional's result does not
+  alias its operand.  Two sit in the level phase's loop around
+  ``level_stream``; the sixth is in the chunk program's stopped no-op
+  branch and never runs while trees grow.  Launches and seconds per
+  site: PERF.md section 5.
 """
 
 from __future__ import annotations
@@ -39,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.compilewatch import JitWatch
+from ..obs.phases import LEVEL_PHASE, REPLAY, REPLAY_TAIL, UPDATE_ROOT_HIST
 from .histogram_pallas import hist_segments
 from .pkernels import (
     BLK,
@@ -251,159 +263,161 @@ def grow_tree_partitioned(
         res = jax.vmap(one)(hist2, sums2)
         return res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF))
 
-    if root_hist is None:
-        if levelwise:
-            # multi-leaf segmented histogram kernel (one launch covers a
-            # whole level's segments; the root is level 0's single
-            # segment) — bit-identical to hist_dyn: same per-block
-            # accumulation order, same fchunk tuning, same 3-term re-sum
-            seg0_tab = jnp.zeros((8, 2), jnp.int32).at[0, 1].set(n)
-            root_hist = hist_segments(
-                p, seg0_tab, 1, num_features=G, num_bins=BH,
-                bits=params.bits, rows=rows, smax=8, interpret=interpret,
-            )[0]
-        else:
-            root_hist = hist_dyn(p, 0, n, G, BH, bits=params.bits, rows=rows,
-                                 interpret=interpret)
-        if params.axis_name:
-            root_hist = jax.lax.psum(root_hist, params.axis_name)
-    # (callers passing root_hist in data-parallel mode psum it themselves)
-    root_sums = jnp.sum(root_hist[0], axis=0)  # (3,): totals via feature 0
-    rr = find2(jnp.stack([root_hist, root_hist]),
-               jnp.stack([root_sums, root_sums]), jnp.array(True))
+    with jax.named_scope(UPDATE_ROOT_HIST):
+        if root_hist is None:
+            if levelwise:
+                # multi-leaf segmented histogram kernel (one launch covers a
+                # whole level's segments; the root is level 0's single
+                # segment) — bit-identical to hist_dyn: same per-block
+                # accumulation order, same fchunk tuning, same 3-term re-sum
+                seg0_tab = jnp.zeros((8, 2), jnp.int32).at[0, 1].set(n)
+                root_hist = hist_segments(
+                    p, seg0_tab, 1, num_features=G, num_bins=BH,
+                    bits=params.bits, rows=rows, smax=8, interpret=interpret,
+                )[0]
+            else:
+                root_hist = hist_dyn(p, 0, n, G, BH, bits=params.bits, rows=rows,
+                                     interpret=interpret)
+            if params.axis_name:
+                root_hist = jax.lax.psum(root_hist, params.axis_name)
+        # (callers passing root_hist in data-parallel mode psum it themselves)
+        root_sums = jnp.sum(root_hist[0], axis=0)  # (3,): totals via feature 0
+        rr = find2(jnp.stack([root_hist, root_hist]),
+                   jnp.stack([root_sums, root_sums]), jnp.array(True))
 
-    root_val = leaf_output(root_sums[0], root_sums[1], hyper.lambda_l1, hyper.lambda_l2)
-    root_bs = jnp.stack([rr.gain[0], rr.feature[0].astype(jnp.float32),
-                         rr.threshold_bin[0].astype(jnp.float32),
-                         rr.default_bin_for_zero[0].astype(jnp.float32),
-                         rr.left_sum_g[0], rr.left_sum_h[0], rr.left_cnt[0],
-                         jnp.float32(0.0)])
-    root_leaf = jnp.stack([root_sums[0], root_sums[1], root_sums[2], root_val,
-                           root_sums[2], jnp.float32(0.0), jnp.float32(0.0),
-                           jnp.float32(0.0)])
-    seg0 = jnp.zeros((L, 2), jnp.int32).at[0, 1].set(n)
-    bs0 = jnp.full((L, 8), NEG_INF, jnp.float32).at[0].set(root_bs)
-    leaf0 = jnp.zeros((L, 8), jnp.float32).at[0].set(root_leaf)
+        root_val = leaf_output(root_sums[0], root_sums[1], hyper.lambda_l1, hyper.lambda_l2)
+        root_bs = jnp.stack([rr.gain[0], rr.feature[0].astype(jnp.float32),
+                             rr.threshold_bin[0].astype(jnp.float32),
+                             rr.default_bin_for_zero[0].astype(jnp.float32),
+                             rr.left_sum_g[0], rr.left_sum_h[0], rr.left_cnt[0],
+                             jnp.float32(0.0)])
+        root_leaf = jnp.stack([root_sums[0], root_sums[1], root_sums[2], root_val,
+                               root_sums[2], jnp.float32(0.0), jnp.float32(0.0),
+                               jnp.float32(0.0)])
+        seg0 = jnp.zeros((L, 2), jnp.int32).at[0, 1].set(n)
+        bs0 = jnp.full((L, 8), NEG_INF, jnp.float32).at[0].set(root_bs)
+        leaf0 = jnp.zeros((L, 8), jnp.float32).at[0].set(root_leaf)
 
     # ---- phase 1: level-batched expansion into candidate tables ------
-    if levelwise:
-        SMAX = min(-(-(L + 1) // 8) * 8, 512)
-        CANDMAX = 2 * SMAX
-        MAXLVL = params.max_levels
-        c_seg0 = jnp.zeros((CANDMAX, 2), jnp.int32).at[0, 1].set(n)
-        c_bs0 = jnp.full((CANDMAX, 8), NEG_INF, jnp.float32).at[0].set(root_bs)
-        c_leaf0 = jnp.zeros((CANDMAX, 8), jnp.float32).at[0].set(root_leaf)
-        c_childlo0 = jnp.full((CANDMAX,), -1, jnp.int32)
-        frontier0 = jnp.zeros((SMAX,), jnp.int32)  # slot 0 = root
+    with jax.named_scope(LEVEL_PHASE):
+        if levelwise:
+            SMAX = min(-(-(L + 1) // 8) * 8, 512)
+            CANDMAX = 2 * SMAX
+            MAXLVL = params.max_levels
+            c_seg0 = jnp.zeros((CANDMAX, 2), jnp.int32).at[0, 1].set(n)
+            c_bs0 = jnp.full((CANDMAX, 8), NEG_INF, jnp.float32).at[0].set(root_bs)
+            c_leaf0 = jnp.zeros((CANDMAX, 8), jnp.float32).at[0].set(root_leaf)
+            c_childlo0 = jnp.full((CANDMAX,), -1, jnp.int32)
+            frontier0 = jnp.zeros((SMAX,), jnp.int32)  # slot 0 = root
 
-        def lcond(s):
-            return (s[7] > 0) & (s[8] < MAXLVL)
+            def lcond(s):
+                return (s[7] > 0) & (s[8] < MAXLVL)
 
-        def lbody(s):
-            (p, c_seg, c_bs, c_leaf, c_childlo, cand_n, frontier,
-             frontier_n, level) = s
-            idx = jnp.arange(SMAX)
-            fvalid = idx < frontier_n
-            fslots = jnp.clip(frontier, 0, CANDMAX - 1)
-            gains = jnp.where(fvalid, c_bs[fslots, 0], NEG_INF)
-            active = gains > 0.0
-            # cap: children must fit both the frontier array and the
-            # candidate table; dropped nodes stay splittable via the
-            # phase-2 classic tail
-            n_act = jnp.minimum(jnp.sum(active.astype(jnp.int32)), SMAX // 2)
-            n_act = jnp.minimum(n_act, jnp.maximum((CANDMAX - cand_n) // 2, 0))
-            # compact active slots to the front (stable frontier order)
-            order = jnp.argsort(jnp.where(active, 0, 1), stable=True)
-            aslots = fslots[order]
-            arow = idx < n_act
-            segs = c_seg[aslots]  # (SMAX, 2)
-            bsr = c_bs[aslots]
-            feat = jnp.clip(bsr[:, 1].astype(jnp.int32), 0, F - 1)
-            thr = bsr[:, 2].astype(jnp.int32)
-            dbz = bsr[:, 3].astype(jnp.int32)
-            mrows = mtab[feat]
-            col = mrows[:, 2].astype(jnp.int32)
-            seg_tab = jnp.stack([
-                segs[:, 0], jnp.where(arow, segs[:, 1], 0),
-                col // per, (col % per) * params.bits,
-                mrows[:, 0].astype(jnp.int32), dbz, thr,
-                mrows[:, 1].astype(jnp.int32),
-                mrows[:, 3].astype(jnp.int32), mrows[:, 4].astype(jnp.int32),
-                mrows[:, 5].astype(jnp.int32), jnp.zeros_like(col),
-            ], axis=1)
-            p, nl, hists = level_stream(
-                p, seg_tab, n_act, num_features=G, num_bins=BH,
-                bits=params.bits, rows=rows, smax=SMAX, interpret=interpret,
-            )
-            if params.axis_name:
-                # ONE collective per level (vs per split): global children
-                # histograms keep the tree bit-identical on every device
-                hists = jax.lax.psum(
-                    jnp.where(arow[:, None, None], hists, 0.0), params.axis_name
+            def lbody(s):
+                (p, c_seg, c_bs, c_leaf, c_childlo, cand_n, frontier,
+                 frontier_n, level) = s
+                idx = jnp.arange(SMAX)
+                fvalid = idx < frontier_n
+                fslots = jnp.clip(frontier, 0, CANDMAX - 1)
+                gains = jnp.where(fvalid, c_bs[fslots, 0], NEG_INF)
+                active = gains > 0.0
+                # cap: children must fit both the frontier array and the
+                # candidate table; dropped nodes stay splittable via the
+                # phase-2 classic tail
+                n_act = jnp.minimum(jnp.sum(active.astype(jnp.int32)), SMAX // 2)
+                n_act = jnp.minimum(n_act, jnp.maximum((CANDMAX - cand_n) // 2, 0))
+                # compact active slots to the front (stable frontier order)
+                order = jnp.argsort(jnp.where(active, 0, 1), stable=True)
+                aslots = fslots[order]
+                arow = idx < n_act
+                segs = c_seg[aslots]  # (SMAX, 2)
+                bsr = c_bs[aslots]
+                feat = jnp.clip(bsr[:, 1].astype(jnp.int32), 0, F - 1)
+                thr = bsr[:, 2].astype(jnp.int32)
+                dbz = bsr[:, 3].astype(jnp.int32)
+                mrows = mtab[feat]
+                col = mrows[:, 2].astype(jnp.int32)
+                seg_tab = jnp.stack([
+                    segs[:, 0], jnp.where(arow, segs[:, 1], 0),
+                    col // per, (col % per) * params.bits,
+                    mrows[:, 0].astype(jnp.int32), dbz, thr,
+                    mrows[:, 1].astype(jnp.int32),
+                    mrows[:, 3].astype(jnp.int32), mrows[:, 4].astype(jnp.int32),
+                    mrows[:, 5].astype(jnp.int32), jnp.zeros_like(col),
+                ], axis=1)
+                p, nl, hists = level_stream(
+                    p, seg_tab, n_act, num_features=G, num_bins=BH,
+                    bits=params.bits, rows=rows, smax=SMAX, interpret=interpret,
                 )
-            lsums = bsr[:, 4:7]
-            tots = c_leaf[aslots][:, 0:3]
-            rsums = tots - lsums
-            cdepth = c_leaf[aslots][:, 5] + 1.0
-            hist_l = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=0))(hists)
-            hist_r = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=7))(hists)
-            hist2 = jnp.stack([hist_l, hist_r], axis=1)  # (SMAX, 2, G, BH, 3)
-            sums2 = jnp.stack([lsums, rsums], axis=1)  # (SMAX, 2, 3)
-            dok2 = (jnp.ones((SMAX, 2), bool) if params.max_depth <= 0
-                    else jnp.stack([cdepth < params.max_depth] * 2, axis=1))
-            res = jax.vmap(find2)(hist2, sums2, dok2)  # fields (SMAX, 2)
-            vals2 = leaf_output(sums2[..., 0], sums2[..., 1],
-                                hyper.lambda_l1, hyper.lambda_l2)  # (SMAX, 2)
-            il = jnp.where(arow, cand_n + 2 * idx, CANDMAX)
-            ir = jnp.where(arow, cand_n + 2 * idx + 1, CANDMAX)
-            seg_l = jnp.stack([segs[:, 0], nl], axis=1)
-            seg_r = jnp.stack([segs[:, 0] + nl, segs[:, 1] - nl], axis=1)
-            c_seg = (c_seg.at[il].set(seg_l, mode="drop")
-                     .at[ir].set(seg_r, mode="drop"))
+                if params.axis_name:
+                    # ONE collective per level (vs per split): global children
+                    # histograms keep the tree bit-identical on every device
+                    hists = jax.lax.psum(
+                        jnp.where(arow[:, None, None], hists, 0.0), params.axis_name
+                    )
+                lsums = bsr[:, 4:7]
+                tots = c_leaf[aslots][:, 0:3]
+                rsums = tots - lsums
+                cdepth = c_leaf[aslots][:, 5] + 1.0
+                hist_l = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=0))(hists)
+                hist_r = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=7))(hists)
+                hist2 = jnp.stack([hist_l, hist_r], axis=1)  # (SMAX, 2, G, BH, 3)
+                sums2 = jnp.stack([lsums, rsums], axis=1)  # (SMAX, 2, 3)
+                dok2 = (jnp.ones((SMAX, 2), bool) if params.max_depth <= 0
+                        else jnp.stack([cdepth < params.max_depth] * 2, axis=1))
+                res = jax.vmap(find2)(hist2, sums2, dok2)  # fields (SMAX, 2)
+                vals2 = leaf_output(sums2[..., 0], sums2[..., 1],
+                                    hyper.lambda_l1, hyper.lambda_l2)  # (SMAX, 2)
+                il = jnp.where(arow, cand_n + 2 * idx, CANDMAX)
+                ir = jnp.where(arow, cand_n + 2 * idx + 1, CANDMAX)
+                seg_l = jnp.stack([segs[:, 0], nl], axis=1)
+                seg_r = jnp.stack([segs[:, 0] + nl, segs[:, 1] - nl], axis=1)
+                c_seg = (c_seg.at[il].set(seg_l, mode="drop")
+                         .at[ir].set(seg_r, mode="drop"))
 
-            def bs_rows(k):
-                return jnp.stack([
-                    res.gain[:, k], res.feature[:, k].astype(jnp.float32),
-                    res.threshold_bin[:, k].astype(jnp.float32),
-                    res.default_bin_for_zero[:, k].astype(jnp.float32),
-                    res.left_sum_g[:, k], res.left_sum_h[:, k],
-                    res.left_cnt[:, k], jnp.zeros((SMAX,), jnp.float32),
-                ], axis=1)
+                def bs_rows(k):
+                    return jnp.stack([
+                        res.gain[:, k], res.feature[:, k].astype(jnp.float32),
+                        res.threshold_bin[:, k].astype(jnp.float32),
+                        res.default_bin_for_zero[:, k].astype(jnp.float32),
+                        res.left_sum_g[:, k], res.left_sum_h[:, k],
+                        res.left_cnt[:, k], jnp.zeros((SMAX,), jnp.float32),
+                    ], axis=1)
 
-            c_bs = (c_bs.at[il].set(bs_rows(0), mode="drop")
-                    .at[ir].set(bs_rows(1), mode="drop"))
+                c_bs = (c_bs.at[il].set(bs_rows(0), mode="drop")
+                        .at[ir].set(bs_rows(1), mode="drop"))
 
-            def leaf_rows(k):
-                z = jnp.zeros((SMAX,), jnp.float32)
-                return jnp.stack([
-                    sums2[:, k, 0], sums2[:, k, 1], sums2[:, k, 2],
-                    vals2[:, k], sums2[:, k, 2], cdepth, z, z,
-                ], axis=1)
+                def leaf_rows(k):
+                    z = jnp.zeros((SMAX,), jnp.float32)
+                    return jnp.stack([
+                        sums2[:, k, 0], sums2[:, k, 1], sums2[:, k, 2],
+                        vals2[:, k], sums2[:, k, 2], cdepth, z, z,
+                    ], axis=1)
 
-            c_leaf = (c_leaf.at[il].set(leaf_rows(0), mode="drop")
-                      .at[ir].set(leaf_rows(1), mode="drop"))
-            par = jnp.where(arow, aslots, CANDMAX)
-            c_childlo = c_childlo.at[par].set(
-                jnp.where(arow, il, -1), mode="drop")
-            children = jnp.clip(
-                jnp.stack([il, ir], axis=1).reshape(-1)[:SMAX], 0, CANDMAX - 1
+                c_leaf = (c_leaf.at[il].set(leaf_rows(0), mode="drop")
+                          .at[ir].set(leaf_rows(1), mode="drop"))
+                par = jnp.where(arow, aslots, CANDMAX)
+                c_childlo = c_childlo.at[par].set(
+                    jnp.where(arow, il, -1), mode="drop")
+                children = jnp.clip(
+                    jnp.stack([il, ir], axis=1).reshape(-1)[:SMAX], 0, CANDMAX - 1
+                )
+                return (p, c_seg, c_bs, c_leaf, c_childlo, cand_n + 2 * n_act,
+                        children, 2 * n_act, level + 1)
+
+            (p, c_seg, c_bs, c_leaf, c_childlo, _, _, _, _) = jax.lax.while_loop(
+                lcond, lbody,
+                (p, c_seg0, c_bs0, c_leaf0, c_childlo0, jnp.int32(1), frontier0,
+                 jnp.int32(1), jnp.int32(0)),
             )
-            return (p, c_seg, c_bs, c_leaf, c_childlo, cand_n + 2 * n_act,
-                    children, 2 * n_act, level + 1)
-
-        (p, c_seg, c_bs, c_leaf, c_childlo, _, _, _, _) = jax.lax.while_loop(
-            lcond, lbody,
-            (p, c_seg0, c_bs0, c_leaf0, c_childlo0, jnp.int32(1), frontier0,
-             jnp.int32(1), jnp.int32(0)),
-        )
-        pslot0 = jnp.full((L,), -1, jnp.int32).at[0].set(0)
-    else:
-        CANDMAX = 1
-        c_seg = jnp.zeros((1, 2), jnp.int32)
-        c_bs = jnp.zeros((1, 8), jnp.float32)
-        c_leaf = jnp.zeros((1, 8), jnp.float32)
-        c_childlo = jnp.full((1,), -1, jnp.int32)
-        pslot0 = jnp.full((L,), -1, jnp.int32)
+            pslot0 = jnp.full((L,), -1, jnp.int32).at[0].set(0)
+        else:
+            CANDMAX = 1
+            c_seg = jnp.zeros((1, 2), jnp.int32)
+            c_bs = jnp.zeros((1, 8), jnp.float32)
+            c_leaf = jnp.zeros((1, 8), jnp.float32)
+            c_childlo = jnp.full((1,), -1, jnp.int32)
+            pslot0 = jnp.full((L,), -1, jnp.int32)
 
     # ---- phase 2: exact best-first selection ------------------------
     st = _PState(
@@ -463,19 +477,20 @@ def grow_tree_partitioned(
             off_lo = mrow[3].astype(jnp.int32)
             off_hi = mrow[4].astype(jnp.int32)
             bias = mrow[5].astype(jnp.int32)
-            p, nl, lhist, rhist = split_stream(
-                p, start, cnt,
-                colidx // per, (colidx % per) * params.bits, zb, dbz, thr, cat,
-                off_lo=off_lo, off_hi=off_hi, bias=bias,
-                num_features=G, num_bins=BH, bits=params.bits, rows=rows,
-                interpret=interpret,
-            )
-            hist2 = jnp.stack([lhist, rhist])
-            if params.axis_name:
-                # global children histograms; the split decision below is
-                # then bit-identical on every device (local segments
-                # diverge, the tree does not)
-                hist2 = jax.lax.psum(hist2, params.axis_name)
+            with jax.named_scope(REPLAY_TAIL):
+                p, nl, lhist, rhist = split_stream(
+                    p, start, cnt,
+                    colidx // per, (colidx % per) * params.bits, zb, dbz, thr, cat,
+                    off_lo=off_lo, off_hi=off_hi, bias=bias,
+                    num_features=G, num_bins=BH, bits=params.bits, rows=rows,
+                    interpret=interpret,
+                )
+                hist2 = jnp.stack([lhist, rhist])
+                if params.axis_name:
+                    # global children histograms; the split decision below is
+                    # then bit-identical on every device (local segments
+                    # diverge, the tree does not)
+                    hist2 = jax.lax.psum(hist2, params.axis_name)
 
             right = totals - left
             sums2 = jnp.stack([left, right])  # (2, 3)
@@ -537,26 +552,27 @@ def grow_tree_partitioned(
             pslot=st.pslot.at[idx2].set(ps2),
         )
 
-    st = jax.lax.while_loop(cond, body, st)
-    recs = st.recs
-    res = PTreeResult(
-        num_splits=st.num_splits,
-        starts=st.seg[:, 0],
-        cnts=st.seg[:, 1],
-        leaf_value=st.leaf[:, 3],
-        leaf_cnt=st.leaf[:, 4],
-        recs_raw=recs,
-        rec_leaf=recs[:, 0].astype(jnp.int32),
-        rec_feat=recs[:, 1].astype(jnp.int32),
-        rec_thr=recs[:, 2].astype(jnp.int32),
-        rec_dbz=recs[:, 3].astype(jnp.int32),
-        rec_gain=recs[:, 4],
-        rec_lval=recs[:, 5],
-        rec_rval=recs[:, 6],
-        rec_lcnt=recs[:, 7],
-        rec_rcnt=recs[:, 8],
-        rec_internal_value=recs[:, 9],
-    )
+    with jax.named_scope(REPLAY):
+        st = jax.lax.while_loop(cond, body, st)
+        recs = st.recs
+        res = PTreeResult(
+            num_splits=st.num_splits,
+            starts=st.seg[:, 0],
+            cnts=st.seg[:, 1],
+            leaf_value=st.leaf[:, 3],
+            leaf_cnt=st.leaf[:, 4],
+            recs_raw=recs,
+            rec_leaf=recs[:, 0].astype(jnp.int32),
+            rec_feat=recs[:, 1].astype(jnp.int32),
+            rec_thr=recs[:, 2].astype(jnp.int32),
+            rec_dbz=recs[:, 3].astype(jnp.int32),
+            rec_gain=recs[:, 4],
+            rec_lval=recs[:, 5],
+            rec_rval=recs[:, 6],
+            rec_lcnt=recs[:, 7],
+            rec_rcnt=recs[:, 8],
+            rec_internal_value=recs[:, 9],
+        )
     return res, st.p
 
 

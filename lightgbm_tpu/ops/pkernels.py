@@ -362,6 +362,7 @@ def hist_dyn(p, start, cnt, num_features, num_bins, bits=8, rows=None, interpret
         ),
         out_shape=jax.ShapeDtypeStruct((8, fb), jnp.float32),
         interpret=interpret,
+        name="hist_dyn",
     )(jnp.stack([jnp.int32(start), jnp.int32(cnt)]), p)
     return _hist_from_rows(out, num_features, num_bins)
 
@@ -569,6 +570,7 @@ def update_and_root_hist(p, layout: PLayout, grad_fn, delta=None, sel=None,
         ),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="update_and_root_hist",
     )(jnp.stack([jnp.int32(num_rows)]), aux, p)
     if not with_hist:
         return p, None
@@ -726,6 +728,7 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
         ),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="update_multi_and_hists",
     )(jnp.stack([jnp.int32(num_rows)]), aux, p)
     cnt = out[6 * K]
     hists = []
@@ -843,6 +846,7 @@ def score_add(p, layout: PLayout, delta, k: int = 0, *, num_rows,
         out_shape=jax.ShapeDtypeStruct(p.shape, jnp.int32),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="score_add",
     )(aux, p)
 
 
@@ -1342,6 +1346,7 @@ def level_stream(p, seg_tab, n_active, *, num_features, num_bins, bits=8,
         ),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="level_stream",
     )(sv, p)
     return p, nl, hist[:, :, :fb]
 
@@ -1409,6 +1414,7 @@ def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
         ),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="split_stream",
     )(sv, p)
     left = _hist_from_rows(hist, num_features, num_bins, row0=0)
     right = _hist_from_rows(hist, num_features, num_bins, row0=7)
@@ -1582,6 +1588,7 @@ def update_channels(p, layout: PLayout, grad_fn, delta=None, sel=None,
         out_shape=jax.ShapeDtypeStruct(p.shape, jnp.int32),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="update_channels",
     )(aux, p)
 
 

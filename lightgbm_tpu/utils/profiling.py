@@ -2,13 +2,18 @@
 phase timers (serial_tree_learner.cpp:10-37, gbdt.cpp:22-63) plus the
 per-iteration wall-clock log (application.cpp:233-236).
 
-``PhaseTimers`` is now a thin adapter over the structured tracer
-(obs/trace.py): every phase still emits a ``jax.named_scope`` (so
-xprof/jax.profiler device traces carry the same span names), accumulates
-into the TIMETAG-style totals dumped at exit, AND — when
-``LIGHTGBM_TPU_TRACE`` is set — lands as a structured span in the JSONL
-trace (feeding the per-iteration ``phases`` breakdown).  Enable the
-legacy aggregate dump with LIGHTGBM_TPU_TIMETAG=1 or ``timetag.enable()``.
+``PhaseTimers`` is a thin adapter over the structured tracer
+(obs/trace.py): every phase accumulates into the TIMETAG-style totals
+dumped at exit and, when ``LIGHTGBM_TPU_TRACE`` is set, lands as a
+structured span in the JSONL trace (feeding the per-iteration ``phases``
+breakdown).  An enabled span is also a
+``jax.profiler.TraceAnnotation("lgbm:<name>")``, which is what puts a host
+phase on a profiler trace's clock.  (A ``jax.named_scope`` here never
+did: a scope names operations while a program is TRACED, and these
+phases wrap calls to programs that are already compiled.  The scopes that
+do reach the compiled programs sit inside them, obs/phases.py.)  Enable
+the legacy aggregate dump with LIGHTGBM_TPU_TIMETAG=1 or
+``timetag.enable()``.
 """
 
 from __future__ import annotations
@@ -44,17 +49,15 @@ class PhaseTimers:
 
     @contextlib.contextmanager
     def phase(self, name: str, **attrs) -> Iterator[None]:
-        """Time a phase; also emits a jax.named_scope so device traces
-        (jax.profiler.trace) carry the same phase names, and a structured
-        tracer span when the JSONL trace is enabled."""
+        """Time a phase: a structured tracer span (and with it an
+        ``lgbm:<name>`` profiler annotation) when the JSONL trace is
+        enabled, a TIMETAG total when that is."""
         if not self.enabled and not tracer.enabled:
-            with jax.named_scope(name):
-                yield
+            yield
             return
         start = time.perf_counter()
         with tracer.span(name, **attrs):
-            with jax.named_scope(name):
-                yield
+            yield
         if self.enabled:
             self.totals[name] += time.perf_counter() - start
             self.counts[name] += 1
@@ -98,9 +101,9 @@ class XprofCapture:
     compiles and warmup would drown the steady-state timeline), then
     runs :func:`profile_trace` across the next
     ``LIGHTGBM_TPU_XPROF_ITERS`` iterations (default 4) and stops — one
-    bounded xplane capture per run.  The ``jax.named_scope`` phase
-    names PhaseTimers already emits land in the device trace, so the
-    capture needs no further instrumentation at the call sites: drive
+    bounded xplane capture per run.  With ``LIGHTGBM_TPU_TRACE`` set as
+    well, every tracer span lands in the capture as an ``lgbm:<name>``
+    annotation, so the call sites need no further instrumentation: drive
     ``on_iter_start()`` / ``on_iter_end()`` around each training
     iteration and call :meth:`close` on the way out (stops a capture
     the run abandoned mid-window)."""

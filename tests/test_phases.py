@@ -1,0 +1,388 @@
+"""Device time by phase (obs/phases.py, JitWatch.phase_map, the nested host
+spans): the names the chunk programs give themselves, the join from a compiled
+module's text to them, and what all of it costs with tracing off (nothing)."""
+
+import ast
+import json
+import os
+import pathlib
+import re
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+import lightgbm_tpu as lgb  # noqa: E402
+from lightgbm_tpu.obs import PHASES, compilewatch, tracer  # noqa: E402
+from lightgbm_tpu.obs.compilewatch import JitWatch  # noqa: E402
+from lightgbm_tpu.obs.phases import ENCLOSING, parse_hlo_phases, phase_of  # noqa: E402
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+FIXTURE = REPO / "tests" / "fixtures" / "v5e_chunk_hlo_cut.txt"
+KERNEL_FILES = ("lightgbm_tpu/ops/pkernels.py", "lightgbm_tpu/ops/histogram_pallas.py")
+KERNELS = ("hist_dyn", "update_and_root_hist", "update_multi_and_hists", "score_add",
+           "level_stream", "split_stream", "update_channels",
+           "hist_segment", "hist_segments", "hist_segment_q")
+
+
+def _toy(n=300, f=4, seed=0):
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, f)
+    return X, (X[:, 0] + X[:, 1] > 0).astype(float), rng.randint(0, 3, n).astype(float)
+
+
+# -- the names inside the programs -------------------------------------------
+def _lowered_chunk_program(params, y, X):
+    """StableHLO text, with locations, of the fused chunk program for this
+    configuration (kernels interpreted; nothing runs)."""
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y, params=params))
+    pt = bst.boosting.ptrainer
+    cfg = pt.config
+    used = pt.params.num_features
+    if cfg.feature_fraction < 1.0:
+        used = max(1, int(used * cfg.feature_fraction))
+    prog = pt._build_program(pt.CHUNK_ALLOC, cfg.bagging_fraction < 1.0 and cfg.bagging_freq > 0,
+                             max(1, int(cfg.bagging_freq)), used)
+    return prog.lower(pt.p, jnp.float32(0.1), pt._base_key, jnp.int32(0),
+                      jnp.int32(2)).as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    old = os.environ.get("LIGHTGBM_TPU_PGROW")
+    os.environ["LIGHTGBM_TPU_PGROW"] = "force"
+    try:
+        X, yb, ym = _toy()
+        base = {"num_leaves": 7, "verbose": -1}
+        return {
+            "binary": _lowered_chunk_program(
+                {**base, "objective": "binary", "bagging_fraction": 0.8, "bagging_freq": 1,
+                 "feature_fraction": 0.75}, yb, X),
+            "multiclass": _lowered_chunk_program(
+                {**base, "objective": "multiclass", "num_class": 3}, ym, X),
+        }
+    finally:
+        if old is None:
+            del os.environ["LIGHTGBM_TPU_PGROW"]
+        else:
+            os.environ["LIGHTGBM_TPU_PGROW"] = old
+
+
+def _loc_names(text):
+    return set(re.findall(r'loc\("([^"]*)"', text))
+
+
+@pytest.mark.parametrize("phase", PHASES)
+def test_chunk_program_carries_every_phase_word(lowered, phase):
+    """Every word of the vocabulary is a scope of the lowered chunk program
+    (`score_add` as a scope of its own only where K > 1 lands deltas in the
+    loop)."""
+    text = lowered["multiclass" if phase == "score_add" else "binary"]
+    if phase == "score_add":
+        assert re.search(r"score_add/jit\(score_add\)", text), \
+            "the per-class score_add call has no scope around it"
+    assert phase in {phase_of(n) for n in _loc_names(text)}
+
+
+@pytest.mark.parametrize("kernel,program", [
+    ("update_and_root_hist", "binary"), ("level_stream", "binary"),
+    ("split_stream", "binary"), ("score_add", "binary"),
+    ("update_multi_and_hists", "multiclass")])
+def test_chunk_program_names_its_kernels(lowered, kernel, program):
+    """`pallas_call(name=...)` reaches the program text as a scope of the
+    kernel's own name, whatever branch or loop it is called from."""
+    assert any(re.search(rf"(^|/){kernel}(/|$)", n) for n in _loc_names(lowered[program]))
+
+
+def _pallas_call_sites():
+    sites = {}
+    for rel in KERNEL_FILES:
+        tree = ast.parse((REPO / rel).read_text())
+        for fn in tree.body:
+            if not isinstance(fn, ast.FunctionDef):
+                continue
+            for node in ast.walk(fn):
+                if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                        and node.func.attr == "pallas_call"):
+                    name = [k.value for k in node.keywords if k.arg == "name"]
+                    sites[fn.name] = name[0].value if name and isinstance(name[0], ast.Constant) else None
+    return sites
+
+
+def test_every_pallas_call_site_is_known():
+    assert sorted(_pallas_call_sites()) == sorted(KERNELS)
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_pallas_call_is_named_after_its_function(kernel):
+    assert _pallas_call_sites()[kernel] == kernel
+
+
+# -- the parser ---------------------------------------------------------------
+@pytest.mark.parametrize("op_name,phase", [
+    ("jit(prog)/while/body/cond/branch_1_fun/jit(grow_tree_partitioned)/level_phase/while/body/add",
+     "level_phase"),
+    ("jit(prog)/while/body/jit(grow_tree_partitioned)/replay/while/body/cond/branch_1_fun/"
+     "replay_tail/jit(split_stream)/split_stream/pallas_call", "replay_tail"),
+    ("jit(prog)/chunk_epilogue/jit(score_add)/score_add/pallas_call", "chunk_epilogue"),
+    ("jit(prog)/while/body/score_add/jit(score_add)/score_add/pallas_call", "score_add"),
+    ("jit(prog)/while/body/cond", None),
+    ("jit(prog)/while/body/my_replay_helper/add", None),  # a word is a whole component
+])
+def test_phase_of(op_name, phase):
+    assert phase_of(op_name) == phase
+
+
+# what the fixture's instructions must map to, worked out by hand from the text:
+# own word; no metadata -> next/previous word of its computation, else the
+# caller's; metadata without a word -> the caller's alone
+FIXTURE_MAP = {
+    # ENTRY: the main loop sits outside every scope (parameters are no events)
+    "while.79": None,
+    "score_add.1": "chunk_epilogue",  # a Mosaic custom call; its own name is no scope
+    # the main loop's body and condition, the one_iter conditional
+    "cond.127": None, "add.1545": None, "lt.551": None,
+    # the live iteration
+    "fusion.200": "canon_reorder", "update_and_root_hist.1": "update_root_hist",
+    "while.78": "level_phase", "while.77": "replay",
+    # the stopped no-op branch copies the matrix and nothing names it
+    "get-tuple-element.2390": None, "copy.2417": None,
+    # the replay loop's body: two metadata-less matrix copies around named work
+    "get-tuple-element.2355": "replay", "copy.2389": "replay",
+    "slice_reduce_fusion.19": "replay", "cond.129": "replay", "copy.2398": "replay",
+    "cond.98": "replay",
+    # take_classic: the kernel and what feeds it are the tail's; a derived
+    # instruction whose path was cut back takes the conditional's phase
+    "concatenate.315": "replay_tail", "split_stream.5": "replay_tail",
+    "get-tuple-element.1123": "replay",
+    # take_pre: a metadata-less matrix copy in a conditional's branch
+    "get-tuple-element.2254": "replay", "copy.2308": "replay", "copy.2144": "replay",
+    "constant_dynamic-slice_fusion.4": "replay", "copy.2313": "replay",
+}
+
+
+@pytest.fixture(scope="module")
+def fixture_map():
+    return parse_hlo_phases(FIXTURE.read_text())
+
+
+def test_parser_module_matrix_and_copy_sites(fixture_map):
+    """tests/fixtures/v5e_chunk_hlo_cut.txt: 8 KB cut from the compiled text of
+    a chunk program on a TPU v5e (chip run of PR 25's tree, 20,000 rows): real
+    lines, less their backend_config and with long tuple types shortened."""
+    assert fixture_map["module"] == "jit_prog"
+    assert fixture_map["matrix"] == "s32[16,21024]"
+    assert sorted(fixture_map["matrix_copies"]) == ["copy.2308", "copy.2389", "copy.2398",
+                                                    "copy.2417"]
+    # the insides of a fusion are no events of their own
+    assert "dynamic_slice.38" not in fixture_map["ops"]
+    assert set(fixture_map["ops"]) == set(FIXTURE_MAP)
+
+
+@pytest.mark.parametrize("instruction", sorted(FIXTURE_MAP))
+def test_parser_gives_the_hand_written_map(fixture_map, instruction):
+    assert fixture_map["ops"][instruction] == FIXTURE_MAP[instruction]
+
+
+def test_parser_on_text_without_a_module():
+    assert parse_hlo_phases("") == {"module": None, "matrix": None, "ops": {},
+                                    "matrix_copies": []}
+
+
+def test_vocabulary_is_flat_and_enclosing_is_inside_it():
+    assert len(set(PHASES)) == len(PHASES) == 9
+    assert all(inner in PHASES and outer in PHASES for inner, outer in ENCLOSING.items())
+
+
+# -- JitWatch.phase_map -------------------------------------------------------
+def test_jitwatch_phase_map_round_trips():
+    @jax.jit
+    def toy_two_scopes(x):
+        with jax.named_scope("replay"):
+            y = jnp.sort(jnp.sin(x))
+        with jax.named_scope("leaf_delta"):  # a cumsum's expansion loses its path
+            return jnp.cumsum(y)
+
+    w = JitWatch(toy_two_scopes, "test.toy_two_scopes")
+    assert w.phase_map() is None and w.module == "jit_toy_two_scopes"
+    out = w(jnp.arange(4096, dtype=jnp.float32))
+    m = w.phase_map()
+    assert m["name"] == "test.toy_two_scopes" and m["module"] == "jit_toy_two_scopes"
+    assert m["matrix"] == "f32[4096]"
+    assert set(m["ops"].values()) == {"replay", "leaf_delta"}
+    # the remembered signature holds no buffer, and the map is a pure re-run
+    spec = jax.tree_util.tree_leaves(w._compiled_spec)
+    assert all(isinstance(s, jax.ShapeDtypeStruct) for s in spec)
+    assert w.phase_map() == m
+    maps = compilewatch.phase_maps(modules={"jit_toy_two_scopes"})
+    assert [p["name"] for p in maps] == ["test.toy_two_scopes"]
+    np.testing.assert_allclose(out, w(jnp.arange(4096, dtype=jnp.float32)))
+    assert w.compiles == 1 and w.retraces == 0  # asking for the map is no retrace
+
+
+def test_sharded_chunk_program_maps_too(monkeypatch):
+    """The data-parallel chunk program (`shard_map`, arguments sharded over a
+    mesh) rebuilds from its remembered signature like the serial one."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs a multi-device mesh")
+    monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+    X, y, _ = _toy(1200, 6)
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1, "tree_learner": "data"}
+    bst = lgb.train(params, lgb.Dataset(X, label=y, params=dict(params)), 2, verbose_eval=False)
+    pt = bst.boosting.ptrainer
+    assert type(pt).__name__ == "ShardedPartitionedTrainer"
+    (watch,) = pt._progs.values()
+    m = watch.phase_map()
+    assert m["module"] == watch.module == "jit_shard_body"
+    assert {"update_root_hist", "level_phase", "replay", "leaf_delta",
+            "chunk_epilogue"} <= set(m["ops"].values())
+    assert "canon_reorder" not in m["ops"].values()  # the sharded program has none
+
+
+# -- the host spans -----------------------------------------------------------
+@pytest.fixture
+def tracing_off(monkeypatch):
+    monkeypatch.delenv("LIGHTGBM_TPU_TRACE", raising=False)
+    monkeypatch.delenv("LIGHTGBM_TPU_AUDIT", raising=False)
+    monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+    tracer.close()
+    tracer.path = None
+    tracer.refresh_from_env()
+    yield
+    tracer.close()
+    tracer.path = None
+
+
+@pytest.fixture
+def counted(monkeypatch):
+    """Counts of the calls the instrumentation may only make while tracing."""
+    calls = {"annotation": 0, "block": 0, "phase_map": 0}
+    real_ann, real_block, real_map = (jax.profiler.TraceAnnotation, jax.block_until_ready,
+                                      JitWatch.phase_map)
+
+    def annotation(*a, **k):
+        calls["annotation"] += 1
+        return real_ann(*a, **k)
+
+    def block(x):
+        calls["block"] += 1
+        return real_block(x)
+
+    def phase_map(self):
+        calls["phase_map"] += 1
+        return real_map(self)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    monkeypatch.setattr(jax, "block_until_ready", block)
+    monkeypatch.setattr(JitWatch, "phase_map", phase_map)
+    return calls
+
+
+def _booster(X, y):
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1}
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y, params=params))
+    assert bst.boosting.ptrainer is not None
+    return bst
+
+
+def test_tracing_off_costs_nothing(tracing_off, counted):
+    """No record, no TraceAnnotation, no block_until_ready from the new spans,
+    no re-lowering: the untraced path issues the calls it issued before."""
+    X, y, _ = _toy()
+    bst = _booster(X, y)
+    work = tracer.work_ops
+    for _ in range(2):
+        bst.boosting.train_iters_partitioned(2, is_eval=False)
+    assert tracer.work_ops == work
+    assert counted == {"annotation": 0, "block": 0, "phase_map": 0}
+
+
+@pytest.fixture
+def traced_chunks(tmp_path, monkeypatch, counted):
+    """Two fused chunks of two iterations with the JSONL sink on."""
+    monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+    monkeypatch.setenv("LIGHTGBM_TPU_TRACE_PHASES", "0")
+    X, y, _ = _toy()
+    bst = _booster(X, y)
+    path = str(tmp_path / "trace.jsonl")
+    tracer.configure(path)
+    try:
+        for _ in range(2):
+            bst.boosting.train_iters_partitioned(2, is_eval=False)
+        n_maps = tracer.write_program_maps(modules={"jit_prog"})
+    finally:
+        tracer.close()
+        tracer.path = None
+    with open(path) as f:
+        recs = [json.loads(line) for line in f]
+    return bst, recs, n_maps, dict(counted)
+
+
+def test_nested_spans_are_each_right_alone(traced_chunks):
+    bst, recs, _, calls = traced_chunks
+    spans = [r for r in recs if r["ev"] == "span"]
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    assert len(by["records_fetch"]) == len(by["device_wait"]) == len(by["records_d2h"]) == 2
+    for fetch, wait, d2h in zip(by["records_fetch"], by["device_wait"], by["records_d2h"]):
+        assert wait["dur_s"] + d2h["dur_s"] <= fetch["dur_s"]
+        assert wait["parent"] == d2h["parent"] == "records_fetch"
+        assert wait["depth"] == d2h["depth"] == fetch["depth"] + 1
+    # the fence ran (tracing is on) and every span was an annotation too
+    assert calls["block"] >= 2 and calls["annotation"] >= len(spans)
+    trees = by["trees_from_records"]
+    assert [t["trees"] for t in trees] == [2, 2]
+    models = bst.boosting.models
+    assert sum(t["splits"] for t in trees) == sum(int(m.num_leaves) - 1 for m in models)
+
+
+@pytest.mark.parametrize("name", ["chunk_program", "records_fetch"])
+def test_old_readers_inputs_keep_name_and_nesting(traced_chunks, name):
+    """`chunk_device_wait_ms_per_iter`, `driver_host_ms_per_iter` and
+    `host_ms_per_chunk` sum these two spans by name: both still come once a
+    chunk, side by side under `tree`, neither inside the other."""
+    _, recs, _, _ = traced_chunks
+    got = [r for r in recs if r["ev"] == "span" and r["name"] == name]
+    assert len(got) == 2
+    assert all(r["parent"] == "tree" and r["depth"] == 1 for r in got)
+    if name == "chunk_program":
+        assert all(r["iters"] == 2 for r in got)
+
+
+def test_program_records_only_on_demand(traced_chunks):
+    """`write_program_maps` puts the chunk program's map into the sink; the
+    training path and `close()` never build one."""
+    _, recs, n_maps, calls = traced_chunks
+    programs = [r for r in recs if r["ev"] == "program"]
+    assert n_maps == len(programs) == calls["phase_map"] >= 1
+    chunk = [p for p in programs if p["name"].startswith("ptrainer.chunk")]
+    assert chunk and chunk[0]["module"] == "jit_prog"
+    assert {"level_phase", "replay", "canon_reorder", "leaf_delta"} <= set(chunk[0]["ops"].values())
+    assert recs.index(programs[0]) > max(i for i, r in enumerate(recs) if r["ev"] == "span")
+
+
+def test_trees_and_scores_bit_equal_with_and_without_tracing(tmp_path, monkeypatch):
+    monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+    monkeypatch.setenv("LIGHTGBM_TPU_TRACE_PHASES", "0")
+    monkeypatch.delenv("LIGHTGBM_TPU_TRACE", raising=False)
+    X, y, _ = _toy(400, 5)
+    out = {}
+    try:
+        for mode in ("off", "on"):
+            tracer.close()
+            tracer.path = None
+            if mode == "on":
+                tracer.configure(str(tmp_path / "t.jsonl"))
+            bst = _booster(X, y)
+            bst.boosting.train_iters_partitioned(3, is_eval=False)
+            out[mode] = (bst.model_to_string(), np.asarray(bst.boosting.scores))
+    finally:
+        tracer.close()
+        tracer.path = None
+    assert out["off"][0] == out["on"][0]
+    np.testing.assert_array_equal(out["off"][1], out["on"][1])
