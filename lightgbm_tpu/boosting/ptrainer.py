@@ -3,7 +3,7 @@
 Drives ops/pgrow.py.  The motivation is dispatch latency (a host round
 trip per iteration; its cost is not measured on this machine), so the
 reference's per-iteration host loop (GBDT::TrainOneIter,
-gbdt.cpp:381-495) becomes a ``lax.fori_loop`` over iterations INSIDE one
+gbdt.cpp:381-495) becomes a ``lax.while_loop`` over iterations INSIDE one
 jitted program.  Per iteration:
 
   K == 1 (binary/regression, incl. GOSS):
@@ -34,14 +34,17 @@ training; the original-order score vectors are rebuilt ONCE per chunk
 
 Why every channel write goes through a Pallas kernel: an XLA-level
 write to the packed matrix copies all of it; only
-``input_output_aliases`` mutate truly in place.  That keeps THIS file
-free of such copies, and the compiled program says so: of the six
-static sites at which the 21M-row chunk program copies the whole matrix
-(``JitWatch.phase_map()["matrix_copies"]``, compiled for the v5e, PR
-26), none sits in a phase of this file — three are in the grower's
-replay, two in its level phase (ops/pgrow.py has the per-site account)
-and one in the no-op branch a stopped chunk takes.  The canonical
-reorder's ``dynamic_update_slice`` below writes in place.
+``input_output_aliases`` mutate truly in place.  The same contract
+binds control flow (PR 27): no ``lax.cond`` may take or return the
+matrix.  The chunk loop used to wrap each iteration in
+``lax.cond(stopped, no-op, live)``, and copy insertion then put two
+whole-matrix copies into the body of every loop nested inside it, the
+grower's level and replay loops: about 530 launches a tree at 255
+leaves (ops/pgrow.py has the account; docs/matrix_copy_variants.py the
+reproducer).  The stop test is now part of the loop's predicate, and
+the compiled 21M-row program has no whole-matrix copy
+(tests/test_phases_v5e_compile.py).  The canonical reorder's
+``dynamic_update_slice`` below writes in place.
 
 Row-order-free semantics this relies on: histograms, leaf statistics and
 elementwise objectives are permutation-invariant.  Ranking objectives
@@ -340,17 +343,8 @@ class PartitionedTrainer:
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def prog(p, lr, key, iter0, t_run):
-            def one_iter(t, carry):
-                # once an iteration produced an empty tree, training has
-                # logically stopped: later in-program iterations must be
-                # FULL no-ops — growing a throwaway tree would repartition
-                # rows and invalidate last_kept's physical layout (which
-                # rollback_last applies positionally)
-                return jax.lax.cond(carry[2], lambda c: c,
-                                    functools.partial(_live_iter, t), carry)
-
-            def _live_iter(t, carry):
-                (p, recs, stopped, delta, last_kept) = carry
+            def one_iter(state):
+                t, _, p, recs, delta, last_kept = state
                 it = iter0 + t
                 # ---- canonical row order at every tree start.  The
                 # partition layout a tree leaves behind depends on HOW it
@@ -456,11 +450,10 @@ class PartitionedTrainer:
                     # score delta: +lr * leaf_value over each segment,
                     # clamped like Tree.shrinkage (tree.h:13
                     # kMaxTreeOutput) so training-time scores match the
-                    # stored model.  Once an iteration produces an empty
-                    # tree, training has logically stopped and later
-                    # in-program iterations must not touch the scores.
+                    # stored model.  An empty tree adds nothing (and ends
+                    # the loop).
                     with jax.named_scope(LEAF_DELTA):
-                        keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
+                        keep = (tree.num_splits > 0).astype(jnp.float32)
                         lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
                         delta = segment_values(tree, n, keep * lval)
                         # rollback needs the last KEPT tree's delta: an empty
@@ -492,7 +485,7 @@ class PartitionedTrainer:
                             rows=lay.class_rows(k),
                         )
                         with jax.named_scope(LEAF_DELTA):
-                            keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
+                            keep = (tree.num_splits > 0).astype(jnp.float32)
                             lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
                             dk = segment_values(tree, n, keep * lval)
                         with jax.named_scope(SCORE_ADD):
@@ -510,19 +503,28 @@ class PartitionedTrainer:
                     "num_splits": recs["num_splits"].at[t].set(ns_t),
                     "raw": recs["raw"].at[t].set(raw_t),
                 }
-                new_stopped = stopped | (~any_split)
-                return (p, recs, new_stopped, delta, last_kept)
+                return (t + 1, ~any_split, p, recs, delta, last_kept)
 
             m = L - 1
             recs0 = {
                 "num_splits": jnp.zeros((T, K), jnp.int32),
                 "raw": jnp.zeros((T, K, m, 12)),
             }
-            carry0 = (p, recs0, jnp.array(False), jnp.zeros((n,), jnp.float32),
-                      jnp.zeros((n,), jnp.float32))
-            p, recs, _, last_delta, last_kept = jax.lax.fori_loop(
-                0, jnp.minimum(t_run, T), one_iter, carry0
-            )
+            # (t, stopped, p, recs, pending delta, last kept delta)
+            state0 = (jnp.int32(0), jnp.array(False), p, recs0,
+                      jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32))
+            t_end = jnp.minimum(t_run, T)
+            # once an iteration produced an empty tree, training has
+            # logically stopped and the loop is LEFT: growing a throwaway
+            # tree would repartition rows and invalidate last_kept's
+            # physical layout (which rollback_last applies positionally),
+            # and recs0 is zeros, so not running the rest writes what
+            # running no-ops would.  The test sits in the predicate, not
+            # in a lax.cond around the body: a conditional that carries p
+            # costs whole-matrix copies in every loop nested inside it
+            # (module docstring)
+            _, _, p, recs, last_delta, last_kept = jax.lax.while_loop(
+                lambda s: (s[0] < t_end) & ~s[1], one_iter, state0)
             with jax.named_scope(CHUNK_EPILOGUE):
                 if K == 1:
                     # settle the last tree's delta into the channel so the
@@ -1320,15 +1322,8 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
             ax = jax.lax.axis_index("data")
             nreal = nreal_g[0]  # this shard's real-row count
 
-            def one_iter(t, carry):
-                # post-stop iterations are full no-ops (see the serial
-                # trainer: a throwaway tree would repartition rows under
-                # the positionally-applied last_kept)
-                return jax.lax.cond(carry[2], lambda c: c,
-                                    functools.partial(_live_iter, t), carry)
-
-            def _live_iter(t, carry):
-                (p, recs, stopped, delta, last_kept) = carry
+            def one_iter(state):
+                t, _, p, recs, delta, last_kept = state
                 it = iter0 + t
                 # validity must travel WITH the row: split_stream permutes
                 # shard columns, so padding is identified by the preserved
@@ -1421,7 +1416,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                         interpret=interpret, root_hist=root_hist,
                     )
                     with jax.named_scope(LEAF_DELTA):
-                        keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
+                        keep = (tree.num_splits > 0).astype(jnp.float32)
                         lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
                         delta = segment_values(tree, nl, keep * lval)
                         last_kept = jnp.where(keep > 0, delta, last_kept)
@@ -1448,7 +1443,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                             rows=lay.class_rows(k),
                         )
                         with jax.named_scope(LEAF_DELTA):
-                            keep = ((tree.num_splits > 0) & (~stopped)).astype(jnp.float32)
+                            keep = (tree.num_splits > 0).astype(jnp.float32)
                             lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
                             dk = segment_values(tree, nl, keep * lval)
                         with jax.named_scope(SCORE_ADD):
@@ -1462,19 +1457,23 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                     "num_splits": recs["num_splits"].at[t].set(ns_t),
                     "raw": recs["raw"].at[t].set(raw_t),
                 }
-                new_stopped = stopped | (~any_split)
-                return (p, recs, new_stopped, delta, last_kept)
+                return (t + 1, ~any_split, p, recs, delta, last_kept)
 
             m = L - 1
             recs0 = {
                 "num_splits": jnp.zeros((T, K), jnp.int32),
                 "raw": jnp.zeros((T, K, m, 12)),
             }
-            carry0 = (p, recs0, jnp.array(False), jnp.zeros((nl,), jnp.float32),
-                      jnp.zeros((nl,), jnp.float32))
-            p, recs, _, last_delta, last_kept = jax.lax.fori_loop(
-                0, jnp.minimum(t_run, T), one_iter, carry0
-            )
+            # (t, stopped, p, recs, pending delta, last kept delta)
+            state0 = (jnp.int32(0), jnp.array(False), p, recs0,
+                      jnp.zeros((nl,), jnp.float32), jnp.zeros((nl,), jnp.float32))
+            t_end = jnp.minimum(t_run, T)
+            # an empty tree ends the loop (see the serial trainer: the
+            # stop test is in the predicate because a lax.cond may not
+            # carry p); num_splits is replicated, so every shard leaves
+            # at the same iteration
+            _, _, p, recs, last_delta, last_kept = jax.lax.while_loop(
+                lambda s: (s[0] < t_end) & ~s[1], one_iter, state0)
             with jax.named_scope(CHUNK_EPILOGUE):
                 if K == 1:
                     # score-only chunk-end settle (see the serial trainer)

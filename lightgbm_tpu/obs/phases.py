@@ -26,7 +26,7 @@ SAMPLE = "sample"                    # bagging / GOSS / feature-fraction draws
 UPDATE_ROOT_HIST = "update_root_hist"  # channel refresh + root histogram + root split
 LEVEL_PHASE = "level_phase"          # level-batched expansion (level_stream)
 REPLAY = "replay"                    # best-first selection over the candidate tables
-REPLAY_TAIL = "replay_tail"          # inside replay: the classic per-split split_stream
+REPLAY_TAIL = "replay_tail"          # inside replay: split_stream, once a replayed split
 LEAF_DELTA = "leaf_delta"            # segment values -> per-row score delta
 SCORE_ADD = "score_add"              # a class's delta onto its score row (K > 1)
 CHUNK_EPILOGUE = "chunk_epilogue"    # settle the last delta, scores to original order

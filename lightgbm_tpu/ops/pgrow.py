@@ -27,17 +27,23 @@ Design notes (v2, measured on v5e):
   measured ~150 us/split tax.
 - Left/right split search runs as ONE vmapped call over the stacked
   (2, F, B, 3) children histograms.
-- Where the whole-matrix copies are (the top operation of every trace on
-  record, PERF.md section 5): the compiled 21M-row chunk program has six
-  static ``copy s32[16,21001024]`` sites (compiled for the v5e, PR 26;
-  ``JitWatch.phase_map()["matrix_copies"]``).  Three sit in the replay
-  (phase 2 below): one at the top of the ``while`` body before the
-  ``gain > 0`` conditional, one after it, and one in ``take_pre``, the
-  branch that returns ``p`` untouched — a conditional's result does not
-  alias its operand.  Two sit in the level phase's loop around
-  ``level_stream``; the sixth is in the chunk program's stopped no-op
-  branch and never runs while trees grow.  Launches and seconds per
-  site: PERF.md section 5.
+- The carry contract (PR 27): the packed matrix goes loop carry ->
+  aliased kernel -> loop carry and through NO ``lax.cond``, here and in
+  the chunk programs that inline this grower (boosting/ptrainer.py).
+  XLA's copy insertion answers a conditional that carries the matrix
+  with whole-matrix copies (1.34 GB each at 21M rows) that copy removal
+  does not elide: one in a branch that returns it untouched, and two in
+  the body of EVERY loop nested inside any conditional that carries it,
+  the level loop included, which has no conditional of its own.  The
+  parent program had three such conditionals (``stopped`` around each
+  iteration, ``gain > 0`` and ``has_pre`` in the replay), six static
+  copy sites and about 700 launches a tree: 53% of the iteration
+  (PERF.md section 5; docs/matrix_copy_variants.py is the reproducer).
+  So the replay's stop test lives in the loop's predicate, and
+  ``split_stream`` runs outside the ``has_pre`` conditional, on an empty
+  segment when the children were precomputed; that conditional carries
+  the small tables alone.  tests/test_phases_v5e_compile.py holds the
+  compiled 21M-row program to zero copy sites.
 """
 
 from __future__ import annotations
@@ -166,7 +172,6 @@ class PTreeResult(NamedTuple):
 class _PState(NamedTuple):
     p: jnp.ndarray
     num_splits: jnp.ndarray
-    done: jnp.ndarray
     seg: jnp.ndarray  # (L, 2) i32 [start, cnt]
     bs: jnp.ndarray  # (L, 8) f32 [gain, feat, thr, dbz, lg, lh, lc, 0]
     leaf: jnp.ndarray  # (L, 8) f32 [sum_g, sum_h, sum_c, value, cnt, depth, 0, 0]
@@ -423,7 +428,6 @@ def grow_tree_partitioned(
     st = _PState(
         p=p,
         num_splits=jnp.int32(0),
-        done=jnp.array(False),
         seg=seg0,
         bs=bs0,
         leaf=leaf0,
@@ -431,14 +435,12 @@ def grow_tree_partitioned(
         pslot=pslot0,
     )
 
+    # "no leaf left with a positive gain" is part of the predicate, not a
+    # lax.cond in the body: no conditional may carry p (module docstring)
     def cond(st: _PState):
-        return (~st.done) & (st.num_splits < L - 1)
+        return (st.num_splits < L - 1) & (jnp.max(st.bs[:, 0]) > 0.0)
 
     def body(st: _PState):
-        gain = jnp.max(st.bs[:, 0])
-        return jax.lax.cond(gain > 0.0, _split, lambda s: s._replace(done=True), st)
-
-    def _split(st: _PState):
         s = st.num_splits
         bl = jnp.argmax(st.bs[:, 0]).astype(jnp.int32)
         rl = (s + 1).astype(jnp.int32)
@@ -460,36 +462,46 @@ def grow_tree_partitioned(
         childlo = c_childlo[jnp.clip(slot, 0, CANDMAX - 1)]
         has_pre = (slot >= 0) & (childlo >= 0)
 
-        def take_pre(p):
+        # Children the level phase precomputed need no pass over the rows:
+        # the kernel is then launched on the EMPTY segment at 0 (no block
+        # is read or written, nl = 0, zero histograms; tens of us) rather
+        # than put in a branch, and the has_pre conditional below carries
+        # the small tables alone.  Not (start, 0): an unaligned start
+        # reads and rewrites one block.
+        mrow = mtab[feat]
+        zb = mrow[0].astype(jnp.int32)
+        cat = mrow[1].astype(jnp.int32)
+        colidx = mrow[2].astype(jnp.int32)
+        off_lo = mrow[3].astype(jnp.int32)
+        off_hi = mrow[4].astype(jnp.int32)
+        bias = mrow[5].astype(jnp.int32)
+        with jax.named_scope(REPLAY_TAIL):
+            p, nl, lhist, rhist = split_stream(
+                st.p, jnp.where(has_pre, 0, start), jnp.where(has_pre, 0, cnt),
+                colidx // per, (colidx % per) * params.bits, zb, dbz, thr, cat,
+                off_lo=off_lo, off_hi=off_hi, bias=bias,
+                num_features=G, num_bins=BH, bits=params.bits, rows=rows,
+                interpret=interpret,
+            )
+
+        def take_pre(nl, lhist, rhist):
             clo = jnp.clip(childlo, 0, CANDMAX - 1)
             chi = jnp.clip(childlo + 1, 0, CANDMAX - 1)
             seg2 = jnp.stack([c_seg[clo], c_seg[chi]])
             bs2 = jnp.stack([c_bs[clo], c_bs[chi]])
             leaf2 = jnp.stack([c_leaf[clo], c_leaf[chi]])
             ps2 = jnp.stack([clo, chi])
-            return p, seg2, bs2, leaf2, ps2
+            return seg2, bs2, leaf2, ps2
 
-        def take_classic(p):
-            mrow = mtab[feat]
-            zb = mrow[0].astype(jnp.int32)
-            cat = mrow[1].astype(jnp.int32)
-            colidx = mrow[2].astype(jnp.int32)
-            off_lo = mrow[3].astype(jnp.int32)
-            off_hi = mrow[4].astype(jnp.int32)
-            bias = mrow[5].astype(jnp.int32)
+        def take_classic(nl, lhist, rhist):
             with jax.named_scope(REPLAY_TAIL):
-                p, nl, lhist, rhist = split_stream(
-                    p, start, cnt,
-                    colidx // per, (colidx % per) * params.bits, zb, dbz, thr, cat,
-                    off_lo=off_lo, off_hi=off_hi, bias=bias,
-                    num_features=G, num_bins=BH, bits=params.bits, rows=rows,
-                    interpret=interpret,
-                )
                 hist2 = jnp.stack([lhist, rhist])
                 if params.axis_name:
                     # global children histograms; the split decision below is
                     # then bit-identical on every device (local segments
-                    # diverge, the tree does not)
+                    # diverge, the tree does not).  Inside the branch: a
+                    # precomputed split needs no collective, and has_pre
+                    # is replicated
                     hist2 = jax.lax.psum(hist2, params.axis_name)
 
             right = totals - left
@@ -519,10 +531,10 @@ def grow_tree_partitioned(
                  jnp.zeros((2,)), jnp.zeros((2,))], axis=1
             )  # (2, 8)
             ps2 = jnp.full((2,), -1, jnp.int32)
-            return p, seg2, bs2, leaf2, ps2
+            return seg2, bs2, leaf2, ps2
 
-        p, seg2, bs2, leaf2, ps2 = jax.lax.cond(
-            has_pre, take_pre, take_classic, st.p
+        seg2, bs2, leaf2, ps2 = jax.lax.cond(
+            has_pre, take_pre, take_classic, nl, lhist, rhist
         )
         # child outputs are recomputed HERE, at one shared (2,)-shaped
         # site outside the cond, from the children's g/h sums.  The

@@ -34,7 +34,13 @@ runtime; not re-measured on this machine):
      SEPARATE small carry arrays), and the jitted kernel entry points
      here (``split_stream``/``level_stream``/``score_add``) carry
      ``donate_argnums=(0,)`` so standalone calls alias straight through
-     instead of paying a defensive input copy.  ``update_channels`` /
+     instead of paying a defensive input copy.  The contract covers
+     control flow too (PR 27, measured on a v5e: 53% of a 21M-row
+     iteration was such copies): no ``lax.cond`` may take or return the
+     matrix, because copy insertion then copies it whole in the
+     pass-through branch and twice in every loop body nested inside; a
+     caller with nothing to do launches the kernel on the empty segment
+     ``(0, 0)`` instead (ops/pgrow.py).  ``update_channels`` /
      ``score_add`` stream only the 8-aligned mutable band for
      score/gradient maintenance — the bin words are never re-read or
      re-written by a pass that doesn't need them.

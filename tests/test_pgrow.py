@@ -830,3 +830,173 @@ class TestUpdateHistFree:
         assert hist is not None and np.asarray(hist).shape == (lay.F, 32, 3)
         np.testing.assert_array_equal(np.asarray(Pa, np.int32),
                                       np.asarray(Pb, np.int32))
+
+
+class TestEmptySegmentLaunch:
+    """split_stream on the empty segment at 0 is what the replay launches
+    when a split's children were precomputed by the level phase (PR 27:
+    the kernel runs outside the ``has_pre`` conditional so that the
+    packed matrix never passes through one).  It must be a no-op."""
+
+    @pytest.mark.parametrize("bits,nbins", [(8, 32), (4, 16)])
+    def test_start0_cnt0_touches_nothing(self, bits, nbins):
+        rng = np.random.default_rng(31)
+        n, f = 5000, 11
+        lay = pk.PLayout(f, bits=bits)
+        bins = rng.integers(0, nbins, size=(n, f), dtype=np.uint8)
+        P = pk.pack_matrix(bins, lay, label=rng.random(n).astype(np.float32))
+        for row in (lay.G, lay.H, lay.SEL):
+            P = P.at[row, :n].set(jnp.asarray(
+                np.abs(rng.standard_normal(n)).astype(np.float32).view(np.int32)))
+        before = np.asarray(P, np.int32)
+        per = 32 // bits
+        P2, nl, lh, rh = pk.split_stream(
+            jnp.array(P), 0, 0, 5 // per, (5 % per) * bits, 0, 0, 7, 0,
+            num_features=f, num_bins=nbins, bits=bits, rows=lay.rows,
+            interpret=INTERP)
+        assert int(nl) == 0
+        np.testing.assert_array_equal(np.asarray(P2, np.int32), before)
+        assert np.asarray(lh).shape == (f, nbins, 3)
+        assert not np.asarray(lh).any() and not np.asarray(rh).any()
+
+
+class TestChunkStops:
+    """A chunk in which a tree comes out empty.  Since PR 27 the chunk
+    program LEAVES its loop there (``~stopped`` is part of the loop's
+    predicate; before, every later iteration took a no-op branch of a
+    conditional that carried the whole matrix).  Either way the state
+    after the chunk is the state the stopping iteration left."""
+
+    LR = 1.0
+
+    def _booster(self, kind, monkeypatch):
+        import lightgbm_tpu as lgb
+
+        monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+        rng = np.random.default_rng(5)
+        n = 3000
+        X = rng.standard_normal((n, 4)).astype(np.float32)
+        X[:, 0] = rng.integers(0, 3, n)
+        if kind == "const":
+            y = np.full(n, 3.0, np.float32)  # no gradient: tree 0 is empty
+        else:
+            # three plateaus under stumps: the gains decay 4x a tree (about
+            # 5900, 1470, 360, ...), and min_gain_to_split cuts the third
+            y = np.asarray([0.0, 2.0, 5.0], np.float32)[X[:, 0].astype(int)]
+        params = dict(objective="regression", num_leaves=2, learning_rate=self.LR,
+                      max_bin=31, min_data_in_leaf=20, min_gain_to_split=1000.0,
+                      verbose=-1)
+        return lgb.Booster(params=params,
+                           train_set=lgb.Dataset(X, label=y, params=dict(params)))
+
+    @pytest.mark.parametrize("kind,stop_at", [("const", 0), ("step", 2)])
+    def test_state_after_the_stop(self, monkeypatch, kind, stop_at):
+        b = self._booster(kind, monkeypatch).boosting
+        pt = b.ptrainer
+        b._boost_from_average()
+        if pt.score_dirty:
+            pt.sync_scores_from(b.scores[0])
+        prog = pt._build_program(4, False, 1, pt.params.num_features)
+        p0 = np.asarray(pt.p)
+        args = (jnp.float32(self.LR), pt._base_key, jnp.int32(0))
+        whole = jax.device_get(prog(jnp.array(p0), *args, jnp.int32(4)))
+        # the same program told to run only up to the stopping iteration
+        short = jax.device_get(prog(jnp.array(p0), *args, jnp.int32(stop_at + 1)))
+        ns = whole[1]["num_splits"][:, 0]
+        assert (ns[:stop_at] > 0).all() and not ns[stop_at:].any()
+        assert not whole[1]["raw"][stop_at:].any()
+        for got, want in zip(jax.tree_util.tree_leaves(whole),
+                             jax.tree_util.tree_leaves(short)):
+            np.testing.assert_array_equal(got, want)
+        if stop_at == 0:
+            # nothing was kept: scores and rollback snapshot are untouched
+            lay = pt.layout
+            np.testing.assert_array_equal(whole[0][lay.SCORE], p0[lay.SCORE])
+            assert not whole[3].any()
+
+    def test_rollback_after_a_stopped_chunk(self, monkeypatch):
+        bst = self._booster("step", monkeypatch)
+        b = bst.boosting
+        assert b.train_iters_partitioned(4, is_eval=False) is True
+        assert b.iter == 2
+        bst.rollback_one_iter()
+        assert b.iter == 1 and not b.ptrainer.score_dirty
+        ref = self._booster("step", monkeypatch).boosting
+        assert ref.train_iters_partitioned(1, is_eval=False) is False
+        np.testing.assert_allclose(
+            np.asarray(b.ptrainer.scores_original_order()),
+            np.asarray(ref.ptrainer.scores_original_order()), rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(b.scores), np.asarray(ref.scores),
+                                   rtol=1e-5, atol=1e-6)
+
+    def test_nothing_to_roll_back_when_the_first_tree_is_empty(self, monkeypatch):
+        b = self._booster("const", monkeypatch).boosting
+        assert b.train_iters_partitioned(4, is_eval=False) is True
+        assert b.iter == 0 and b.ptrainer._last_tree is None
+        np.testing.assert_array_equal(
+            np.asarray(b.ptrainer.scores_original_order()), np.float32(3.0))
+
+
+GOLDEN_RECS = os.path.join(os.path.dirname(__file__), "golden", "pgrow_recs.npz")
+
+
+def chunk_records(levelgrow: str, sharded: bool):
+    """Split records of 3 iterations on a seeded 65,536-row table, as the
+    chunk program returns them.  LIGHTGBM_TPU_PGROW=force must be set."""
+    from unittest import mock
+
+    import lightgbm_tpu as lgb
+    import lightgbm_tpu.parallel as par
+
+    rng = np.random.default_rng(20270927)
+    n, f = 65536, 10
+    X = rng.standard_normal((n, f)).astype(np.float32)
+    w = rng.standard_normal(f)
+    y = (rng.random(n) < 1 / (1 + np.exp(-(X @ w + X[:, 0] * X[:, 1])))).astype(np.float32)
+    params = dict(objective="binary", num_leaves=31, learning_rate=0.1, max_bin=63,
+                  min_data_in_leaf=20, verbose=-1,
+                  tree_learner="data" if sharded else "serial")
+    mesh4 = par.make_mesh(4) if sharded else None
+    with mock.patch.dict(os.environ, {"LIGHTGBM_TPU_LEVELGROW": levelgrow}), \
+            mock.patch.object(par, "make_mesh", lambda n_devices=None: mesh4):
+        b = lgb.Booster(params=params,
+                        train_set=lgb.Dataset(X, label=y, params=dict(params))).boosting
+    pt = b.ptrainer
+    assert pt.params.levelwise == (levelgrow == "1")
+    assert getattr(pt, "d", 1) == (4 if sharded else 1)
+    seen = {}
+    run = pt.train_chunk
+
+    def capture(*a, **k):
+        out = run(*a, **k)
+        seen["recs"] = out[0]
+        return out
+
+    with mock.patch.object(pt, "train_chunk", capture):
+        b.train_iters_partitioned(3, is_eval=False)
+    assert seen["recs"]["num_splits"].tolist() == [[30]] * 3
+    return seen["recs"]["raw"]
+
+
+GOLDEN_CASES = [("serial_lg1", "1", False), ("serial_lg0", "0", False),
+                ("dp4_lg1", "1", True), ("dp4_lg0", "0", True)]
+
+
+class TestRecordsGolden:
+    """tests/golden/pgrow_recs.npz was written by
+    tests/golden/make_pgrow_recs.py from the PARENT of PR 27 (commit
+    028fc30), whose change to the chunk program and the replay is
+    control flow alone: of 90 replayed splits in the level-batched runs
+    78 take precomputed children and 12 the ``split_stream`` tail, and
+    not one byte of a record may differ.  A later PR that changes the
+    arithmetic on purpose rewrites the file with that script and says so."""
+
+    @pytest.mark.parametrize("name,levelgrow,sharded", GOLDEN_CASES)
+    def test_records_byte_equal(self, monkeypatch, name, levelgrow, sharded):
+        if sharded and len(jax.devices()) < 4:
+            pytest.skip("needs 4 devices")
+        monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+        want = np.load(GOLDEN_RECS)[name]
+        got = chunk_records(levelgrow, sharded)
+        assert got.dtype == want.dtype and got.shape == want.shape == (3, 1, 30, 12)
+        assert got.tobytes() == want.tobytes()

@@ -149,7 +149,9 @@ FIXTURE_MAP = {
     # the live iteration
     "fusion.200": "canon_reorder", "update_and_root_hist.1": "update_root_hist",
     "while.78": "level_phase", "while.77": "replay",
-    # the stopped no-op branch copies the matrix and nothing names it
+    # the fixture is the program as it was before PR 27, when each iteration sat
+    # in lax.cond(stopped, no-op, live): the no-op branch copies the matrix and
+    # nothing names it (the parser's rules are what this pins, not the program)
     "get-tuple-element.2390": None, "copy.2417": None,
     # the replay loop's body: two metadata-less matrix copies around named work
     "get-tuple-element.2355": "replay", "copy.2389": "replay",

@@ -8,6 +8,7 @@ time may load libtpu, and every xdist worker imports this file), and this is
 the only test file that does so."""
 
 import collections
+import contextlib
 import os
 import re
 
@@ -30,40 +31,67 @@ NOT_LAUNCHED = ("get-tuple-element", "tuple", "constant", "bitcast", "while", "c
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(scope="module")
-def compiled_text(one_chip):
-    """Compiled HLO text of the serial chunk program at 21M rows x 28 features:
-    the trainer is built on a small table (its closures do not depend on the
-    row count) and then told the real one; kernels go through Mosaic."""
-    import lightgbm_tpu as lgb
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@contextlib.contextmanager
+def _compiling_for_a_described_chip():
+    """Kernels through Mosaic (the trainer is built under PGROW=force), and no
+    compile cache: a compile for a described chip is written to it and cannot
+    be read back."""
     from jax.experimental.compilation_cache import compilation_cache
 
     old = os.environ.get("LIGHTGBM_TPU_PGROW")
     os.environ["LIGHTGBM_TPU_PGROW"] = "force"
     cache_was = jax.config.jax_enable_compilation_cache
-    # a compile for a described chip is written to the cache and cannot be read back
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        rng = np.random.RandomState(7)
-        X = rng.randn(20_000, 28)
-        y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(float)
-        bst = lgb.Booster(params=PARAMS, train_set=lgb.Dataset(X, label=y, params=dict(PARAMS)))
-        pt = bst.boosting.ptrainer
-        pt.num_rows = ROWS
-        pt.params = pt.params._replace(num_rows=ROWS)
-        pt.interpret = False
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+        if old is None:
+            del os.environ["LIGHTGBM_TPU_PGROW"]
+        else:
+            os.environ["LIGHTGBM_TPU_PGROW"] = old
+
+
+def _small_trainer(**more):
+    """A fused trainer on a small table: its closures do not depend on the row
+    count, so it is then told the real one."""
+    import lightgbm_tpu as lgb
+
+    params = dict(PARAMS, **more)
+    rng = np.random.RandomState(7)
+    X = rng.randn(20_000, 28)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(float)
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y, params=dict(params)))
+    pt = bst.boosting.ptrainer
+    pt.num_rows = ROWS
+    pt.params = pt.params._replace(num_rows=ROWS)
+    pt.interpret = False
+    return pt
+
+
+@pytest.fixture(scope="module")
+def compiled_text(one_chip):
+    """Compiled HLO text of the serial chunk program at 21M rows x 28 features."""
+    with _compiling_for_a_described_chip():
+        pt = _small_trainer()
         prog = pt._build_program(pt.CHUNK_ALLOC, False, 1, 28)
 
         def spec(shape, dtype):
@@ -73,13 +101,38 @@ def compiled_text(one_chip):
         lowered = prog.lower(spec((pt.p.shape[0], ROWS + 1024), jnp.int32), spec((), jnp.float32),
                              spec(key.shape, key.dtype), spec((), jnp.int32), spec((), jnp.int32))
         return lowered.compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
-        if old is None:
-            del os.environ["LIGHTGBM_TPU_PGROW"]
-        else:
-            os.environ["LIGHTGBM_TPU_PGROW"] = old
+
+
+@pytest.fixture(scope="module")
+def sharded_text(topo):
+    """The data-parallel chunk program (``tree_learner=data``, which no cell of
+    the benchmark runs) at 21M rows a chip, compiled for the four described
+    chips: the trainer is built on four CPU devices and then handed their mesh."""
+    from unittest import mock
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import lightgbm_tpu.parallel as par
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices to build the sharded trainer on")
+    cpu_mesh = par.make_mesh(4)
+    with _compiling_for_a_described_chip():
+        with mock.patch.object(par, "make_mesh", lambda n_devices=None: cpu_mesh):
+            pt = _small_trainer(tree_learner="data")
+        assert type(pt).__name__ == "ShardedPartitionedTrainer" and pt.d == 4
+        pt.mesh = mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+        prog = pt._build_program(pt.CHUNK_ALLOC, False, 1, 28)
+
+        def spec(shape, dtype, *axes):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+        key = pt._base_key
+        lowered = prog.lower(
+            spec((4, pt.p.shape[1], ROWS + 1024), jnp.int32, "data"), spec((4,), jnp.int32, "data"),
+            spec((), jnp.float32), spec(key.shape, key.dtype), spec((), jnp.int32),
+            spec((), jnp.int32))
+        return lowered.compile().as_text()
 
 
 @pytest.fixture(scope="module")
@@ -110,16 +163,39 @@ def test_mosaic_kernels_carry_their_names_and_phases(compiled_text, phase_map, k
 
 
 def test_whole_matrix_copy_sites(phase_map):
-    """ROADMAP S1's starting point: six static sites, three in the replay, two
-    in the level phase, one in the stopped no-op branch (no phase), none in a
-    phase of ptrainer.py.  A PR that removes copies lowers these counts."""
+    """None (PR 27).  The packed matrix goes loop carry -> aliased kernel ->
+    loop carry and through no conditional: the parent's six static sites (three
+    in the replay, two in the level phase, one in the stopped no-op branch) were
+    all copy-insertion's answer to a ``lax.cond`` that carried the matrix.  A
+    site that comes back inside a loop costs 1.34 GB of traffic a launch: read
+    its operand and users in the compiled text before guessing (PERF.md
+    section 5 has the table of causes)."""
     sites = collections.Counter(phase_map["ops"][c] for c in phase_map["matrix_copies"])
-    assert sites == {"replay": 3, "level_phase": 2, None: 1}
+    assert sites == {}
+
+
+def test_no_conditional_carries_the_matrix(compiled_text, phase_map):
+    """The contract behind the count above, read off the compiled text: of the
+    parent's three conditionals (the ``stopped`` test around every iteration,
+    ``gain > 0`` and ``has_pre`` in the replay) only ``has_pre`` is left, and
+    no conditional's operands or result hold an array of the matrix's shape;
+    ``split_stream`` is still one kernel, launched outside any branch."""
+    types = {m.group(1): m.group(2) for m in re.finditer(
+        r"^\s+(?:ROOT )?%(\S+) = (\(.*?\)|\S+) [\w-]+\(", compiled_text, re.M)}
+    conds = re.findall(r"%(\S+) = .* conditional\((.*?)\), branch_computations", compiled_text)
+    assert len(conds) == 1
+    (name, operands), = conds
+    assert phase_map["ops"][name] == "replay"
+    for inst in [name] + re.findall(r"%([\w.-]+)", operands):
+        assert phase_map["matrix"] not in types[inst], inst
+    calls = re.findall(r'%(split_stream(?:\.\d+)?) = .*custom_call_target="tpu_custom_call"',
+                       compiled_text)
+    assert len(calls) == 1
 
 
 def test_what_no_phase_claims_is_bookkeeping(compiled_text, phase_map):
-    """Outside every scope: the main loop, the record stores, and the stopped
-    no-op branch with its copy.  Nothing else the size of the table."""
+    """Outside every scope: the main loop with its counter and stop test, and
+    the record stores.  Nothing the size of the table."""
     unclaimed = {k for k, v in phase_map["ops"].items() if v is None}
     table_sized = []
     for line in compiled_text.splitlines():
@@ -128,5 +204,18 @@ def test_what_no_phase_claims_is_bookkeeping(compiled_text, phase_map):
             continue
         if "21000000" in m.group(2) or "21001024" in m.group(2):
             table_sized.append(m.group(1))
-    assert table_sized == [c for c in phase_map["matrix_copies"] if phase_map["ops"][c] is None]
+    assert table_sized == []
     assert len(unclaimed) < 0.1 * len(phase_map["ops"])
+
+
+def test_sharded_program_copies_no_shard(sharded_text):
+    """The same contract in the data-parallel program (the parent compiled to
+    six ``copy s32[16,...]`` sites and three conditionals here too, and its one
+    trace on record had the copies at 66% of the time): no copy of a shard of
+    the matrix, one conditional, and no more collectives than the parent's
+    three ``all-reduce`` sites (root, level, replay tail): the tail's stays
+    inside the ``has_pre`` conditional's classic branch."""
+    assert "tpu_custom_call" in sharded_text
+    assert re.findall(r" = s32\[(?:1,)?16,21001024\]\S* copy\(", sharded_text) == []
+    assert len(re.findall(r" conditional\(", sharded_text)) == 1
+    assert len(re.findall(r" all-reduce(?:-start)?\(", sharded_text)) == 3
