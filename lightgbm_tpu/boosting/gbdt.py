@@ -794,9 +794,11 @@ class GBDT:
             fence(self.scores)
         chunk_trees = [[] for _ in range(K)]
         # host work between two chunk programs; `splits` is what the
-        # trace's readers divide the replay's launches by
+        # trace's readers divide the replay's launches by, the streaming
+        # kernels' counts what their operation and byte models are made of
         counts = ({"trees": n_done * K,
-                   "splits": int(recs["num_splits"][:n_done].sum())}
+                   "splits": int(recs["num_splits"][:n_done].sum()),
+                   **pt.stream_counts(recs, n_done)}
                   if tracer.enabled and n_done > 0 else {})
         with tracer.span("trees_from_records", **counts):
             for t in range(n_done):

@@ -94,6 +94,9 @@ from ..ops.pgrow import (
 )
 from ..ops.pkernels import (
     PLayout,
+    col_groups,
+    hist_lanes,
+    level_stream_vmem_bytes,
     pack_matrix_device,
     score_add,
     split_stream,
@@ -390,6 +393,7 @@ class PartitionedTrainer:
 
                 ns_t = recs["num_splits"][t]
                 raw_t = recs["raw"][t]
+                lv_t = recs["levels"][t]
                 if K == 1:
                     if goss_on:
                         # GOSS (goss.hpp:126-198): settle the pending
@@ -463,6 +467,7 @@ class PartitionedTrainer:
                     any_split = tree.num_splits > 0
                     ns_t = ns_t.at[0].set(tree.num_splits)
                     raw_t = raw_t.at[0].set(tree.recs_raw)
+                    lv_t = lv_t.at[0].set(tree.level_counts)
                 else:
                     # K trees per iteration (per-class loop,
                     # gbdt.cpp:445-480): ALL K gradient planes + K root
@@ -494,6 +499,7 @@ class PartitionedTrainer:
                         any_split = any_split | (tree.num_splits > 0)
                         ns_t = ns_t.at[k].set(tree.num_splits)
                         raw_t = raw_t.at[k].set(tree.recs_raw)
+                        lv_t = lv_t.at[k].set(tree.level_counts)
                     delta = delta  # unused for K > 1 (scores always settled)
 
                 # ONE packed record buffer: per-op dispatch inside the
@@ -502,6 +508,7 @@ class PartitionedTrainer:
                 recs = {
                     "num_splits": recs["num_splits"].at[t].set(ns_t),
                     "raw": recs["raw"].at[t].set(raw_t),
+                    "levels": recs["levels"].at[t].set(lv_t),
                 }
                 return (t + 1, ~any_split, p, recs, delta, last_kept)
 
@@ -509,6 +516,10 @@ class PartitionedTrainer:
             recs0 = {
                 "num_splits": jnp.zeros((T, K), jnp.int32),
                 "raw": jnp.zeros((T, K, m, 12)),
+                # per tree: level_stream launches, the rows they streamed and
+                # the segments they partitioned (one shard's rows, in the
+                # sharded program)
+                "levels": jnp.zeros((T, K, 3), jnp.int32),
             }
             # (t, stopped, p, recs, pending delta, last kept delta)
             state0 = (jnp.int32(0), jnp.array(False), p, recs0,
@@ -912,6 +923,27 @@ class PartitionedTrainer:
 
         recs_np = {"num_splits": all_ns[:n_done], "raw": all_raw[:n_done]}
         return recs_np, self.scores_original_order(), n_done
+
+    def stream_counts(self, recs_np, n_done: int) -> dict:
+        """What the streaming kernels did for the first ``n_done``
+        iterations of a chunk's records, for the ``trees_from_records``
+        span: ``levels`` (level_stream launches), ``level_rows`` and
+        ``level_segments`` (the rows they streamed and the segments they
+        partitioned, summed over levels; one shard's rows under
+        ``tree_learner=data``), and the shapes a launch works on:
+        ``hist_cells`` (lanes of one leaf's histogram row as the kernels
+        issue it, padding included), ``channels`` (rows of the packed
+        matrix) and ``col_groups`` (column groups a kernel walks a block
+        in: 1 up to 31 columns).  Called only when tracing is on."""
+        cols = self.params.num_cols or self.params.num_features
+        bins = self.params.num_bins_hist or self.params.num_bins
+        out = {"hist_cells": hist_lanes(cols, bins), "channels": self.layout.C,
+               "col_groups": col_groups(cols, self.params.bits).count}
+        lv = recs_np.get("levels")  # the defused traced mode keeps none
+        if lv is not None:
+            out.update(zip(("levels", "level_rows", "level_segments"),
+                           lv[:n_done].sum(axis=(0, 1)).tolist()))
+        return out
 
     def grow_result_view(self, recs_np, t, k: int = 0):
         """GrowResult-like view of tree (t, class k)'s records
@@ -1350,6 +1382,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
 
                 ns_t = recs["num_splits"][t]
                 raw_t = recs["raw"][t]
+                lv_t = recs["levels"][t]
                 if K == 1:
                     if goss_on:
                         # settle pending delta + fresh gradients first
@@ -1423,6 +1456,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                     any_split = tree.num_splits > 0
                     ns_t = ns_t.at[0].set(tree.num_splits)
                     raw_t = raw_t.at[0].set(tree.recs_raw)
+                    lv_t = lv_t.at[0].set(tree.level_counts)
                 else:
                     # K trees per iteration from one gradient pass; each
                     # class's delta lands on its score row immediately
@@ -1452,10 +1486,12 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                         any_split = any_split | (tree.num_splits > 0)
                         ns_t = ns_t.at[k].set(tree.num_splits)
                         raw_t = raw_t.at[k].set(tree.recs_raw)
+                        lv_t = lv_t.at[k].set(tree.level_counts)
 
                 recs = {
                     "num_splits": recs["num_splits"].at[t].set(ns_t),
                     "raw": recs["raw"].at[t].set(raw_t),
+                    "levels": recs["levels"].at[t].set(lv_t),
                 }
                 return (t + 1, ~any_split, p, recs, delta, last_kept)
 
@@ -1463,6 +1499,10 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
             recs0 = {
                 "num_splits": jnp.zeros((T, K), jnp.int32),
                 "raw": jnp.zeros((T, K, m, 12)),
+                # per tree: level_stream launches, the rows they streamed and
+                # the segments they partitioned (one shard's rows, in the
+                # sharded program)
+                "levels": jnp.zeros((T, K, 3), jnp.int32),
             }
             # (t, stopped, p, recs, pending delta, last kept delta)
             state0 = (jnp.int32(0), jnp.array(False), p, recs0,
@@ -1491,7 +1531,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         mapped = self._shard_map(
             shard_body,
             (P("data"), P("data"), P(), P(), P(), P()),
-            (P("data"), {"num_splits": P(), "raw": P()}, P(None, "data"),
+            (P("data"), {"num_splits": P(), "raw": P(), "levels": P()}, P(None, "data"),
              P("data")),
         )
         return jax.jit(mapped, donate_argnums=(0,))
@@ -1627,23 +1667,41 @@ def eligible(config, train_set, objective, num_tree_per_iteration: int) -> bool:
     # bundling is built lazily, only once a partitioned run is plausible
     if hasattr(train_set, "ensure_bundles"):
         train_set.ensure_bundles(config)
-    # Wide-matrix ceiling (Bosch-968/Epsilon-2000 shapes): two hard
-    # budgets bound the fused kernels, not just the per-column unroll.
-    # (a) Mosaic program size grows linearly with the per-block one-hot
-    #     unroll (fixable with a rolled word-group loop), and
-    # (b) VMEM: the split/level kernels hold 11 (C, BLK) stream buffers
-    #     + the (BLK, BLK) tri + the (16, G*B) hist accumulators; at
-    #     G=968, B=64 that is ~17 MB at BLK=1024 and the level kernel's
-    #     double-buffered hist alone is ~8 MB — G=2000 cannot fit any
-    #     BLK without spilling accumulators to HBM.
-    # Beyond the cap the mask-based grower (which tiles columns freely
-    # at the XLA level) handles these shapes; gpu_tree_learner.cpp's
-    # multi-tuple packing is the reference analogue of that fallback.
+    # Width.  The streaming kernels walk a block's bin words in column
+    # groups (ops/pkernels.py: col_groups, a rolled loop, so Mosaic
+    # program size does not grow with the columns) and ask Mosaic for the
+    # VMEM their whole-block buffers and histogram rows need, so K == 1
+    # rides at any width that VMEM holds: at 2,000 columns x 63 bins
+    # level_stream holds 12 (512, BLK) blocks and two (16, 128,000)
+    # histograms, 42 MB of the v5e's 128 MiB.  The ceiling that is left is
+    # that budget.  Multiclass keeps the old 512 columns: its update
+    # kernel holds 6K+1 histogram rows of ALL columns at once (53 MB at
+    # K = 16 and 2,000 columns, twice with its output block).
     bundle = getattr(train_set, "bundle", None)
     cols = bundle.num_cols if bundle is not None else train_set.num_features
-    if cols > 512:
-        return False
+    col_bins = int(bundle.max_col_bin) if bundle is not None else int(train_set.max_num_bin)
+    if num_tree_per_iteration > 1 and cols > MULTICLASS_MAX_COLS:
+        return _declined("multiclass (K = %d) above %d columns (%d): "
+                         "update_multi_and_hists holds 6K+1 histogram rows of every "
+                         "column in VMEM", num_tree_per_iteration, MULTICLASS_MAX_COLS, cols)
+    vmem = level_stream_vmem_bytes(cols, col_bins, num_score=num_tree_per_iteration)
+    if vmem > VMEM_BUDGET_BYTES:
+        return _declined("%d columns x %d bins: level_stream would hold %.0f MB in "
+                         "VMEM (budget %.0f MB)", cols, col_bins, vmem / 1e6,
+                         VMEM_BUDGET_BYTES / 1e6)
     return True
+
+
+MULTICLASS_MAX_COLS = 512
+VMEM_BUDGET_BYTES = 80 * 1024 * 1024  # of the v5e's 128 MiB, before Mosaic's own spills
+
+
+def _declined(reason: str, *args) -> bool:
+    """A configuration the fused engine could in principle run and does not:
+    say so, because the mask grower it falls to pays a pass over all rows
+    for every split."""
+    Log.warning("fused partitioned trainer declined, using the mask grower: " + reason, *args)
+    return False
 
 
 def _build_bundle_meta(bundle, train_set, num_bins: int) -> BundleMeta:

@@ -25,16 +25,17 @@ CANON_REORDER = "canon_reorder"      # rows back to original order at a tree's s
 SAMPLE = "sample"                    # bagging / GOSS / feature-fraction draws
 UPDATE_ROOT_HIST = "update_root_hist"  # channel refresh + root histogram + root split
 LEVEL_PHASE = "level_phase"          # level-batched expansion (level_stream)
+SPLIT_SCAN = "split_scan"            # inside level_phase: the split search over a level's histograms
 REPLAY = "replay"                    # best-first selection over the candidate tables
 REPLAY_TAIL = "replay_tail"          # inside replay: split_stream, once a replayed split
 LEAF_DELTA = "leaf_delta"            # segment values -> per-row score delta
 SCORE_ADD = "score_add"              # a class's delta onto its score row (K > 1)
 CHUNK_EPILOGUE = "chunk_epilogue"    # settle the last delta, scores to original order
 
-PHASES = (CANON_REORDER, SAMPLE, UPDATE_ROOT_HIST, LEVEL_PHASE, REPLAY,
+PHASES = (CANON_REORDER, SAMPLE, UPDATE_ROOT_HIST, LEVEL_PHASE, SPLIT_SCAN, REPLAY,
           REPLAY_TAIL, LEAF_DELTA, SCORE_ADD, CHUNK_EPILOGUE)
 # a phase that only ever sits inside another: readers of the outer one add it
-ENCLOSING = {REPLAY_TAIL: REPLAY}
+ENCLOSING = {REPLAY_TAIL: REPLAY, SPLIT_SCAN: LEVEL_PHASE}
 
 _COMPUTATION = re.compile(r"^(ENTRY )?%(\S+) \(.*\) -> .*\{$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(")

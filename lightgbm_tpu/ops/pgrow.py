@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.compilewatch import JitWatch
-from ..obs.phases import LEVEL_PHASE, REPLAY, REPLAY_TAIL, UPDATE_ROOT_HIST
+from ..obs.phases import LEVEL_PHASE, REPLAY, REPLAY_TAIL, SPLIT_SCAN, UPDATE_ROOT_HIST
 from .histogram_pallas import hist_segments
 from .pkernels import (
     BLK,
@@ -167,6 +167,10 @@ class PTreeResult(NamedTuple):
     rec_lcnt: jnp.ndarray
     rec_rcnt: jnp.ndarray
     rec_internal_value: jnp.ndarray
+    # what the level phase did for this tree (zeros under LEVELGROW=0), as
+    # (3,) int32: level_stream launches, the rows they streamed and the
+    # segments they partitioned, each summed over the levels
+    level_counts: jnp.ndarray = None
 
 
 class _PState(NamedTuple):
@@ -308,7 +312,15 @@ def grow_tree_partitioned(
         if levelwise:
             SMAX = min(-(-(L + 1) // 8) * 8, 512)
             CANDMAX = 2 * SMAX
-            MAXLVL = params.max_levels
+            # A table of 2 * SMAX candidates holds a complete tree of
+            # log2(SMAX) levels; one level more takes the slots that nodes
+            # without a split left free.  Every level searches all SMAX slots
+            # whatever it holds (2.1 GB of histograms at 2,000 columns), so a
+            # level after that would pay a whole search for the few slots
+            # still free, and whether a tree wanted one moved a 255-leaf
+            # iteration by 6% from one seed to the next.  Those splits are
+            # the replay's, one at a time.
+            MAXLVL = min(params.max_levels, (SMAX - 1).bit_length() + 1)
             c_seg0 = jnp.zeros((CANDMAX, 2), jnp.int32).at[0, 1].set(n)
             c_bs0 = jnp.full((CANDMAX, 8), NEG_INF, jnp.float32).at[0].set(root_bs)
             c_leaf0 = jnp.zeros((CANDMAX, 8), jnp.float32).at[0].set(root_leaf)
@@ -316,11 +328,13 @@ def grow_tree_partitioned(
             frontier0 = jnp.zeros((SMAX,), jnp.int32)  # slot 0 = root
 
             def lcond(s):
-                return (s[7] > 0) & (s[8] < MAXLVL)
+                # a frontier, a level left, and room for two children: a
+                # full table ends the phase (no level with nothing to take)
+                return (s[7] > 0) & (s[8] < MAXLVL) & (s[5] + 2 <= CANDMAX)
 
             def lbody(s):
                 (p, c_seg, c_bs, c_leaf, c_childlo, cand_n, frontier,
-                 frontier_n, level) = s
+                 frontier_n, level, streamed) = s
                 idx = jnp.arange(SMAX)
                 fvalid = idx < frontier_n
                 fslots = jnp.clip(frontier, 0, CANDMAX - 1)
@@ -364,13 +378,16 @@ def grow_tree_partitioned(
                 tots = c_leaf[aslots][:, 0:3]
                 rsums = tots - lsums
                 cdepth = c_leaf[aslots][:, 5] + 1.0
-                hist_l = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=0))(hists)
-                hist_r = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=7))(hists)
-                hist2 = jnp.stack([hist_l, hist_r], axis=1)  # (SMAX, 2, G, BH, 3)
                 sums2 = jnp.stack([lsums, rsums], axis=1)  # (SMAX, 2, 3)
                 dok2 = (jnp.ones((SMAX, 2), bool) if params.max_depth <= 0
                         else jnp.stack([cdepth < params.max_depth] * 2, axis=1))
-                res = jax.vmap(find2)(hist2, sums2, dok2)  # fields (SMAX, 2)
+                # the level's whole-array work: SMAX x 2 x G x BH cells, 29 MB
+                # of histograms a level at 28 columns, 2.1 GB at 2,000
+                with jax.named_scope(SPLIT_SCAN):
+                    hist_l = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=0))(hists)
+                    hist_r = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=7))(hists)
+                    hist2 = jnp.stack([hist_l, hist_r], axis=1)  # (SMAX, 2, G, BH, 3)
+                    res = jax.vmap(find2)(hist2, sums2, dok2)  # fields (SMAX, 2)
                 vals2 = leaf_output(sums2[..., 0], sums2[..., 1],
                                     hyper.lambda_l1, hyper.lambda_l2)  # (SMAX, 2)
                 il = jnp.where(arow, cand_n + 2 * idx, CANDMAX)
@@ -408,13 +425,16 @@ def grow_tree_partitioned(
                     jnp.stack([il, ir], axis=1).reshape(-1)[:SMAX], 0, CANDMAX - 1
                 )
                 return (p, c_seg, c_bs, c_leaf, c_childlo, cand_n + 2 * n_act,
-                        children, 2 * n_act, level + 1)
+                        children, 2 * n_act, level + 1,
+                        streamed + jnp.stack([jnp.sum(seg_tab[:, 1]), n_act]))
 
-            (p, c_seg, c_bs, c_leaf, c_childlo, _, _, _, _) = jax.lax.while_loop(
-                lcond, lbody,
-                (p, c_seg0, c_bs0, c_leaf0, c_childlo0, jnp.int32(1), frontier0,
-                 jnp.int32(1), jnp.int32(0)),
-            )
+            (p, c_seg, c_bs, c_leaf, c_childlo, _, _, _, levels, streamed) = (
+                jax.lax.while_loop(
+                    lcond, lbody,
+                    (p, c_seg0, c_bs0, c_leaf0, c_childlo0, jnp.int32(1), frontier0,
+                     jnp.int32(1), jnp.int32(0), jnp.zeros((2,), jnp.int32)),
+                ))
+            level_counts = jnp.concatenate([levels[None], streamed])
             pslot0 = jnp.full((L,), -1, jnp.int32).at[0].set(0)
         else:
             CANDMAX = 1
@@ -423,6 +443,7 @@ def grow_tree_partitioned(
             c_leaf = jnp.zeros((1, 8), jnp.float32)
             c_childlo = jnp.full((1,), -1, jnp.int32)
             pslot0 = jnp.full((L,), -1, jnp.int32)
+            level_counts = jnp.zeros((3,), jnp.int32)
 
     # ---- phase 2: exact best-first selection ------------------------
     st = _PState(
@@ -584,6 +605,7 @@ def grow_tree_partitioned(
             rec_lcnt=recs[:, 7],
             rec_rcnt=recs[:, 8],
             rec_internal_value=recs[:, 9],
+            level_counts=level_counts,
         )
     return res, st.p
 

@@ -52,6 +52,22 @@ runtime; not re-measured on this machine):
      anyway, and adding both children's histograms only widens the MXU
      operand from 7 to 14 sublanes — free on a 128-wide systolic array.
 
+Width (PR 29).  Every kernel here is ``grid=(1,)`` with hand-written DMA
+over whole (C, BLK) blocks, and a block is 2 MB at 2,000 columns (C =
+512), so no kernel loads one as a value.  A block stays in its VMEM
+buffer and the COMPUTE walks it in groups: the histograms in column
+groups of _HIST_GROUP_WORDS word rows (``col_groups``: a rolled loop
+over full groups, then what is left as one static group — all there is
+up to 31 columns), the permutation in groups of _PERM_GROUP_ROWS channel
+rows (``_for_row_groups``).  Program size therefore does not grow with
+the columns, and each kernel asks Mosaic for the VMEM its buffers need
+(``_vmem_params``; the histograms of ALL groups stay in VMEM: 16 MB of
+the v5e's 128 MiB at 2,000 columns x 63 bins).  A column's bins sit on a
+pitch of ``bin_pitch`` lanes in a histogram row (63 -> 64), so that a
+group starts on a lane tile whatever the bin count; ``_hist_from_rows``
+drops the padding cells, which stay zero.  Grouping decides only which
+cells share a loop trip: histograms are bit-identical under any.
+
 ``split_stream`` replaces the old partition + copy-back + child-histogram
 trio with ONE pass: a two-ended in-place partition (blocks are consumed
 from both ends of the segment so vacated space always precedes the write
@@ -83,6 +99,7 @@ per-thread buffer merge.)
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +112,18 @@ from .histogram_pallas import tune_fchunk
 BLK = 1024  # columns (data rows) per streamed chunk
 _LANE = 128  # DMA lane-alignment quantum
 _RING = 3  # read-buffer ring depth per stream end (max occupancy 2 + 1 inflight)
+_HIST_GROUP_WORDS = 8  # bin-word rows a rolled histogram group holds (one sublane tile)
+_PERM_GROUP_ROWS = 128  # channel rows a rolled permutation group holds
+# one-hot tile budget of the kernels whose VMEM the partition stream buffers
+# crowd (split_stream, level_stream): the historical 1 MiB
+_SPLIT_TILE_BYTES = 1024 * 1024
+_UPDATE_TILE_BYTES = 2 * 1024 * 1024  # tune_fchunk's default, the other kernels'
+# Mosaic's scoped VMEM default fits the kernels' scratch up to a few hundred
+# columns.  Past _VMEM_DEFAULT_FITS of scratch the limit is asked for: the
+# scratch plus room for the values Mosaic spills there (the (BLK, BLK)
+# permutation one-hots and iota, a one-hot tile, a row group's byte planes)
+_VMEM_DEFAULT_FITS = 6 * 1024 * 1024
+_VMEM_SPILL_ROOM = 40 * 1024 * 1024
 
 
 def num_words(num_features: int, bits: int = 8) -> int:
@@ -234,7 +263,7 @@ def pack_matrix_device(bins_dev, layout: PLayout, label=None, weight=None) -> jn
     return jnp.pad(p, ((0, cpad), (0, BLK)))
 
 
-def _planes(blk_i32, c):
+def _planes(blk_i32):
     """(C, BLK) int32 -> (4C, BLK) bf16 byte planes (exact in bf16)."""
     ps = [(blk_i32 >> (8 * k)) & 255 for k in range(4)]
     return jnp.concatenate(ps, axis=0).astype(jnp.bfloat16)
@@ -261,23 +290,170 @@ def _split3(x):
     return [hi, mid, lo]
 
 
+# ======================================================================
+# column groups: what lets the streaming kernels pass any width
+# ======================================================================
+class ColGroups(NamedTuple):
+    """How a streaming kernel walks the bin words of one (C, BLK) block when
+    it builds a histogram: ``n_full`` groups of ``gw`` word rows in a ROLLED
+    loop (program size does not grow with the columns), then the ``tail_w``
+    words that are left as one static group.  Up to a group's worth of
+    columns (28 columns: 7 words) the static group is all there is.  ANY
+    grouping gives bit-identical histograms: a group only decides which
+    (feature, bin) cells share a loop trip; each cell still contracts the
+    same BLK lanes in the same order."""
+
+    gw: int
+    n_full: int
+    tail_w: int
+
+    @property
+    def count(self) -> int:
+        return self.n_full + (1 if self.tail_w else 0)
+
+
+def col_groups(num_features: int, bits: int = 8) -> ColGroups:
+    """Derived from the shape alone, as ``tune_fchunk`` derives its chunk."""
+    gw = _HIST_GROUP_WORDS
+    n_full = num_features // (gw * (32 // bits))  # groups whose columns all exist
+    return ColGroups(gw, n_full, num_words(num_features, bits) - n_full * gw)
+
+
+def bin_pitch(num_bins: int) -> int:
+    """Lanes a column takes in a kernel's histogram row: its bins padded to
+    the sublane tile (63 -> 64), so that a column's one-hot rows stack on
+    tile boundaries and a group of _HIST_GROUP_WORDS words starts on a lane
+    tile of the accumulator whatever the bin count.  A padding cell's
+    one-hot row never matches: it stays zero, and ``_hist_from_rows`` drops
+    it.  (Keeping the pitch at the bin count while one static group holds
+    every column was tried on the chip, PR 29: level_stream then took 12%
+    longer at 28 columns than with the padded pitch.)"""
+    return -(-num_bins // 8) * 8
+
+
+def hist_lanes(num_features: int, num_bins: int) -> int:
+    """Lanes of one histogram row as the kernels issue it: F columns of
+    ``bin_pitch`` cells, padded to the lane tile."""
+    return -(-num_features * bin_pitch(num_bins) // _LANE) * _LANE
+
+
+def _group_fchunk(nfeat: int, pitch: int, max_tile_bytes: int) -> int:
+    """tune_fchunk for a full group: every chunk must start on a lane tile,
+    because the group's own start is dynamic."""
+    f = tune_fchunk(nfeat, pitch, max_tile_bytes=max_tile_bytes)
+    if f >= nfeat or (f * pitch) % _LANE == 0:
+        return f
+    return max((k for k in range(1, f) if (k * pitch) % _LANE == 0), default=nfeat)
+
+
+def _onehot_dots(words, vals, acc_ref, lane0, nfeat, *, pitch, bits, fchunk, iota_b):
+    """acc[0:nv, lane0 + f*pitch + b] += sum over lanes of vals * [bin f == b]
+    for the ``nfeat`` columns packed in ``words`` ((rows, BLK) int32), in
+    static chunks of ``fchunk`` columns: an (fchunk*pitch, BLK) bf16 one-hot
+    tile contracted on the MXU with the value rows on sublanes, so the
+    accumulator is (nv, F*pitch): lane-major, which copies out clean (an
+    (F*B, nv) output pays a strided VMEM->HBM copy measured at ~2 ms)."""
+    nv = vals.shape[0]
+    per = 32 // bits
+    mask = (1 << bits) - 1
+    for c0 in range(0, nfeat, fchunk):
+        c1 = min(c0 + fchunk, nfeat)
+        chunks = []
+        for f in range(c0, c1):
+            wd, p4 = divmod(f, per)
+            byte = (words[wd : wd + 1, :] >> (p4 * bits)) & mask
+            chunks.append((byte == iota_b).astype(jnp.bfloat16))
+        oh = jnp.concatenate(chunks, axis=0)
+        if isinstance(lane0, int):
+            lanes = slice(lane0 + c0 * pitch, lane0 + c1 * pitch)
+        else:
+            lanes = pl.ds(pl.multiple_of(lane0 + c0 * pitch, _LANE), (c1 - c0) * pitch)
+        acc_ref[0:nv, lanes] += jax.lax.dot_general(
+            vals, oh, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+
+def _hist_accumulate(blk_ref, vals, acc_ref, *, nf, nb, bits, max_tile_bytes):
+    """One resident block's share of a histogram: ``acc_ref[0:nv, f*pitch + b]
+    +=`` the one-hot dots of every column with the (nv, BLK) bf16 value
+    rows, column group by column group (``col_groups``).  ``blk_ref`` is
+    the (C, BLK) block in VMEM; only a group's word rows are loaded at a
+    time."""
+    grp = col_groups(nf, bits)
+    gf = grp.gw * (32 // bits)
+    pitch = bin_pitch(nb)
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (pitch, BLK), 0)
+    if grp.n_full:
+        fchunk = _group_fchunk(gf, pitch, max_tile_bytes)
+
+        def one_group(i, _):
+            words = blk_ref[pl.ds(pl.multiple_of(i * grp.gw, 8), grp.gw), :]
+            _onehot_dots(words, vals, acc_ref, i * (gf * pitch), gf, pitch=pitch,
+                         bits=bits, fchunk=fchunk, iota_b=iota_b)
+            return 0
+
+        jax.lax.fori_loop(0, grp.n_full, one_group, 0)
+    if grp.tail_w:
+        w0 = grp.n_full * grp.gw
+        tail_f = nf - grp.n_full * gf
+        words = blk_ref[w0 : w0 + -(-grp.tail_w // 8) * 8, :]
+        _onehot_dots(words, vals, acc_ref, grp.n_full * gf * pitch, tail_f,
+                     pitch=pitch, bits=bits, iota_b=iota_b,
+                     fchunk=tune_fchunk(tail_f, pitch, max_tile_bytes=max_tile_bytes))
+
+
+def _for_row_groups(c: int, fn) -> None:
+    """``fn(rows)`` over all ``c`` channel rows of a block, ``rows`` an
+    index for the sublane axis: whole groups of _PERM_GROUP_ROWS in a rolled
+    loop, then what is left (everything, up to one group) statically."""
+    rg = _PERM_GROUP_ROWS
+    n_full = c // rg if c > rg else 0
+    if n_full:
+        def one_group(i, _):
+            fn(pl.ds(pl.multiple_of(i * rg, 8), rg))
+            return 0
+
+        jax.lax.fori_loop(0, n_full, one_group, 0)
+    if c > n_full * rg:
+        fn(slice(n_full * rg, c))
+
+
+def _vmem_params(*scratch_bytes):
+    """Compiler parameters for a kernel whose VMEM scratch comes to
+    ``sum(scratch_bytes)``: none while the scoped default holds it (every
+    shape up to a few hundred columns), else the limit it needs (the v5e
+    has 128 MiB; at 2,000 columns level_stream asks for ~81 MiB)."""
+    need = int(sum(scratch_bytes))
+    if need <= _VMEM_DEFAULT_FITS:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need + _VMEM_SPILL_ROOM)
+
+
+def _tile_bytes(c: int, n: int = 1) -> int:
+    return n * c * BLK * 4
+
+
 def _hist_from_rows(out, num_features, num_bins, row0=0):
-    """(Σ 3-term g, Σ 3-term h, cnt) rows -> (F, B, 3) histogram."""
-    hist = jnp.stack(
-        [
-            out[row0 + 0] + (out[row0 + 1] + out[row0 + 2]),
-            out[row0 + 3] + (out[row0 + 4] + out[row0 + 5]),
-            out[row0 + 6],
-        ],
-        axis=1,
+    """(Σ 3-term g, Σ 3-term h, cnt) kernel rows of ``hist_lanes`` lanes
+    -> (F, B, 3) histogram."""
+    return _hist_cells(
+        out[row0 + 0] + (out[row0 + 1] + out[row0 + 2]),
+        out[row0 + 3] + (out[row0 + 4] + out[row0 + 5]),
+        out[row0 + 6],
+        num_features, num_bins,
     )
-    return hist.reshape(num_features, num_bins, 3)
+
+
+def _hist_cells(g, h, cnt, num_features, num_bins):
+    pitch = bin_pitch(num_bins)
+    hist = jnp.stack([g, h, cnt], axis=1)[: num_features * pitch]
+    return hist.reshape(num_features, pitch, 3)[:, :num_bins]
 
 
 # ======================================================================
 # histogram kernel (root histogram / standalone segments)
 # ======================================================================
-def _hist_kernel(sref, p_any, o_ref, acc_ref, buf_ref, sem, *, nf, nb, rows, c, fchunk, bits):
+def _hist_kernel(sref, p_any, o_ref, acc_ref, buf_ref, sem, *, nf, nb, rows, bits):
     start = sref[0]
     cnt = sref[1]
     g_row, h_row, sel_row = rows
@@ -293,7 +469,6 @@ def _hist_kernel(sref, p_any, o_ref, acc_ref, buf_ref, sem, *, nf, nb, rows, c, 
 
     get_dma(0, 0).start()
 
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (nb, BLK), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, BLK), 1)
 
     def body(j, _):
@@ -304,7 +479,7 @@ def _hist_kernel(sref, p_any, o_ref, acc_ref, buf_ref, sem, *, nf, nb, rows, c, 
             get_dma(1 - slot, j + 1).start()
 
         get_dma(slot, j).wait()
-        blk = buf_ref[slot]
+        blk = buf_ref.at[slot]
         pos = lane + j * BLK
         valid = ((pos >= head) & (pos < head + cnt)).astype(jnp.float32)
         sel = pltpu.bitcast(blk[sel_row : sel_row + 1, :], jnp.float32) * valid
@@ -314,24 +489,8 @@ def _hist_kernel(sref, p_any, o_ref, acc_ref, buf_ref, sem, *, nf, nb, rows, c, 
         vals = jnp.concatenate(
             _split3(g) + _split3(h) + [sel.astype(jnp.bfloat16)], axis=0
         )
-
-        per = 32 // bits
-        mask = (1 << bits) - 1
-        for c0 in range(0, nf, fchunk):
-            c1 = min(c0 + fchunk, nf)
-            chunks = []
-            for f in range(c0, c1):
-                wd, p4 = divmod(f, per)
-                byte = (blk[wd : wd + 1, :] >> (p4 * bits)) & mask
-                chunks.append((byte == iota_b).astype(jnp.bfloat16))
-            oh = jnp.concatenate(chunks, axis=0)
-            # (7, BLK) x (F_c*B, BLK) -> (7, F_c*B): value rows on sublanes
-            # so the accumulator/output is (8, F*B) — lane-major, which
-            # copies out clean (an (F*B, 7) output pays a strided
-            # VMEM->HBM copy measured at ~2 ms).
-            acc_ref[0:7, c0 * nb : c1 * nb] += jax.lax.dot_general(
-                vals, oh, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
+        _hist_accumulate(blk, vals, acc_ref, nf=nf, nb=nb, bits=bits,
+                         max_tile_bytes=_UPDATE_TILE_BYTES)
         return 0
 
     jax.lax.fori_loop(0, nblk, body, 0)
@@ -350,11 +509,9 @@ def hist_dyn(p, start, cnt, num_features, num_bins, bits=8, rows=None, interpret
         wpad = -(-num_words(num_features, bits) // 8) * 8
         rows = (wpad, wpad + 1, wpad + 2)
     c = p.shape[0]
-    fb = num_features * num_bins
-    fchunk = tune_fchunk(num_features, num_bins)
+    fb = hist_lanes(num_features, num_bins)
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, nf=num_features, nb=num_bins, rows=rows, c=c,
-                          fchunk=fchunk, bits=bits),
+        functools.partial(_hist_kernel, nf=num_features, nb=num_bins, rows=rows, bits=bits),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(1,),
@@ -367,6 +524,7 @@ def hist_dyn(p, start, cnt, num_features, num_bins, bits=8, rows=None, interpret
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((8, fb), jnp.float32),
+        compiler_params=_vmem_params(_tile_bytes(c, 2), 2 * 8 * fb * 4),
         interpret=interpret,
         name="hist_dyn",
     )(jnp.stack([jnp.int32(start), jnp.int32(cnt)]), p)
@@ -377,20 +535,21 @@ def hist_dyn(p, start, cnt, num_features, num_bins, bits=8, rows=None, interpret
 # update_and_root_hist: fused channel refresh + root histogram
 # ======================================================================
 def _upd_hist_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, abuf,
-                     stage, rsem, asem, wsem, sem_unused, *, nf, nb, rows, c,
-                     fchunk, bits, grad_fn, lay_rows, use_sel, use_mul,
-                     use_weight, n_delta, n_score, k_grad, with_hist=True):
+                     stage, rsem, asem, wsem, *, nf, nb, band0,
+                     bits, grad_fn, lay_rows, use_sel, use_mul,
+                     use_weight, n_delta, with_hist=True):
     """One streaming pass over ALL rows: score += delta, (g, h) =
-    grad_fn(score, label, weight), select = sel, block written back in
-    place, AND the root (F, B, 3) histogram accumulated from the fresh
-    values.  Structurally a copy of _hist_kernel (its DMA pattern
-    measures at full HBM bandwidth) plus a _stream_flush write-back.
+    grad_fn(score, label, weight), select = sel, the block's mutable band
+    written back in place (the bin words are read and never rewritten),
+    AND the root (F, B, 3) histogram accumulated from the fresh values.
+    Structurally a copy of _hist_kernel (its DMA pattern measures at full
+    HBM bandwidth) plus a _stream_flush write-back of the band.
 
     ``lay_rows`` = (G, H, SEL, SCORE, LABEL, ROWID, WEIGHT) absolute row
-    indices."""
+    indices; ``band0`` the first row of the band they sit in."""
     n = sref[0]
-    g_row, h_row, sel_row = rows
-    G_, H_, SEL_, SCORE_, LABEL_, ROWID_, WEIGHT_ = lay_rows
+    G_, H_, SEL_, SCORE_, LABEL_, ROWID_, WEIGHT_ = (r - band0 for r in lay_rows)
+    bandn = stage.shape[1]
     nblk = (n + BLK - 1) // BLK
     if with_hist:
         acc_ref[:, :] = jnp.zeros_like(acc_ref)
@@ -408,7 +567,6 @@ def _upd_hist_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, ab
     get_dma(0, 0).start()
     get_aux(0, 0).start()
 
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (nb, BLK), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, BLK), 1)
 
     def body(j, _):
@@ -421,17 +579,18 @@ def _upd_hist_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, ab
 
         get_dma(slot, j).wait()
         get_aux(slot, j).wait()
-        blk = buf_ref[slot]
+        blk = buf_ref.at[slot]
+        band = blk[band0 : band0 + bandn, :]
         aux = abuf[slot]
 
         # ---- channel update (single-class contract: multiclass runs
         # update_multi_and_hists instead)
-        scores = pltpu.bitcast(blk[SCORE_ : SCORE_ + 1, :], jnp.float32)
+        scores = pltpu.bitcast(band[SCORE_ : SCORE_ + 1, :], jnp.float32)
         if n_delta:
             scores = scores + aux[0:1, :]
-        label = pltpu.bitcast(blk[LABEL_ : LABEL_ + 1, :], jnp.float32)
+        label = pltpu.bitcast(band[LABEL_ : LABEL_ + 1, :], jnp.float32)
         weight = (
-            pltpu.bitcast(blk[WEIGHT_ : WEIGHT_ + 1, :], jnp.float32)
+            pltpu.bitcast(band[WEIGHT_ : WEIGHT_ + 1, :], jnp.float32)
             if use_weight else None
         )
         gv, hv = grad_fn(scores, label, weight)
@@ -447,15 +606,15 @@ def _upd_hist_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, ab
         if use_sel:
             selv = aux[7:8, :]
         else:
-            selv = pltpu.bitcast(blk[SEL_ : SEL_ + 1, :], jnp.float32)
-        out = blk
+            selv = pltpu.bitcast(band[SEL_ : SEL_ + 1, :], jnp.float32)
+        out = band
         out = _setrow(out, G_, pltpu.bitcast(gv, jnp.int32))
         out = _setrow(out, H_, pltpu.bitcast(hv, jnp.int32))
         if use_sel:
             out = _setrow(out, SEL_, pltpu.bitcast(selv, jnp.int32))
         if n_delta:
             out = _setrow(out, SCORE_, pltpu.bitcast(scores, jnp.int32))
-        _stream_flush(stage, wsem, p_any, out, j, j * BLK)
+        _stream_flush(stage, wsem, _band_block(p_any, band0, bandn, j * BLK), out, j)
 
         # ---- root histogram from the fresh values (skipped entirely for
         # histogram-free passes — GOSS's gradient-prep pass used to pay
@@ -469,19 +628,8 @@ def _upd_hist_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, ab
             vals = jnp.concatenate(
                 _split3(g) + _split3(h) + [sel.astype(jnp.bfloat16)], axis=0
             )
-            per = 32 // bits
-            mask = (1 << bits) - 1
-            for c0 in range(0, nf, fchunk):
-                c1 = min(c0 + fchunk, nf)
-                chunks = []
-                for f in range(c0, c1):
-                    wd, p4 = divmod(f, per)
-                    byte = (blk[wd : wd + 1, :] >> (p4 * bits)) & mask
-                    chunks.append((byte == iota_b).astype(jnp.bfloat16))
-                oh = jnp.concatenate(chunks, axis=0)
-                acc_ref[0:7, c0 * nb : c1 * nb] += jax.lax.dot_general(
-                    vals, oh, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-                )
+            _hist_accumulate(blk, vals, acc_ref, nf=nf, nb=nb, bits=bits,
+                             max_tile_bytes=_UPDATE_TILE_BYTES)
         return 0
 
     jax.lax.fori_loop(0, nblk, body, 0)
@@ -494,7 +642,7 @@ def _upd_hist_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, ab
 
 def update_and_root_hist(p, layout: PLayout, grad_fn, delta=None, sel=None,
                          mul=None, *, num_rows, num_features, num_bins,
-                         bits=8, rows=None, with_hist: bool = True,
+                         bits=8, with_hist: bool = True,
                          interpret: bool = False):
     """Fused per-iteration channel maintenance + root histogram: ONE
     streaming pass writes score += delta, fresh (g, h), bagging select —
@@ -507,12 +655,9 @@ def update_and_root_hist(p, layout: PLayout, grad_fn, delta=None, sel=None,
     the same matrix writes) with the histogram accumulation compiled
     out and returns (p, None) — the GOSS gradient-prep pass, which used
     to pay the full F*B one-hot/matmul work only to discard it."""
-    if rows is None:
-        rows = layout.rows
     ntot = p.shape[1]
     c = p.shape[0]
-    fb = num_features * num_bins
-    fchunk = tune_fchunk(num_features, num_bins)
+    fb = hist_lanes(num_features, num_bins)
 
     def fit(v):
         v = jnp.asarray(v, jnp.float32)
@@ -540,11 +685,9 @@ def update_and_root_hist(p, layout: PLayout, grad_fn, delta=None, sel=None,
     lay_rows = (layout.G, layout.H, layout.SEL, layout.SCORE, layout.LABEL,
                 layout.ROWID, layout.WEIGHT)
     kern = functools.partial(
-        _upd_hist_kernel, nf=num_features, nb=num_bins, rows=rows, c=c,
-        fchunk=fchunk, bits=bits, grad_fn=grad_fn, lay_rows=lay_rows,
+        _upd_hist_kernel, nf=num_features, nb=num_bins, band0=layout.WPAD, bits=bits, grad_fn=grad_fn, lay_rows=lay_rows,
         use_sel=use_sel, use_mul=use_mul, use_weight=layout.with_weight,
-        n_delta=n_delta, n_score=layout.num_score, k_grad=0,
-        with_hist=with_hist,
+        n_delta=n_delta, with_hist=with_hist,
     )
     p, out = pl.pallas_call(
         kern,
@@ -563,11 +706,10 @@ def update_and_root_hist(p, layout: PLayout, grad_fn, delta=None, sel=None,
                 pltpu.VMEM((8, fb), jnp.float32),
                 pltpu.VMEM((2, c, BLK), jnp.int32),
                 pltpu.VMEM((2, 8, BLK), jnp.float32),
-                pltpu.VMEM((2, c, BLK), jnp.int32),  # write stage
+                pltpu.VMEM((2, layout.BAND, BLK), jnp.int32),  # write stage
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA(()),
             ],
         ),
         out_shape=(
@@ -575,6 +717,7 @@ def update_and_root_hist(p, layout: PLayout, grad_fn, delta=None, sel=None,
             jax.ShapeDtypeStruct((8, fb), jnp.float32),
         ),
         input_output_aliases={2: 0},
+        compiler_params=_vmem_params(_tile_bytes(c, 2), 2 * 8 * fb * 4),
         interpret=interpret,
         name="update_and_root_hist",
     )(jnp.stack([jnp.int32(num_rows)]), aux, p)
@@ -587,15 +730,17 @@ def update_and_root_hist(p, layout: PLayout, grad_fn, delta=None, sel=None,
 # update_multi_and_hists: K gradient planes + K root histograms, one pass
 # ======================================================================
 def _upd_multi_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, abuf,
-                      stage, rsem, asem, wsem, *, nf, nb, c, fchunk, bits,
+                      stage, rsem, asem, wsem, *, nf, nb, bits,
                       grad_all_fn, lay, use_sel):
     """One streaming pass over ALL rows: (g_k, h_k) for EVERY class k from
     the score-channel snapshot (GBDT::Boosting computes all K gradient
     planes once per iteration, gbdt.cpp:692-700), bagging select, the
-    block written back in place, and ALL K root histograms accumulated —
-    the K value groups just widen the MXU operand (7K+... sublanes)."""
+    block's band written back in place, and ALL K root histograms
+    accumulated — the K value groups just widen the MXU operand (6K+1
+    sublanes)."""
     n = sref[0]
     K = lay.num_score
+    band0, bandn = lay.WPAD, lay.BAND
     nblk = (n + BLK - 1) // BLK
     acc_ref[:, :] = jnp.zeros_like(acc_ref)
 
@@ -612,8 +757,10 @@ def _upd_multi_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, a
     get_dma(0, 0).start()
     get_aux(0, 0).start()
 
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (nb, BLK), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, BLK), 1)
+
+    def brow(band, r, k=1):
+        return band[r - band0 : r - band0 + k, :]
 
     def body(j, _):
         slot = jax.lax.rem(j, 2)
@@ -625,13 +772,14 @@ def _upd_multi_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, a
 
         get_dma(slot, j).wait()
         get_aux(slot, j).wait()
-        blk = buf_ref[slot]
+        blk = buf_ref.at[slot]
+        band = blk[band0 : band0 + bandn, :]
         aux = abuf[slot]
 
-        scores = pltpu.bitcast(blk[lay.SCORE : lay.SCORE + K, :], jnp.float32)
-        label = pltpu.bitcast(blk[lay.LABEL : lay.LABEL + 1, :], jnp.float32)
+        scores = pltpu.bitcast(brow(band, lay.SCORE, K), jnp.float32)
+        label = pltpu.bitcast(brow(band, lay.LABEL), jnp.float32)
         weight = (
-            pltpu.bitcast(blk[lay.WEIGHT : lay.WEIGHT + 1, :], jnp.float32)
+            pltpu.bitcast(brow(band, lay.WEIGHT), jnp.float32)
             if lay.with_weight else None
         )
         gv, hv = grad_all_fn(scores, label, weight)  # (K, BLK) each
@@ -640,14 +788,14 @@ def _upd_multi_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, a
         if use_sel:
             selv = aux[7:8, :]
         else:
-            selv = pltpu.bitcast(blk[lay.SEL : lay.SEL + 1, :], jnp.float32)
-        out = blk
+            selv = pltpu.bitcast(brow(band, lay.SEL), jnp.float32)
+        out = band
         for k in range(K):
-            out = _setrow(out, lay.g_row(k), pltpu.bitcast(gv[k : k + 1], jnp.int32))
-            out = _setrow(out, lay.h_row(k), pltpu.bitcast(hv[k : k + 1], jnp.int32))
+            out = _setrow(out, lay.g_row(k) - band0, pltpu.bitcast(gv[k : k + 1], jnp.int32))
+            out = _setrow(out, lay.h_row(k) - band0, pltpu.bitcast(hv[k : k + 1], jnp.int32))
         if use_sel:
-            out = _setrow(out, lay.SEL, pltpu.bitcast(selv, jnp.int32))
-        _stream_flush(stage, wsem, p_any, out, j, j * BLK)
+            out = _setrow(out, lay.SEL - band0, pltpu.bitcast(selv, jnp.int32))
+        _stream_flush(stage, wsem, _band_block(p_any, band0, bandn, j * BLK), out, j)
 
         # ---- K root histograms from the fresh values
         pos = lane + j * BLK
@@ -658,20 +806,8 @@ def _upd_multi_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, a
             groups += _split3(gv[k : k + 1] * sel) + _split3(hv[k : k + 1] * sel)
         groups.append(sel.astype(jnp.bfloat16))
         vals = jnp.concatenate(groups, axis=0)  # (6K + 1, BLK)
-        per = 32 // bits
-        mask = (1 << bits) - 1
-        nv = 6 * K + 1
-        for c0 in range(0, nf, fchunk):
-            c1 = min(c0 + fchunk, nf)
-            chunks = []
-            for f in range(c0, c1):
-                wd, p4 = divmod(f, per)
-                byte = (blk[wd : wd + 1, :] >> (p4 * bits)) & mask
-                chunks.append((byte == iota_b).astype(jnp.bfloat16))
-            oh = jnp.concatenate(chunks, axis=0)
-            acc_ref[0:nv, c0 * nb : c1 * nb] += jax.lax.dot_general(
-                vals, oh, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
+        _hist_accumulate(blk, vals, acc_ref, nf=nf, nb=nb, bits=bits,
+                         max_tile_bytes=_UPDATE_TILE_BYTES)
         return 0
 
     jax.lax.fori_loop(0, nblk, body, 0)
@@ -688,8 +824,7 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
     K = layout.num_score
     ntot = p.shape[1]
     c = p.shape[0]
-    fb = num_features * num_bins
-    fchunk = tune_fchunk(num_features, num_bins)
+    fb = hist_lanes(num_features, num_bins)
     nv = 6 * K + 1
     nvpad = -(-nv // 8) * 8
 
@@ -702,7 +837,7 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
     use_sel = sel is not None
     aux = jnp.stack([zero] * 7 + [fit(sel) if use_sel else zero])
     kern = functools.partial(
-        _upd_multi_kernel, nf=num_features, nb=num_bins, c=c, fchunk=fchunk,
+        _upd_multi_kernel, nf=num_features, nb=num_bins,
         bits=bits, grad_all_fn=grad_all_fn, lay=layout, use_sel=use_sel,
     )
     p, out = pl.pallas_call(
@@ -722,7 +857,7 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
                 pltpu.VMEM((nvpad, fb), jnp.float32),
                 pltpu.VMEM((2, c, BLK), jnp.int32),
                 pltpu.VMEM((2, 8, BLK), jnp.float32),
-                pltpu.VMEM((2, c, BLK), jnp.int32),
+                pltpu.VMEM((2, layout.BAND, BLK), jnp.int32),  # write stage
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
@@ -733,6 +868,7 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
             jax.ShapeDtypeStruct((nvpad, fb), jnp.float32),
         ),
         input_output_aliases={2: 0},
+        compiler_params=_vmem_params(_tile_bytes(c, 2), 2 * nvpad * fb * 4),
         interpret=interpret,
         name="update_multi_and_hists",
     )(jnp.stack([jnp.int32(num_rows)]), aux, p)
@@ -741,9 +877,7 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
     for k in range(K):
         g = out[6 * k + 0] + (out[6 * k + 1] + out[6 * k + 2])
         h = out[6 * k + 3] + (out[6 * k + 4] + out[6 * k + 5])
-        hists.append(
-            jnp.stack([g, h, cnt], axis=1).reshape(num_features, num_bins, 3)
-        )
+        hists.append(_hist_cells(g, h, cnt, num_features, num_bins))
     return p, hists
 
 
@@ -859,19 +993,33 @@ def score_add(p, layout: PLayout, delta, k: int = 0, *, num_rows,
 # ======================================================================
 # split_stream: two-ended in-place partition + both-children histograms
 # ======================================================================
-def _stream_flush(stage, wsem, dst_any, merged, nstart, dst_off):
-    """Start one aligned BLK write via the double-buffered stage.  Caller
-    guarantees wait-before-reuse via _stage_wait."""
+def _band_block(p_any, band0, bandn, off):
+    """The band rows of the BLK columns at ``off``: what a channel update
+    writes back (band0 and bandn are multiples of the 8-row tile)."""
+    return p_any.at[band0 : band0 + bandn, pl.ds(off, BLK)]
+
+
+def _stage_wait(stage, wsem, nstart, flushing=True):
+    """Before write ``nstart`` refills its stage slot: wait for the write
+    that used the slot two starts ago."""
     slot = jax.lax.rem(nstart, 2)
 
-    @pl.when(nstart >= 2)
+    @pl.when(flushing & (nstart >= 2))
     def _():
         pltpu.make_async_copy(stage.at[slot], stage.at[slot], wsem.at[slot]).wait()
 
-    stage[slot] = merged
-    pltpu.make_async_copy(
-        stage.at[slot], dst_any.at[:, pl.ds(dst_off, BLK)], wsem.at[slot]
-    ).start()
+
+def _stage_start(stage, wsem, dst, nstart):
+    slot = jax.lax.rem(nstart, 2)
+    pltpu.make_async_copy(stage.at[slot], dst, wsem.at[slot]).start()
+
+
+def _stream_flush(stage, wsem, dst, merged, nstart):
+    """Start one aligned write of ``merged`` to the HBM block ``dst`` via
+    the double-buffered stage."""
+    _stage_wait(stage, wsem, nstart)
+    stage[jax.lax.rem(nstart, 2)] = merged
+    _stage_start(stage, wsem, dst, nstart)
 
 
 def _stream_drain(stage, wsem, nstarts):
@@ -886,9 +1034,9 @@ def _stream_drain(stage, wsem, nstarts):
 
 def _run_segment(
     p_any, hist_ref, scalars,
-    bufF, bufB, carL, carR, stageL, stageR, tri_ref,
+    buf, carL, carR, stageL, stageR, tri_ref,
     rsemF, rsemB, csemL, csemR, wsemL, wsemR,
-    *, c, bits, nf, nb, rows, fchunk,
+    *, c, bits, nf, nb, rows,
 ):
     """One pass over one parent segment: stable-unordered in-place
     partition by the split predicate + (F, B, 3) histograms of BOTH
@@ -902,7 +1050,18 @@ def _run_segment(
     Before classifying, any side whose vacated space hit zero is topped
     up with a demand read; a flush whose target block is the other side's
     in-flight read waits that read first.  Invariants guarantee writes
-    only ever land on blocks already read."""
+    only ever land on blocks already read.
+
+    Past a few hundred columns a (C, BLK) block is too large to hold as
+    one value, so the block stays in its VMEM buffer and is worked on in
+    groups of channel rows: the left/right decision reads the one word row
+    that holds the split column, the histograms walk the bin words group
+    by group (``_hist_accumulate``), and the permutation the decision
+    implies is applied to ``_PERM_GROUP_ROWS`` rows at a time
+    (``_for_row_groups``), each group merged into the carries and the
+    write stages before the next is loaded.  ``buf`` is the two read
+    rings in one array (front slots 0.._RING-1, back slots _RING..) so
+    the hand block is one dynamic slot of it."""
     (start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
      off_lo, off_hi, bias) = scalars
     # EFB bundle range remap (feature_group.h PushData layout): the
@@ -937,21 +1096,19 @@ def _run_segment(
     def dmaF(k):  # k-th front read = block k
         slot = jax.lax.rem(k, _RING)
         return pltpu.make_async_copy(
-            p_any.at[:, pl.ds(base + k * BLK, BLK)], bufF.at[slot], rsemF.at[slot]
+            p_any.at[:, pl.ds(base + k * BLK, BLK)], buf.at[slot], rsemF.at[slot]
         )
 
     def dmaB(k):  # k-th back read = block nblk-1-k
         slot = jax.lax.rem(k, _RING)
         return pltpu.make_async_copy(
             p_any.at[:, pl.ds(base + (nblk - 1 - k) * BLK, BLK)],
-            bufB.at[slot],
+            buf.at[_RING + slot],
             rsemB.at[slot],
         )
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, BLK), 1)
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (nb, BLK), 0)
-    per = 32 // bits
+    iota_8 = jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0)
     vmask = (1 << bits) - 1
 
     def body(j, st):
@@ -1023,20 +1180,22 @@ def _run_segment(
 
         cb = cb + doCB
 
-        # ---- hand block
+        # ---- hand block: it stays in its ring slot
         useF = (cf - kf) > 0
-        slotF = jax.lax.rem(kf, _RING)
-        slotB = jax.lax.rem(kb, _RING)
-        hand = jnp.where(useF, bufF[slotF], bufB[slotB])
+        hand = buf.at[jnp.where(useF, jax.lax.rem(kf, _RING),
+                                _RING + jax.lax.rem(kb, _RING))]
         jh = jnp.where(useF, kf, nblk - 1 - kb)
         kf = kf + useF
         kb = kb + (~useF)
 
         # ---- classify: split predicate (DataPartition::Split fused with
-        # the DefaultValueForZero bin remap of dense_bin.hpp:191-232)
+        # the DefaultValueForZero bin remap of dense_bin.hpp:191-232) on
+        # the split column's word row, picked out of its 8-row tile
         pos = lane + jh * BLK
         valid = (pos >= head) & (pos < E)
-        wordrow = jnp.sum(jnp.where(iota_c == word, hand, 0), axis=0, keepdims=True)
+        w8 = pl.multiple_of((word // 8) * 8, 8)
+        wordrow = jnp.sum(jnp.where(iota_8 == word - w8, hand[pl.ds(w8, 8), :], 0),
+                          axis=0, keepdims=True)
         binv = (wordrow >> shift) & vmask
         in_range = (binv >= off_lo) & (binv < off_hi)
         fb = jnp.where(in_range, binv - off_lo + bias, zero_bin)
@@ -1060,19 +1219,11 @@ def _run_segment(
             + _split3(gv * grm) + _split3(hv * grm) + [(selv * grm).astype(jnp.bfloat16)],
             axis=0,
         )  # (14, BLK)
-        for c0 in range(0, nf, fchunk):
-            c1 = min(c0 + fchunk, nf)
-            chunks = []
-            for f in range(c0, c1):
-                wd, p4 = divmod(f, per)
-                byte = (hand[wd : wd + 1, :] >> (p4 * bits)) & vmask
-                chunks.append((byte == iota_b).astype(jnp.bfloat16))
-            oh = jnp.concatenate(chunks, axis=0)
-            hist_ref[0:14, c0 * nb : c1 * nb] += jax.lax.dot_general(
-                vals, oh, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
+        _hist_accumulate(hand, vals, hist_ref, nf=nf, nb=nb, bits=bits,
+                         max_tile_bytes=_SPLIT_TILE_BYTES)
 
-        # ---- in-block compaction via permutation matmuls
+        # ---- in-block compaction via permutation matmuls: where each
+        # lane goes, once for the block
         lr = jnp.concatenate(
             [glm.astype(jnp.bfloat16), grm.astype(jnp.bfloat16)], axis=0
         )  # (2, BLK)
@@ -1084,21 +1235,12 @@ def _run_segment(
         cumR = cum2[1:2]
         cntl = jnp.max(cumL)
         cntr = jnp.max(cumR)
-        planes = _planes(hand, c)
         tgtL = cl + cumL - 1
         tgtL = tgtL - jnp.where(tgtL >= BLK, BLK, 0)
         ohL = (gl & (ii == tgtL)).astype(jnp.bfloat16)
         tgtR = BLK - cr - cumR
         tgtR = tgtR + jnp.where(tgtR < 0, BLK, 0)
         ohR = (gr & (ii == tgtR)).astype(jnp.bfloat16)
-        permL = _unplanes(
-            jax.lax.dot_general(planes, ohL, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32), c
-        )
-        permR = _unplanes(
-            jax.lax.dot_general(planes, ohR, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32), c
-        )
 
         # ---- left flush (forward, into front-vacated space)
         tL = cl + cntl
@@ -1118,15 +1260,6 @@ def _run_segment(
             dmaF(cf).wait()
 
         cf = cf + nwF
-        mergedL = jnp.where(lane < cl, carL[:, :], permL)
-
-        @pl.when(flushL)
-        def _():
-            _stream_flush(stageL, wsemL, p_any, mergedL, fl, base + fl * BLK)
-
-        carL[:, :] = jnp.where(flushL, permL, mergedL)
-        cl = jnp.where(flushL, tL - BLK, tL)
-        fl = fl + flushL
 
         # ---- right flush (backward, into back-vacated space)
         tR = cr + cntr
@@ -1146,13 +1279,53 @@ def _run_segment(
             dmaF(cf).wait()
 
         cf = cf + nwF2
-        mergedR = jnp.where(lane >= BLK - cr, carR[:, :], permR)
+
+        # ---- the permutation itself, a group of channel rows at a time:
+        # the four byte planes of the group's rows through the two
+        # one-hots, merged under the carries; a side that fills a block
+        # stages it for its write, and its carry keeps the overflow
+        _stage_wait(stageL, wsemL, fl, flushL)
+        _stage_wait(stageR, wsemR, fr, flushR)
+        slotL = jax.lax.rem(fl, 2)
+        slotR = jax.lax.rem(fr, 2)
+
+        def permute(rws):
+            planes = _planes(hand[rws, :])
+            nrow = planes.shape[0] // 4
+            permL = _unplanes(
+                jax.lax.dot_general(planes, ohL, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32), nrow
+            )
+            permR = _unplanes(
+                jax.lax.dot_general(planes, ohR, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32), nrow
+            )
+            mergedL = jnp.where(lane < cl, carL[rws, :], permL)
+            mergedR = jnp.where(lane >= BLK - cr, carR[rws, :], permR)
+
+            @pl.when(flushL)
+            def _():
+                stageL[slotL, rws, :] = mergedL
+
+            @pl.when(flushR)
+            def _():
+                stageR[slotR, rws, :] = mergedR
+
+            carL[rws, :] = jnp.where(flushL, permL, mergedL)
+            carR[rws, :] = jnp.where(flushR, permR, mergedR)
+
+        _for_row_groups(c, permute)
+
+        @pl.when(flushL)
+        def _():
+            _stage_start(stageL, wsemL, p_any.at[:, pl.ds(base + fl * BLK, BLK)], fl)
 
         @pl.when(flushR)
         def _():
-            _stream_flush(stageR, wsemR, p_any, mergedR, fr, base + rtgt * BLK)
+            _stage_start(stageR, wsemR, p_any.at[:, pl.ds(base + rtgt * BLK, BLK)], fr)
 
-        carR[:, :] = jnp.where(flushR, permR, mergedR)
+        cl = jnp.where(flushL, tL - BLK, tL)
+        fl = fl + flushL
         cr = jnp.where(flushR, tR - BLK, tR)
         fr = fr + flushR
 
@@ -1188,8 +1361,14 @@ def _run_segment(
 
     @pl.when(has_mid)
     def _():
-        merged = jnp.where(lane < cl, carL[:, :], carR[:, :])
-        _stream_flush(stageL, wsemL, p_any, merged, fl, base + fl * BLK)
+        _stage_wait(stageL, wsemL, fl)
+        slot = jax.lax.rem(fl, 2)
+
+        def merge(rws):
+            stageL[slot, rws, :] = jnp.where(lane < cl, carL[rws, :], carR[rws, :])
+
+        _for_row_groups(c, merge)
+        _stage_start(stageL, wsemL, p_any.at[:, pl.ds(base + fl * BLK, BLK)], fl)
 
     _stream_drain(stageL, wsemL, fl + has_mid)
     _stream_drain(stageR, wsemR, fr)
@@ -1206,6 +1385,30 @@ def _run_segment(
     return fl * BLK + cl - head
 
 
+def _partition_bytes(c: int) -> int:
+    return _tile_bytes(c, 2 * _RING + 6) + BLK * BLK * 2
+
+
+def level_stream_vmem_bytes(num_cols: int, num_bins: int, num_score: int = 1,
+                            bits: int = 8) -> int:
+    """VMEM scratch of the largest streaming kernel at this width: what
+    ``eligible`` holds against the chip's budget."""
+    c = PLayout(num_cols, num_score=num_score, bits=bits).C
+    return _partition_bytes(c) + 2 * 16 * hist_lanes(num_cols, num_bins) * 4
+
+
+def _partition_scratch(c: int) -> list:
+    """VMEM the two-ended partition holds whatever the histogram's size."""
+    return [
+        pltpu.VMEM((2 * _RING, c, BLK), jnp.int32),  # read rings: front, back
+        pltpu.VMEM((c, BLK), jnp.int32),  # carL
+        pltpu.VMEM((c, BLK), jnp.int32),  # carR
+        pltpu.VMEM((2, c, BLK), jnp.int32),  # stageL
+        pltpu.VMEM((2, c, BLK), jnp.int32),  # stageR
+        pltpu.VMEM((BLK, BLK), jnp.bfloat16),  # tri
+    ]
+
+
 def _build_tri(tri_ref):
     """Triangular cumsum operand, built once per kernel (cheaper than an
     HBM-resident constant: reading a 2 MB tri per pass costs more than
@@ -1217,9 +1420,9 @@ def _build_tri(tri_ref):
 
 def _split_kernel(
     sref, p_in, p_any, hist_ref, nl_ref,
-    bufF, bufB, carL, carR, stageL, stageR, tri_ref,
+    buf, carL, carR, stageL, stageR, tri_ref,
     rsemF, rsemB, csemL, csemR, wsemL, wsemR,
-    *, c, bits, nf, nb, rows, fchunk,
+    *, c, bits, nf, nb, rows,
 ):
     """Single-segment wrapper over _run_segment (the classic per-split
     launch; grow_tree_partitioned's deep tail and standalone callers)."""
@@ -1227,18 +1430,18 @@ def _split_kernel(
     hist_ref[:, :] = jnp.zeros_like(hist_ref)
     scalars = tuple(sref[k] for k in range(11))
     nl = _run_segment(
-        p_any, hist_ref, scalars, bufF, bufB, carL, carR, stageL, stageR,
+        p_any, hist_ref, scalars, buf, carL, carR, stageL, stageR,
         tri_ref, rsemF, rsemB, csemL, csemR, wsemL, wsemR,
-        c=c, bits=bits, nf=nf, nb=nb, rows=rows, fchunk=fchunk,
+        c=c, bits=bits, nf=nf, nb=nb, rows=rows,
     )
     nl_ref[0] = nl
 
 
 def _level_kernel(
     sref, p_in, p_any, hist_out, nl_ref,
-    bufF, bufB, carL, carR, stageL, stageR, tri_ref, hacc,
+    buf, carL, carR, stageL, stageR, tri_ref, hacc,
     rsemF, rsemB, csemL, csemR, wsemL, wsemR, hsem,
-    *, c, bits, nf, nb, rows, fchunk, smax,
+    *, c, bits, nf, nb, rows,
 ):
     """One launch per tree LEVEL: partition EVERY active leaf segment by
     its chosen split and emit both children's histograms per segment —
@@ -1265,10 +1468,10 @@ def _level_kernel(
         hacc[slot] = jnp.zeros_like(hacc[slot])
         scalars = tuple(sref[1 + s, k] for k in range(11))
         nl = _run_segment(
-            p_any, hacc.at[slot], scalars, bufF, bufB, carL, carR,
+            p_any, hacc.at[slot], scalars, buf, carL, carR,
             stageL, stageR, tri_ref, rsemF, rsemB, csemL, csemR,
             wsemL, wsemR,
-            c=c, bits=bits, nf=nf, nb=nb, rows=rows, fchunk=fchunk,
+            c=c, bits=bits, nf=nf, nb=nb, rows=rows,
         )
         nl_ref[s] = nl
         pltpu.make_async_copy(hacc.at[slot], hist_out.at[s], hsem.at[slot]).start()
@@ -1299,25 +1502,20 @@ def level_stream(p, seg_tab, n_active, *, num_features, num_bins, bits=8,
 
     seg_tab: (smax, 12) int32 rows [start, cnt, word, shift, zero_bin,
     dbz, thr, is_cat, off_lo, off_hi, bias, 0] (same scalar contract as
-    split_stream).  Returns (p', nl (smax,), hists (smax, 16, F*B)) —
+    split_stream).  Returns (p', nl (smax,), hists (smax, 16, hist_lanes)) —
     hist rows 0:7 = left child (3-plane g, 3-plane h, count), 7:14 =
     right child; rows for s >= n_active are undefined."""
     if rows is None:
         wpad = -(-num_words(num_features, bits) // 8) * 8
         rows = (wpad, wpad + 1, wpad + 2)
     c = p.shape[0]
-    fb = num_features * num_bins
     # sliced VMEM refs (hacc.at[slot]) must be lane-tile (128) aligned
-    fbp = -(-fb // _LANE) * _LANE
-    # split/level kernels: VMEM is crowded by the partition stream
-    # buffers, so cap the one-hot tile at the historical 1 MiB
-    fchunk = tune_fchunk(num_features, num_bins,
-                         max_tile_bytes=1024 * 1024)
+    fbp = hist_lanes(num_features, num_bins)
     hdr = jnp.zeros((1, 12), jnp.int32).at[0, 0].set(jnp.int32(n_active))
     sv = jnp.concatenate([hdr, seg_tab.astype(jnp.int32)], axis=0)
     p, hist, nl = pl.pallas_call(
         functools.partial(_level_kernel, c=c, bits=bits, nf=num_features,
-                          nb=num_bins, rows=rows, fchunk=fchunk, smax=smax),
+                          nb=num_bins, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(1,),
@@ -1327,14 +1525,7 @@ def level_stream(p, seg_tab, n_active, *, num_features, num_bins, bits=8,
                 pl.BlockSpec(memory_space=pl.ANY),  # hists (DMA'd per segment)
                 pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((_RING, c, BLK), jnp.int32),  # bufF
-                pltpu.VMEM((_RING, c, BLK), jnp.int32),  # bufB
-                pltpu.VMEM((c, BLK), jnp.int32),  # carL
-                pltpu.VMEM((c, BLK), jnp.int32),  # carR
-                pltpu.VMEM((2, c, BLK), jnp.int32),  # stageL
-                pltpu.VMEM((2, c, BLK), jnp.int32),  # stageR
-                pltpu.VMEM((BLK, BLK), jnp.bfloat16),  # tri
+            scratch_shapes=_partition_scratch(c) + [
                 pltpu.VMEM((2, 16, fbp), jnp.float32),  # hacc (double-buffered)
                 pltpu.SemaphoreType.DMA((_RING,)),  # rsemF
                 pltpu.SemaphoreType.DMA((_RING,)),  # rsemB
@@ -1351,10 +1542,11 @@ def level_stream(p, seg_tab, n_active, *, num_features, num_bins, bits=8,
             jax.ShapeDtypeStruct((smax,), jnp.int32),
         ),
         input_output_aliases={1: 0},
+        compiler_params=_vmem_params(_partition_bytes(c), 2 * 16 * fbp * 4),
         interpret=interpret,
         name="level_stream",
     )(sv, p)
-    return p, nl, hist[:, :, :fb]
+    return p, nl, hist
 
 
 @functools.partial(jax.jit, static_argnames=("num_features", "num_bins", "bits", "rows", "interpret"),
@@ -1373,11 +1565,7 @@ def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
         wpad = -(-num_words(num_features, bits) // 8) * 8
         rows = (wpad, wpad + 1, wpad + 2)
     c = p.shape[0]
-    fb = num_features * num_bins
-    # split/level kernels: VMEM is crowded by the partition stream
-    # buffers, so cap the one-hot tile at the historical 1 MiB
-    fchunk = tune_fchunk(num_features, num_bins,
-                         max_tile_bytes=1024 * 1024)
+    fb = hist_lanes(num_features, num_bins)
     sv = jnp.stack(
         [
             jnp.int32(start), jnp.int32(cnt), jnp.int32(word), jnp.int32(shift),
@@ -1387,7 +1575,7 @@ def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
     )
     p, hist, nl = pl.pallas_call(
         functools.partial(_split_kernel, c=c, bits=bits, nf=num_features,
-                          nb=num_bins, rows=rows, fchunk=fchunk),
+                          nb=num_bins, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(1,),
@@ -1397,14 +1585,7 @@ def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
                 pl.BlockSpec(memory_space=pltpu.VMEM),
                 pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((_RING, c, BLK), jnp.int32),  # bufF
-                pltpu.VMEM((_RING, c, BLK), jnp.int32),  # bufB
-                pltpu.VMEM((c, BLK), jnp.int32),  # carL
-                pltpu.VMEM((c, BLK), jnp.int32),  # carR
-                pltpu.VMEM((2, c, BLK), jnp.int32),  # stageL
-                pltpu.VMEM((2, c, BLK), jnp.int32),  # stageR
-                pltpu.VMEM((BLK, BLK), jnp.bfloat16),  # tri
+            scratch_shapes=_partition_scratch(c) + [
                 pltpu.SemaphoreType.DMA((_RING,)),  # rsemF
                 pltpu.SemaphoreType.DMA((_RING,)),  # rsemB
                 pltpu.SemaphoreType.DMA(()),  # csemL
@@ -1419,6 +1600,7 @@ def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ),
         input_output_aliases={1: 0},
+        compiler_params=_vmem_params(_partition_bytes(c), 16 * fb * 4),
         interpret=interpret,
         name="split_stream",
     )(sv, p)

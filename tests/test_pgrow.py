@@ -763,6 +763,57 @@ class TestLevelGrowerCaps:
         # level-batched growth is tree-identical to per-split growth
         np.testing.assert_array_equal(preds["1"], preds["0"])
 
+    @pytest.mark.parametrize("num_leaves,rows,min_hess,binds", [
+        (31, 6000, 1e-3, "table"), (63, 3000, 4.0, "levels")])
+    def test_level_phase_is_bounded_by_its_table(self, monkeypatch, num_leaves, rows,
+                                                 min_hess, binds):
+        """The level phase runs at most log2(SMAX) + 1 levels and none on a
+        full candidate table (each one searches all SMAX slots whatever it
+        holds); what it leaves is the replay's tail, and the trees are those
+        of the per-split grower."""
+        import lightgbm_tpu as lgb
+
+        rng = np.random.default_rng(5)
+        X = rng.standard_normal((rows, 6)).astype(np.float32)
+        w = rng.standard_normal(6)
+        y = (rng.random(rows) < 1 / (1 + np.exp(-(X @ w)))).astype(np.float32)
+        params = dict(objective="binary", num_leaves=num_leaves, learning_rate=0.3,
+                      max_bin=31, min_data_in_leaf=1, min_sum_hessian_in_leaf=min_hess,
+                      verbose=-1)
+        monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+        counts, preds, leaves = [], {}, {}
+        for mode in ("1", "0"):
+            monkeypatch.setenv("LIGHTGBM_TPU_LEVELGROW", mode)
+            bst = lgb.Booster(params=params,
+                              train_set=lgb.Dataset(X, label=y, params=dict(params)))
+            pt = bst.boosting.ptrainer
+            chunk = pt.train_chunk
+
+            def keep(*a, _chunk=chunk, **k):
+                out = _chunk(*a, **k)
+                counts.extend(out[0]["levels"][:, 0].tolist())
+                return out
+
+            pt.train_chunk = keep
+            bst.boosting.train_iters_partitioned(6, is_eval=False)
+            preds[mode] = bst.predict(X)
+            leaves[mode] = [t.num_leaves for t in bst.boosting.models]
+        np.testing.assert_array_equal(preds["1"], preds["0"])
+        assert leaves["1"] == leaves["0"] and max(leaves["1"]) == num_leaves
+        levels, _, segments = np.array(counts[:6]).T  # LEVELGROW=0 counts none
+        assert not np.array(counts[6:]).any()
+        smax = -(-(num_leaves + 1) // 8) * 8
+        cap = (smax - 1).bit_length() + 1
+        assert levels.max() <= cap and segments.max() <= smax - 1, counts
+        if binds == "table":
+            # balanced trees fill the 2 * SMAX slots in the doubling levels:
+            # the phase ends there, with no level that holds no segment
+            assert (levels == cap - 1).all() and (segments == smax - 1).all(), counts
+        else:
+            # unbalanced trees leave slots free after the last level: the
+            # tail takes those splits, and the trees above are still full
+            assert (levels == cap).all() and segments.min() < smax - 1, counts
+
 
 class TestScoreAddBand:
     """score_add streams ONLY the 8-aligned mutable band (PR-6 fused

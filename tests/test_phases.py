@@ -196,7 +196,7 @@ def test_parser_on_text_without_a_module():
 
 
 def test_vocabulary_is_flat_and_enclosing_is_inside_it():
-    assert len(set(PHASES)) == len(PHASES) == 9
+    assert len(set(PHASES)) == len(PHASES) == 10
     assert all(inner in PHASES and outer in PHASES for inner, outer in ENCLOSING.items())
 
 
@@ -341,6 +341,25 @@ def test_nested_spans_are_each_right_alone(traced_chunks):
     assert [t["trees"] for t in trees] == [2, 2]
     models = bst.boosting.models
     assert sum(t["splits"] for t in trees) == sum(int(m.num_leaves) - 1 for m in models)
+
+
+def test_trees_from_records_carries_the_stream_counts(traced_chunks):
+    """Beside `trees` and `splits`: what the operation and byte models of the
+    streaming kernels are made of (benchmarks/harness/hist_ops.py)."""
+    from lightgbm_tpu.ops.pkernels import hist_lanes
+
+    bst, recs, _, _ = traced_chunks
+    pt = bst.boosting.ptrainer
+    n, f, b = pt.num_rows, pt.params.num_features, pt.params.num_bins
+    for t in (r for r in recs if r["ev"] == "span" and r["name"] == "trees_from_records"):
+        assert t["channels"] == pt.layout.C == 16 and t["col_groups"] == 1
+        assert t["hist_cells"] == hist_lanes(f, b) and t["hist_cells"] % 128 == 0
+        assert t["hist_cells"] >= f * b
+        # two trees a chunk: every tree's first level streams every row once,
+        # and no level streams a row twice
+        assert 2 <= t["levels"] <= 2 * pt.params.max_levels
+        assert 2 * n <= t["level_rows"] <= t["levels"] * n
+        assert t["levels"] <= t["level_segments"] <= 2 * (pt.params.num_leaves - 1) * t["levels"]
 
 
 @pytest.mark.parametrize("name", ["chunk_program", "records_fetch"])

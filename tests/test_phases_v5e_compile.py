@@ -70,19 +70,19 @@ def _compiling_for_a_described_chip():
             os.environ["LIGHTGBM_TPU_PGROW"] = old
 
 
-def _small_trainer(**more):
+def _small_trainer(rows=ROWS, cols=28, small_rows=20_000, **more):
     """A fused trainer on a small table: its closures do not depend on the row
     count, so it is then told the real one."""
     import lightgbm_tpu as lgb
 
     params = dict(PARAMS, **more)
     rng = np.random.RandomState(7)
-    X = rng.randn(20_000, 28)
+    X = rng.randn(small_rows, cols)
     y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(float)
     bst = lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y, params=dict(params)))
     pt = bst.boosting.ptrainer
-    pt.num_rows = ROWS
-    pt.params = pt.params._replace(num_rows=ROWS)
+    pt.num_rows = rows
+    pt.params = pt.params._replace(num_rows=rows)
     pt.interpret = False
     return pt
 
@@ -219,3 +219,69 @@ def test_sharded_program_copies_no_shard(sharded_text):
     assert re.findall(r" = s32\[(?:1,)?16,21001024\]\S* copy\(", sharded_text) == []
     assert len(re.findall(r" conditional\(", sharded_text)) == 1
     assert len(re.findall(r" all-reduce(?:-start)?\(", sharded_text)) == 3
+
+
+# -- the wide configuration (benchmarks/configs/epsilon.json) ----------------
+EPS_ROWS, EPS_COLS = 400_000, 2_000
+EPS_MATRIX = f"s32[512,{EPS_ROWS + 1024}]"
+QUARTER_OF_THE_CHIP = 4.29e9
+
+
+@pytest.fixture(scope="module")
+def epsilon_compiled(one_chip):
+    """The serial chunk program at 400,000 rows x 2,000 columns (the parameters
+    are the same published ones), compiled for the described chip: (text,
+    argument bytes, temporary bytes)."""
+    with _compiling_for_a_described_chip():
+        pt = _small_trainer(rows=EPS_ROWS, cols=EPS_COLS, small_rows=4096)
+        assert type(pt).__name__ == "PartitionedTrainer" and pt.p.shape[0] == 512
+        prog = pt._build_program(pt.CHUNK_ALLOC, False, 1, EPS_COLS)
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        key = pt._base_key
+        compiled = prog.lower(
+            spec((512, EPS_ROWS + 1024), jnp.int32), spec((), jnp.float32),
+            spec(key.shape, key.dtype), spec((), jnp.int32), spec((), jnp.int32)).compile()
+        mem = compiled.memory_analysis()
+        return compiled.as_text(), mem.argument_size_in_bytes, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("kernel", ["update_and_root_hist", "level_stream", "split_stream",
+                                    "score_add"])
+def test_epsilon_kernels_compile_through_mosaic(epsilon_compiled, kernel):
+    """Every kernel the K = 1 chunk program reaches is a Mosaic custom call at
+    512 channel rows and 128,000 histogram lanes: the compiler accepted the
+    rolled column groups and the VMEM each kernel asks for."""
+    text = epsilon_compiled[0]
+    assert re.findall(rf'%({kernel}(?:\.\d+)?) = .*custom_call_target="tpu_custom_call"', text)
+
+
+def test_epsilon_program_keeps_the_carry_contract(epsilon_compiled):
+    """PR 27's rule at width: no copy of the matrix that keeps its layout (what
+    copy insertion makes for a conditional that carries it), and the one
+    conditional is the replay's over the small tables.  What IS there once a
+    tree: the canonical reorder's gather, which at 512 channel rows XLA makes
+    as a transposing copy to row-major, a gather of 2 KB rows and a transpose
+    back inside the update fusion (at 16 channel rows it is one strided-gather
+    fusion, `fusion s32[21000000,16]`); ROADMAP S2 deletes the step."""
+    text = epsilon_compiled[0]
+    pm = parse_hlo_phases(text)
+    assert pm["matrix"] == EPS_MATRIX
+    copies = re.findall(rf" = s32\[512,{EPS_ROWS + 1024}\](\{{[\d,]*)\S* copy\(", text)
+    assert [layout for layout in copies if layout == "{1,0"] == []
+    assert {pm["ops"][c] for c in pm["matrix_copies"]} <= {"canon_reorder"}
+    assert len(pm["matrix_copies"]) <= 1
+    assert len(re.findall(r" conditional\(", text)) == 1
+    assert "split_scan" in pm["ops"].values()
+
+
+def test_epsilon_training_fills_the_chip(epsilon_compiled):
+    """What training holds, the program's arguments and temporaries, is over
+    the contract's floor for a cell (a quarter of the chip) and inside the
+    chip: the configuration's rows stay the published 400,000
+    (benchmarks/configs/epsilon.json, reduced_detail)."""
+    _, args, temps = epsilon_compiled
+    assert QUARTER_OF_THE_CHIP < args + temps < 14e9
+    assert 0.8e9 < args < 0.9e9  # the packed matrix, 2,048 bytes a row
