@@ -761,7 +761,7 @@ class GBDT:
             tracer.enabled
             and getattr(pt, "supports_traced", False)
             and K == 1
-            and getattr(self.config, "boosting", "gbdt") != "goss"
+            and str(self.config.boosting_type).lower() != "goss"
             and tracer.phases_enabled(default=pt.interpret)
         )
         import time as _time

@@ -12,8 +12,9 @@ jitted program.  Per iteration:
       root histogram of the fresh values)           [in-place Pallas]
     -> feature sampling -> grow_tree_partitioned    [split_stream kernels]
     -> the tree's score delta is carried PENDING to the next iteration's
-       update (the row layout doesn't change in between) and settled by
-       one extra pass at chunk end.
+       update (the row layout doesn't change in between: a tree starts
+       in the order the previous tree's partition left) and settled
+       by one extra pass at chunk end.
     GOSS prepends a gradient-only pass + device top_k/Bernoulli sampling
     with the (n-top_k)/other_k up-weighting folded into g/h (goss.hpp).
 
@@ -27,10 +28,20 @@ jitted program.  Per iteration:
     never stay pending across another class's tree: each tree physically
     re-permutes the rows.)
 
-Scores, labels and weights travel as bitcast channels of the packed
-matrix, so nothing is ever gathered back to original row order during
-training; the original-order score vectors are rebuilt ONCE per chunk
-(one scatter per class through the rowid channel) for metrics/eval.
+Scores, labels, weights and the row id travel as bitcast channels of
+the packed matrix, so nothing is ever gathered back to original row
+order during training, the matrix included: every tree streams the
+layout the previous one left (until PR 30 the serial program gathered
+all of it back at each tree's start, 40% of a 21M-row iteration, to
+keep two environment-flag modes byte-equal; the sharded program never
+did).  What must follow a row and not a position, the bagging and GOSS
+draws, is keyed by the row id (``rowid_uniform``).  What follows the
+layout is float summation order: a run is bit-deterministic, a
+checkpoint carries the layout (``export_perm``), and two runs that
+partition differently (LEVELGROW=1 against =0) agree byte for byte on
+the first tree and to an ulp after it.  The original-order score
+vectors are rebuilt ONCE per chunk (one scatter per class through the
+rowid channel) for metrics/eval.
 
 Why every channel write goes through a Pallas kernel: an XLA-level
 write to the packed matrix copies all of it; only
@@ -42,9 +53,8 @@ whole-matrix copies into the body of every loop nested inside it, the
 grower's level and replay loops: about 530 launches a tree at 255
 leaves (ops/pgrow.py has the account; docs/matrix_copy_variants.py the
 reproducer).  The stop test is now part of the loop's predicate, and
-the compiled 21M-row program has no whole-matrix copy
-(tests/test_phases_v5e_compile.py).  The canonical reorder's
-``dynamic_update_slice`` below writes in place.
+the compiled 21M-row program has no whole-matrix copy, gather or
+scatter (tests/test_phases_v5e_compile.py).
 
 Row-order-free semantics this relies on: histograms, leaf statistics and
 elementwise objectives are permutation-invariant.  Ranking objectives
@@ -56,12 +66,14 @@ elementwise objectives are permutation-invariant.  Ranking objectives
 
 Deliberate parity divergences from the reference (documented):
 - bagging draws a per-row Bernoulli(bagging_fraction) mask with JAX
-  threefry instead of the host RNG's exact-count subset
-  (gbdt.cpp:275-334); same distribution, different stream.
+  threefry, keyed by (seed, bagging period, row id), instead of the
+  host RNG's exact-count subset (gbdt.cpp:275-334); same distribution,
+  different stream.
 - feature_fraction samples exactly ceil(frac*F) features via device
   top_k on uniform keys instead of utils/random.py's host sampler.
-- GOSS's rest-sample is Bernoulli(other_k/rest) rather than an exact
-  other_k-subset; the top set is exact top_k like the reference.
+- GOSS's rest-sample is Bernoulli(other_k/rest), keyed by (seed,
+  iteration, row id), rather than an exact other_k-subset; the top set
+  is exact top_k like the reference.
 """
 
 from __future__ import annotations
@@ -76,7 +88,6 @@ import numpy as np
 
 from ..obs import JitWatch, fence, tracer
 from ..obs.phases import (
-    CANON_REORDER,
     CHUNK_EPILOGUE,
     LEAF_DELTA,
     SAMPLE,
@@ -135,6 +146,21 @@ def _interpret_kernels() -> bool:
 
 def _i2f(x):
     return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+
+def rowid_uniform(key, rowid):
+    """One U[0, 1) draw per row as a function of ``(key, row id)`` alone:
+    the row's id is folded into the key (a counter-based threefry draw,
+    elementwise), so a row's draw does not depend on where a partition
+    left it and ``rowid_uniform(key, rowid[perm]) ==
+    rowid_uniform(key, rowid)[perm]``.  Bagging and GOSS's rest-sample
+    draw through it from the ROWID channel, which is what lets a tree
+    start in whatever order the previous one left."""
+    return jax.vmap(lambda r: jax.random.uniform(jax.random.fold_in(key, r)))(rowid)
+
+
+def _is_goss(config) -> bool:
+    return str(config.boosting_type).lower() == "goss"
 
 
 class PartitionedTrainer:
@@ -336,7 +362,7 @@ class PartitionedTrainer:
         G = params.num_cols or F
         BH = params.num_bins_hist or params.num_bins
         cfg = self.config
-        goss_on = (getattr(cfg, "boosting", "gbdt") == "goss") and K == 1
+        goss_on = _is_goss(cfg) and K == 1
         if goss_on:
             top_cnt = max(1, int(n * float(cfg.top_rate)))
             other_cnt = max(1, int(n * float(cfg.other_rate)))
@@ -349,29 +375,20 @@ class PartitionedTrainer:
             def one_iter(state):
                 t, _, p, recs, delta, last_kept = state
                 it = iter0 + t
-                # ---- canonical row order at every tree start.  The
-                # partition layout a tree leaves behind depends on HOW it
-                # was grown: the level grower speculatively partitions
-                # whole candidate levels (including splits best-first
-                # acceptance never takes), so LEVELGROW=1 and =0 leave
-                # different physical row orders even when they build the
-                # identical tree — and the NEXT tree's histogram float
-                # summation order then differs (the 1-ULP model
-                # divergence pinned by tests/test_audit.py).  One gather
-                # back to original row order per tree makes every tree's
-                # numerics independent of the previous tree's partition
-                # history (it also pins the positional bagging/GOSS draws
-                # below to original rows).  The positional carries
-                # (pending delta, rollback snapshot) are re-mapped
-                # through the SAME rowid so they stay aligned.
-                with jax.named_scope(CANON_REORDER):
-                    rowid = p[lay.ROWID, :n]
-                    delta = jnp.zeros((n,), jnp.float32).at[rowid].set(delta)
-                    last_kept = jnp.zeros((n,), jnp.float32).at[rowid].set(last_kept)
-                    inv = jnp.zeros((n,), jnp.int32).at[rowid].set(
-                        jnp.arange(n, dtype=jnp.int32))
-                    p = jax.lax.dynamic_update_slice(
-                        p, jnp.take(p[:, :n], inv, axis=1), (0, 0))
+                # ---- a tree starts in the row order the previous tree's
+                # partition left (the first, in the order the matrix was
+                # packed or a checkpoint restored).  Nothing is gathered
+                # back to original order: histograms, leaf statistics and
+                # the elementwise objective are permutation-invariant, the
+                # positional carries (pending delta, rollback snapshot)
+                # were written in this very layout, and every draw below
+                # that must follow a ROW is keyed by the ROWID channel
+                # that travels with it.  What follows partition history
+                # is float summation order alone: LEVELGROW=1 and =0 leave
+                # different layouts behind the same tree, so their later
+                # trees may differ by an ulp (each mode is deterministic,
+                # and export_perm/import_perm carry the layout through a
+                # checkpoint).  ShardedPartitionedTrainer always ran so.
                 # disjoint purpose-tagged key streams: fold a purpose
                 # constant (0=bagging, 1=feature, 2=GOSS) before the
                 # iteration number so no two draws share a subkey
@@ -380,7 +397,8 @@ class PartitionedTrainer:
                         bkey = jax.random.fold_in(
                             jax.random.fold_in(key, 0), it // bag_freq
                         )
-                        sel = jax.random.bernoulli(bkey, bag_frac, (n,)).astype(jnp.float32)
+                        sel = (rowid_uniform(bkey, p[lay.ROWID, :n]) < bag_frac
+                               ).astype(jnp.float32)
                     else:
                         sel = None
                     if used_features < F:
@@ -419,7 +437,7 @@ class PartitionedTrainer:
                             is_top = jnp.zeros((n,), bool).at[top_idx].set(True)
                             gkey = jax.random.fold_in(jax.random.fold_in(key, 2), it)
                             sampled = (~is_top) & (
-                                jax.random.uniform(gkey, (n,)) < goss_prob
+                                rowid_uniform(gkey, p[lay.ROWID, :n]) < goss_prob
                             )
                             warm = it < goss_warm
                             selv = jnp.where(
@@ -705,24 +723,16 @@ class PartitionedTrainer:
             return score_add(p, lay, delta, 0, num_rows=n,
                              interpret=interp), delta
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def canon(p, lt):
-            # canonical row order at tree start — the traced twin of the
-            # fused _live_iter's gather: makes every tree's numerics (and
-            # the positional bagging draw) independent of the previous
-            # tree's partition layout, and keeps the positional rollback
-            # snapshot aligned through the reorder
-            rowid = p[lay.ROWID, :n]
-            lt = jnp.zeros((n,), jnp.float32).at[rowid].set(lt)
-            inv = jnp.zeros((n,), jnp.int32).at[rowid].set(
-                jnp.arange(n, dtype=jnp.int32))
-            p = jax.lax.dynamic_update_slice(
-                p, jnp.take(p[:, :n], inv, axis=1), (0, 0))
-            return p, lt
+        bag_frac = float(self.config.bagging_fraction)
+
+        @jax.jit
+        def bag(p, bkey):
+            # the fused program's bagging draw, by row id
+            return (rowid_uniform(bkey, p[lay.ROWID, :n]) < bag_frac).astype(jnp.float32)
 
         # phase= maps each program onto the measured span it runs under
         # (obs/costmodel.py joins HLO rooflines against those spans);
-        # canon has no span of its own
+        # bag has no span of its own
         return {
             "update": JitWatch(upd, name="ptrainer.traced.update",
                                phase="histogram"),
@@ -732,7 +742,7 @@ class PartitionedTrainer:
                              phase="split"),
             "score": JitWatch(score, name="ptrainer.traced.score",
                               phase="score_update"),
-            "canon": JitWatch(canon, name="ptrainer.traced.canon"),
+            "bag": JitWatch(bag, name="ptrainer.traced.bag"),
         }
 
     def train_chunk_traced(self, T: int, lr: float, iter0: int):
@@ -751,11 +761,9 @@ class PartitionedTrainer:
 
         Same tree semantics as the fused classic path — bit-identical to
         a LIGHTGBM_TPU_LEVELGROW=0 fused chunk (the per-split selection
-        below is the same bookkeeping ``grow_tree_partitioned`` replays).
-        The canonical-row-order gather at each tree start (the fused
-        path's tree-start canonicalization, mirrored here) pins the
-        positional Bernoulli bag mask to original rows, so bagged runs
-        match BOTH fused modes bit for bit as well.
+        below is the same bookkeeping ``grow_tree_partitioned`` replays,
+        so both leave the same row layout behind every tree, and the
+        bagging draw is the fused program's, by row id).
         Per-split dispatch overhead is the documented price of
         attribution, which is why this mode is opt-in
         (LIGHTGBM_TPU_TRACE_PHASES).  K == 1, non-GOSS only — callers
@@ -770,7 +778,6 @@ class PartitionedTrainer:
         per = 32 // params.bits
         bag_on = cfg.bagging_fraction < 1.0 and cfg.bagging_freq > 0
         bag_freq = max(1, int(cfg.bagging_freq))
-        bag_frac = float(cfg.bagging_fraction)
         used_features = F
         if cfg.feature_fraction < 1.0:
             used_features = max(1, int(F * cfg.feature_fraction))
@@ -796,24 +803,14 @@ class PartitionedTrainer:
         for t in range(T):
             it = iter0 + t
             with tracer.iteration(it, mode="traced") as irec:
-                # canonical row order at tree start (see the fused
-                # _live_iter): partition-history-independent numerics +
-                # original-row-pinned bagging draws; the rollback
-                # snapshot rides through the same reorder
-                self.p, lt = progs["canon"](
-                    self.p,
-                    self._last_tree if self._last_tree is not None
-                    else zeros_n,
-                )
-                if self._last_tree is not None:
-                    self._last_tree = lt
+                # the tree starts in the order the previous one left (see
+                # the fused one_iter); the rollback snapshot is positional
+                # in that same layout
                 if bag_on:
                     bkey = jax.random.fold_in(
                         jax.random.fold_in(key, 0), it // bag_freq
                     )
-                    sel = jax.random.bernoulli(
-                        bkey, bag_frac, (n,)
-                    ).astype(jnp.float32)
+                    sel = progs["bag"](self.p, bkey)
                 else:
                     sel = ones_n
                 if used_features < F:
@@ -1342,7 +1339,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         # distributed GOSS also samples per machine over local indices
         # (goss.hpp Bagging over the local data partition); counts scale
         # with each shard's real rows
-        goss_on = (getattr(cfg, "boosting", "gbdt") == "goss") and K == 1
+        goss_on = _is_goss(cfg) and K == 1
         if goss_on:
             top_rate = float(cfg.top_rate)
             other_rate = float(cfg.other_rate)
@@ -1368,8 +1365,8 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                                 jax.random.fold_in(key, 0), it // bag_freq
                             ), ax
                         )
-                        sel = jax.random.bernoulli(bkey, bag_frac, (nl,)).astype(jnp.float32)
-                        sel = sel * valid
+                        sel = (rowid_uniform(bkey, p[lay.ROWID, :nl]) < bag_frac
+                               ).astype(jnp.float32) * valid
                     else:
                         sel = None
                     if used_features < F:
@@ -1421,7 +1418,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                                 jax.random.fold_in(jax.random.fold_in(key, 2), it), ax
                             )
                             sampled = ((~is_top)
-                                       & (jax.random.uniform(gkey, (nl,)) < goss_prob)
+                                       & (rowid_uniform(gkey, p[lay.ROWID, :nl]) < goss_prob)
                                        & (valid > 0))
                             warm = it < goss_warm
                             selv = jnp.where(
@@ -1652,7 +1649,7 @@ def eligible(config, train_set, objective, num_tree_per_iteration: int) -> bool:
         # only — fall back to the mask grower, whose _adjust_gradients
         # hooks apply real GOSS to every class (silently training plain
         # GBDT here would be an algorithm regression)
-        if getattr(config, "boosting", "gbdt") == "goss":
+        if _is_goss(config):
             return False
     # serial -> PartitionedTrainer; data -> ShardedPartitionedTrainer.
     # feature/voting keep the mask grower's collective formulations on a

@@ -16,8 +16,9 @@ Submodules: ``trace`` (spans/counters/gauges/iteration records, JSONL
 sink with LIGHTGBM_TPU_TRACE_MAX_MB rotation; an enabled span is also a
 ``jax.profiler.TraceAnnotation("lgbm:<name>")``), ``phases`` (the flat
 vocabulary of ``jax.named_scope`` words the fused chunk programs wrap
-their phases in — ``PHASES``: canon_reorder, sample, update_root_hist,
-level_phase, replay, replay_tail, leaf_delta, score_add, chunk_epilogue —
+their phases in — ``PHASES``: sample, update_root_hist, level_phase,
+split_scan, replay, replay_tail, leaf_delta, score_add, chunk_epilogue,
+and canon_reorder, which no program opens since PR 30 —
 and ``parse_hlo_phases``, the pure text -> {instruction: phase} join
 through a compiled module's ``op_name`` metadata), ``compilewatch``
 (jax.monitoring compile counter + JitWatch retrace detector, which

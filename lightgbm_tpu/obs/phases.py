@@ -21,7 +21,11 @@ from __future__ import annotations
 import re
 from typing import Dict, Optional
 
-CANON_REORDER = "canon_reorder"      # rows back to original order at a tree's start
+# No program opens this scope since PR 30 (a tree starts in the row order the
+# previous one left).  The word stays because the benchmark's reader
+# (benchmarks/layer_metrics/canon_reorder_ms_per_iter.py) looks it up by name
+# and then reads 0.0; a `benchmark` PR retires both.
+CANON_REORDER = "canon_reorder"      # until PR 30: rows back to original order at a tree's start
 SAMPLE = "sample"                    # bagging / GOSS / feature-fraction draws
 UPDATE_ROOT_HIST = "update_root_hist"  # channel refresh + root histogram + root split
 LEVEL_PHASE = "level_phase"          # level-batched expansion (level_stream)

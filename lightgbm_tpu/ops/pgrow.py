@@ -563,7 +563,14 @@ def grow_tree_partitioned(
         # candidate batches; routing both branches through the SAME
         # division op removes batch-shape / fusion-context rounding as a
         # variable between the LEVELGROW modes, so accepted leaf values
-        # depend only on the (psum-exact) integer-scaled g/h sums.
+        # depend only on the (psum-exact) integer-scaled g/h sums.  That
+        # makes ONE tree the same in both modes from the same row layout.
+        # The modes leave different layouts behind it (the level phase
+        # partitions candidates the selection never takes), and since
+        # PR 30 the next tree starts in the layout it finds, so its f32
+        # histogram sums may differ between the modes by an ulp: byte-equal
+        # in the first tree, equal in structure and to rounding after it,
+        # each mode bit-deterministic (tests/test_row_order.py).
         leaf2 = leaf2.at[:, 3].set(
             leaf_output(leaf2[:, 0], leaf2[:, 1],
                         hyper.lambda_l1, hyper.lambda_l2))

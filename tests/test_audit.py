@@ -10,10 +10,17 @@ level grower speculatively partitions candidate levels best-first
 acceptance never takes), and ``segment_values``' float range-add
 cumsum carried position-dependent 1-ULP residue — so training scores,
 and from round 2 on the gradients, depended on partition history.
-Fixed by an exact integer-rank gather in ``segment_values`` plus a
-canonical row order at every tree start, so the repro class asserts
-parity; ``report diff`` localization is covered on synthetic trails in
-TestReportDiff.
+Fixed by an exact integer-rank gather in ``segment_values``, so the
+repro class asserts parity; ``report diff`` localization is covered on
+synthetic trails in TestReportDiff.
+
+Since PR 30 a tree starts in the row order the previous tree left (the
+canonical reorder that also went in with that fix is gone: it was 40%
+of a 21M-row iteration), so past the first tree the two modes are
+guaranteed equal in structure and to an ulp in values, no longer byte
+for byte (tests/test_row_order.py asserts that contract).  At these
+configurations the trails ARE still byte-identical with the reorder
+gone, so the assertions below stand as they were.
 """
 
 import json
@@ -133,9 +140,10 @@ class TestLevelgrowDivergenceRepro:
     ``segment_values`` float-cumsum range-add gave different rows
     1-ULP-different score deltas depending on position — so from round
     2 on, gradients (hence one leaf value of tree 2) diverged.  Fixed
-    by the exact integer-rank ``segment_values`` gather plus canonical
-    row order at each tree start; this class pins the parity (the
-    synthetic-trail localization coverage lives in TestReportDiff)."""
+    by the exact integer-rank ``segment_values`` gather; this class pins
+    the parity, which holds here without the canonical reorder PR 30
+    deleted (module docstring; the synthetic-trail localization coverage
+    lives in TestReportDiff)."""
 
     @pytest.fixture(scope="class")
     def trails(self, tmp_path_factory):

@@ -251,6 +251,23 @@ def test_resume_bit_identical_fused_partitioned(xy, monkeypatch):
     )
 
 
+def test_resume_bit_identical_fused_bagged_mid_period(xy, monkeypatch):
+    """The fused trainer's bag is a function of (seed, bagging period, row
+    id), so a run killed inside a period (checkpoint at iteration 4 of a
+    period of 3 iterations that began at 3) resumes into the SAME bag of
+    original rows, in the restored layout: byte-equal to the uninterrupted
+    run.  (Since PR 30 no tree start puts the rows back in original order;
+    the draw reads the ROWID channel instead.)"""
+    X, y = xy
+    _assert_resume_bit_identical(
+        dict(objective="binary", num_leaves=7, learning_rate=0.2,
+             min_data_in_leaf=20, verbose=-1, bagging_fraction=0.6,
+             bagging_freq=3, feature_fraction=0.8),
+        X, y, rounds=10, kill=6, freq=4, monkeypatch=monkeypatch,
+        env={"LIGHTGBM_TPU_PGROW": "force"},
+    )
+
+
 def test_resume_bit_identical_fused_goss(xy, monkeypatch):
     X, y = xy
     _assert_resume_bit_identical(

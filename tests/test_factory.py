@@ -272,8 +272,16 @@ class TestRegistryLifecycle:
 class TestPerVersionMetrics:
     @pytest.fixture()
     def server(self, tiny_booster, tmp_path):
+        from lightgbm_tpu.serve import server as serve_server
         from lightgbm_tpu.serve.server import make_server
 
+        # the per-version families belong to the process, not to a server:
+        # another test file's server in this xdist worker may have left
+        # requests and errors under version "1" (which file shares the worker
+        # depends on how loadfile deals the files out)
+        for fam in (serve_server._M_VER_REQS, serve_server._M_VER_ERRS,
+                    serve_server._M_VER_LATENCY):
+            fam.prune(())
         bst, X = tiny_booster
         model = PredictorArtifact.from_booster(bst).save(str(tmp_path / "m"))
         srv = make_server(model, port=0, warmup_max_rows=64,

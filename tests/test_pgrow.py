@@ -582,7 +582,20 @@ class TestShardedPartitioned:
         assert isinstance(bst.boosting.ptrainer, ShardedPartitionedTrainer)
         from sklearn.metrics import roc_auc_score
         auc = roc_auc_score(y, bst.predict(X))
-        assert auc > 0.85, auc
+        # Until PR 30 the fused trainers read `config.boosting`, which does not
+        # exist (the parameter is `boosting_type`), and trained plain GBDT here:
+        # AUC 0.872, over the 0.85 this test asked.  GOSS proper (4 of the 6
+        # trees from 30% of the rows) reads 0.833-0.845 on this table on the
+        # mask grower, the serial and the sharded fused trainer alike, so the
+        # quality is held against the serial fused trainer's.
+        serial = dict(params, tree_learner="serial")
+        ref = lgb.train(serial, lgb.Dataset(X, label=y, params=dict(serial)), 6)
+        ref_auc = roc_auc_score(y, ref.predict(X))
+        assert auc > 0.8 and abs(auc - ref_auc) < 0.03, (auc, ref_auc)
+        # and GOSS really sampled: the last iteration selected under half the rows
+        pt, lay = bst.boosting.ptrainer, bst.boosting.ptrainer.layout
+        sel = np.asarray(pt.p)[:, lay.SEL, :pt.num_rows].view(np.float32)
+        assert 0.2 * n < sel.sum() < 0.5 * n
 
 
 class TestFusedRollback:

@@ -80,7 +80,12 @@ def _loc_names(text):
 def test_chunk_program_carries_every_phase_word(lowered, phase):
     """Every word of the vocabulary is a scope of the lowered chunk program
     (`score_add` as a scope of its own only where K > 1 lands deltas in the
-    loop)."""
+    loop), but for `canon_reorder`: the word stays for the benchmark's reader,
+    and since PR 30 no program, bagged binary or multiclass, opens it."""
+    if phase == "canon_reorder":
+        for text in lowered.values():
+            assert phase not in {phase_of(n) for n in _loc_names(text)}
+        return
     text = lowered["multiclass" if phase == "score_add" else "binary"]
     if phase == "score_add":
         assert re.search(r"score_add/jit\(score_add\)", text), \
@@ -383,7 +388,10 @@ def test_program_records_only_on_demand(traced_chunks):
     assert n_maps == len(programs) == calls["phase_map"] >= 1
     chunk = [p for p in programs if p["name"].startswith("ptrainer.chunk")]
     assert chunk and chunk[0]["module"] == "jit_prog"
-    assert {"level_phase", "replay", "canon_reorder", "leaf_delta"} <= set(chunk[0]["ops"].values())
+    # (every live chunk program of the process is written: this booster's is the
+    # one with a level phase if an earlier test left a LEVELGROW=0 program alive)
+    assert any({"level_phase", "replay", "leaf_delta"} <= set(p["ops"].values()) for p in chunk)
+    assert all("canon_reorder" not in p["ops"].values() for p in chunk)
     assert recs.index(programs[0]) > max(i for i, r in enumerate(recs) if r["ev"] == "span")
 
 

@@ -145,11 +145,55 @@ def test_module_and_matrix(phase_map):
     assert phase_map["matrix"] == "s32[16,21001024]"
 
 
-@pytest.mark.parametrize("phase", [p for p in PHASES if p not in ("sample", "score_add")])
+@pytest.mark.parametrize("phase", [p for p in PHASES
+                                   if p not in ("canon_reorder", "sample", "score_add")])
 def test_the_compiler_keeps_the_phase(phase_map, phase):
-    """Every phase the cells' program runs (it draws no sample, and lands no
-    per-class delta) still owns instructions after XLA's passes."""
+    """Every phase the cells' program runs (it draws no sample, lands no
+    per-class delta and, since PR 30, reorders nothing at a tree's start) still
+    owns instructions after XLA's passes."""
     assert phase in phase_map["ops"].values()
+
+
+_RESULT = re.compile(r"^\s+(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(", re.M)
+_ARRAY = re.compile(r"s32\[([\d,]*)\]")  # the packed matrix is int32 words
+
+
+def _moves_of_matrix_size(text, elements):
+    """Instructions anywhere in the module (fusion bodies included) that
+    gather, scatter, sort, transpose or copy an int32 array of at least
+    ``elements`` elements: what a reorder of the packed matrix would compile
+    to."""
+    found = []
+    for name, shape, opcode in _RESULT.findall(text):
+        if opcode not in ("gather", "scatter", "sort", "transpose", "copy"):
+            continue
+        sizes = [int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+                 for dims in _ARRAY.findall(shape)]
+        if sizes and max(sizes) >= elements:
+            found.append((name, opcode, shape))
+    return found
+
+
+@pytest.mark.parametrize("cell", ["higgs_21m_x_28", "epsilon_400k_x_2000"])
+def test_no_program_reorders_the_matrix(request, cell):
+    """PR 30 deleted the canonical reorder at a tree's start: the serial chunk
+    programs of both configurations own no `canon_reorder` instruction, and
+    nothing in them gathers, scatters, sorts, transposes or copies an array of
+    the matrix's size (the parent's: `fusion s32[21000000,16]`, 469 ms a tree,
+    and at 512 channel rows a transposing copy around a gather of 2 KB rows).
+    Row vectors of `n` elements still move, in `leaf_delta` and the epilogue."""
+    if cell == "higgs_21m_x_28":
+        text, rows, channels = request.getfixturevalue("compiled_text"), ROWS, 16
+    else:
+        text, rows, channels = request.getfixturevalue("epsilon_compiled")[0], EPS_ROWS, 512
+    pm = parse_hlo_phases(text)
+    assert pm["matrix"] == f"s32[{channels},{rows + 1024}]"
+    assert "canon_reorder" not in pm["ops"].values()
+    assert "canon_reorder" not in text
+    assert pm["matrix_copies"] == []
+    assert _moves_of_matrix_size(text, channels * rows) == []
+    # the check can see a row vector's move, so it would have seen the matrix's
+    assert _moves_of_matrix_size(text, rows)
 
 
 @pytest.mark.parametrize("kernel,phase", [
@@ -259,20 +303,16 @@ def test_epsilon_kernels_compile_through_mosaic(epsilon_compiled, kernel):
 
 
 def test_epsilon_program_keeps_the_carry_contract(epsilon_compiled):
-    """PR 27's rule at width: no copy of the matrix that keeps its layout (what
-    copy insertion makes for a conditional that carries it), and the one
-    conditional is the replay's over the small tables.  What IS there once a
-    tree: the canonical reorder's gather, which at 512 channel rows XLA makes
-    as a transposing copy to row-major, a gather of 2 KB rows and a transpose
-    back inside the update fusion (at 16 channel rows it is one strided-gather
-    fusion, `fusion s32[21000000,16]`); ROADMAP S2 deletes the step."""
+    """PR 27's rule at width: no copy of the matrix, neither one that keeps its
+    layout (what copy insertion makes for a conditional that carries it) nor
+    the transposing one the canonical reorder's gather made once a tree until
+    PR 30 deleted the step; the one conditional is the replay's over the small
+    tables."""
     text = epsilon_compiled[0]
     pm = parse_hlo_phases(text)
     assert pm["matrix"] == EPS_MATRIX
-    copies = re.findall(rf" = s32\[512,{EPS_ROWS + 1024}\](\{{[\d,]*)\S* copy\(", text)
-    assert [layout for layout in copies if layout == "{1,0"] == []
-    assert {pm["ops"][c] for c in pm["matrix_copies"]} <= {"canon_reorder"}
-    assert len(pm["matrix_copies"]) <= 1
+    assert re.findall(rf" = s32\[512,{EPS_ROWS + 1024}\]\S* copy\(", text) == []
+    assert pm["matrix_copies"] == []
     assert len(re.findall(r" conditional\(", text)) == 1
     assert "split_scan" in pm["ops"].values()
 
