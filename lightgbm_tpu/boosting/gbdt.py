@@ -753,31 +753,15 @@ class GBDT:
         K = self.num_tree_per_iteration
         if pt.score_dirty:
             pt.sync_scores_from(self.scores if K > 1 else self.scores[0])
-        # traced mode: one iteration per dispatch group with REAL per-phase
-        # (histogram/split/partition/score_update) device-synced timings;
-        # opt-in via LIGHTGBM_TPU_TRACE_PHASES (defaults on only in
-        # interpret mode, where defusing doesn't distort the measurement)
-        use_traced = (
-            tracer.enabled
-            and getattr(pt, "supports_traced", False)
-            and K == 1
-            and str(self.config.boosting_type).lower() != "goss"
-            and tracer.phases_enabled(default=pt.interpret)
-        )
         import time as _time
 
         t_chunk0 = _time.perf_counter()
         with timetag.phase("tree"):
-            if use_traced:
-                recs, scores_orig, n_done = pt.train_chunk_traced(
-                    num_iters, self.shrinkage_rate, self.iter
-                )
-            else:
-                recs, scores_orig, n_done = pt.train_chunk(
-                    num_iters, self.shrinkage_rate, self.iter
-                )
+            recs, scores_orig, n_done = pt.train_chunk(
+                num_iters, self.shrinkage_rate, self.iter
+            )
         chunk_wall = _time.perf_counter() - t_chunk0
-        if tracer.enabled and not use_traced and n_done > 0:
+        if tracer.enabled and n_done > 0:
             # fused chunks execute as ONE device program: emit amortized
             # per-iteration records (flagged) so the trace still has an
             # iteration axis to join compile/memory signals against
@@ -1328,7 +1312,7 @@ class GBDT:
             # here keeps the whole fleet's view consistent mid-epoch.
             self._membership.counts = tuple(int(c) for c in new_plan.counts)
         # injected per-collective delays model per-row-slow hosts: their
-        # stall shrinks with the rank's row share (bench.py elastic)
+        # stall shrinks with the rank's row share
         _net.set_delay_scale(n_new / max(self._initial_local_rows, 1))
         moved_rows = sum(
             max(0, a - b) for a, b in zip(old_plan.counts, new_plan.counts)
@@ -1717,7 +1701,6 @@ class GBDT:
             self.ptrainer.hyper = self.hyper
             self.ptrainer.config = self.config
             self.ptrainer._progs.clear()
-            self.ptrainer._traced_progs = None  # hyper is baked in there too
         self.shrinkage_rate = self.config.learning_rate
         self.is_bagging = (
             self.config.bagging_fraction < 1.0 and self.config.bagging_freq > 0

@@ -97,8 +97,6 @@ from ..obs.phases import (
 from ..ops.pgrow import (
     BundleMeta,
     PGrowParams,
-    _expand_bundle_hist,
-    _meta_table,
     grow_tree_partitioned,
     levelgrow_env_params,
     segment_values,
@@ -110,16 +108,12 @@ from ..ops.pkernels import (
     level_stream_vmem_bytes,
     pack_matrix_device,
     score_add,
-    split_stream,
     update_and_root_hist,
     update_multi_and_hists,
 )
 from ..ops.split import (
-    NEG_INF,
     FeatureMeta,
     SplitHyper,
-    best_split_per_feature,
-    finalize_split,
 )
 from ..utils.log import Log
 
@@ -165,11 +159,6 @@ def _is_goss(config) -> bool:
 
 class PartitionedTrainer:
     """Owns the packed matrix + fused train-chunk programs for one GBDT."""
-
-    # phase-separated traced mode (train_chunk_traced) — serial K == 1
-    # only; the sharded trainer keeps the fused program (a defused
-    # per-split host loop over a mesh would serialize the collectives)
-    supports_traced = True
 
     def __init__(self, train_set, config, objective, meta: FeatureMeta, hyper: SplitHyper,
                  bins_dev=None):
@@ -646,281 +635,6 @@ class PartitionedTrainer:
                 break
         return recs_np, scores_orig, n_done
 
-    # -- phase-separated traced mode -----------------------------------
-    def _traced_progs_build(self):
-        """Small single-phase programs for the traced (defused) mode:
-        update+root-hist, partition (split_stream), split search, score
-        apply.  All dynamic inputs are traced scalars so each program
-        compiles exactly once."""
-        lay = self.layout
-        n = self.num_rows
-        params = self.params
-        F = params.num_features
-        B = params.num_bins
-        G = params.num_cols or F
-        BH = params.num_bins_hist or B
-        L = params.num_leaves
-        meta = self.meta
-        hyper = self.hyper
-        bmeta = self.bmeta
-        interp = self.interpret
-        grad_fn = self._grad_fn
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def upd(p, delta, sel):
-            return update_and_root_hist(
-                p, lay, grad_fn, delta=delta, sel=sel, num_rows=n,
-                num_features=G, num_bins=BH, bits=params.bits,
-                interpret=interp,
-            )
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def part(p, start, cnt, word, shift, zb, dbz, thr, cat,
-                 off_lo, off_hi, bias):
-            return split_stream(
-                p, start, cnt, word, shift, zb, dbz, thr, cat,
-                off_lo=off_lo, off_hi=off_hi, bias=bias, num_features=G,
-                num_bins=BH, bits=params.bits, rows=lay.rows,
-                interpret=interp,
-            )
-
-        @jax.jit
-        def find(hist2, sums2, fmask, depth_ok):
-            # the fused program's find2, lifted out as its own dispatch
-            if bmeta is not None:
-                hist2 = jax.vmap(
-                    lambda hh, ss: _expand_bundle_hist(hh, ss, bmeta, F, B)
-                )(hist2, sums2)
-
-            def one(hist, s):
-                gain_f, thr_f, dbz_f, left_f = best_split_per_feature(
-                    hist, s[0], s[1], s[2], meta, hyper, fmask,
-                    params.use_missing,
-                    has_categorical=params.has_categorical,
-                )
-                return finalize_split(
-                    gain_f, thr_f, dbz_f, left_f, s[0], s[1], s[2], hyper
-                )
-
-            res = jax.vmap(one)(hist2, sums2)
-            return res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF))
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def score(p, starts, cnts, num_splits, values):
-            # segment_values inlined over the explicit (starts, cnts) —
-            # the same EXACT integer-rank gather as ops.pgrow
-            # .segment_values (a float range-add cumsum leaves
-            # position-dependent 1-ULP residue inside segments; see that
-            # docstring), so traced scores match the fused path's bit
-            # for bit
-            active = jnp.arange(L) <= num_splits
-            v = jnp.where(active, values, 0.0)
-            s = jnp.where(active & (cnts > 0), starts, n)
-            marks = jnp.zeros((n + 1,), jnp.int32).at[s].add(1)
-            rank = jnp.cumsum(marks)[:n] - 1
-            order = jnp.argsort(s)
-            delta = jnp.take(v, jnp.take(order, jnp.clip(rank, 0, L - 1)))
-            return score_add(p, lay, delta, 0, num_rows=n,
-                             interpret=interp), delta
-
-        bag_frac = float(self.config.bagging_fraction)
-
-        @jax.jit
-        def bag(p, bkey):
-            # the fused program's bagging draw, by row id
-            return (rowid_uniform(bkey, p[lay.ROWID, :n]) < bag_frac).astype(jnp.float32)
-
-        # phase= maps each program onto the measured span it runs under
-        # (obs/costmodel.py joins HLO rooflines against those spans);
-        # bag has no span of its own
-        return {
-            "update": JitWatch(upd, name="ptrainer.traced.update",
-                               phase="histogram"),
-            "partition": JitWatch(part, name="ptrainer.traced.partition",
-                                  phase="partition"),
-            "find": JitWatch(find, name="ptrainer.traced.find",
-                             phase="split"),
-            "score": JitWatch(score, name="ptrainer.traced.score",
-                              phase="score_update"),
-            "bag": JitWatch(bag, name="ptrainer.traced.bag"),
-        }
-
-    def train_chunk_traced(self, T: int, lr: float, iter0: int):
-        """Phase-separated twin of ``train_chunk`` for run tracing: each
-        boosting iteration executes as separate fenced device programs so
-        the trace carries REAL per-phase timings —
-
-          histogram    the streaming update+root-histogram pass
-          partition    split_stream passes (in-place partition; note the
-                       children histograms are accumulated IN this pass —
-                       this port's core fusion — so the reference's
-                       per-leaf "hist" time appears here)
-          split        the vmapped split-search math over candidate
-                       histograms
-          score_update the leaf-delta application
-
-        Same tree semantics as the fused classic path — bit-identical to
-        a LIGHTGBM_TPU_LEVELGROW=0 fused chunk (the per-split selection
-        below is the same bookkeeping ``grow_tree_partitioned`` replays,
-        so both leave the same row layout behind every tree, and the
-        bagging draw is the fused program's, by row id).
-        Per-split dispatch overhead is the documented price of
-        attribution, which is why this mode is opt-in
-        (LIGHTGBM_TPU_TRACE_PHASES).  K == 1, non-GOSS only — callers
-        gate on ``supports_traced``/K."""
-        assert self.K == 1, "traced mode is single-class only"
-        cfg = self.config
-        lay = self.layout
-        n = self.num_rows
-        params = self.params
-        L = params.num_leaves
-        F = params.num_features
-        per = 32 // params.bits
-        bag_on = cfg.bagging_fraction < 1.0 and cfg.bagging_freq > 0
-        bag_freq = max(1, int(cfg.bagging_freq))
-        used_features = F
-        if cfg.feature_fraction < 1.0:
-            used_features = max(1, int(F * cfg.feature_fraction))
-        if not hasattr(self, "_traced_progs") or self._traced_progs is None:
-            self._traced_progs = self._traced_progs_build()
-        progs = self._traced_progs
-        mtab = np.asarray(_meta_table(self.meta, self.bmeta, F, params.bits))
-        l1 = float(self.hyper.lambda_l1)
-        l2 = float(self.hyper.lambda_l2)
-        max_depth = int(params.max_depth)
-        key = self._base_key
-        m = L - 1
-        all_ns = np.zeros((T, 1), np.int32)
-        all_raw = np.zeros((T, 1, m, 12), np.float32)
-        n_done = 0
-        zeros_n = jnp.zeros((n,), jnp.float32)
-        ones_n = jnp.ones((n,), jnp.float32)
-
-        def _leaf_out(g, h):
-            reg = max(abs(g) - l1, 0.0)
-            return -np.sign(g) * reg / (h + l2) if (h + l2) != 0 else 0.0
-
-        for t in range(T):
-            it = iter0 + t
-            with tracer.iteration(it, mode="traced") as irec:
-                # the tree starts in the order the previous one left (see
-                # the fused one_iter); the rollback snapshot is positional
-                # in that same layout
-                if bag_on:
-                    bkey = jax.random.fold_in(
-                        jax.random.fold_in(key, 0), it // bag_freq
-                    )
-                    sel = progs["bag"](self.p, bkey)
-                else:
-                    sel = ones_n
-                if used_features < F:
-                    fkey = jax.random.fold_in(jax.random.fold_in(key, 1), it)
-                    u = jax.random.uniform(fkey, (F,))
-                    _, fidx = jax.lax.top_k(u, used_features)
-                    fmask = jnp.zeros((F,), jnp.float32).at[fidx].set(1.0)
-                else:
-                    fmask = jnp.ones((F,), jnp.float32)
-
-                with tracer.span("histogram"):
-                    self.p, root_hist = progs["update"](self.p, zeros_n, sel)
-                    fence(root_hist)
-                root_sums = np.asarray(jnp.sum(root_hist[0], axis=0))
-
-                # host-side split bookkeeping (the fused _PState tables)
-                seg = np.zeros((L, 2), np.int64)
-                seg[0] = (0, n)
-                bs = np.full((L, 8), -np.inf, np.float32)
-                leaf = np.zeros((L, 8), np.float32)
-                leaf[0, 0:3] = root_sums
-                leaf[0, 3] = _leaf_out(root_sums[0], root_sums[1])
-                leaf[0, 4] = root_sums[2]
-                recs = np.zeros((m, 12), np.float32)
-
-                with tracer.span("split"):
-                    rr = jax.device_get(progs["find"](
-                        jnp.stack([root_hist, root_hist]),
-                        jnp.stack([jnp.asarray(root_sums)] * 2),
-                        fmask, jnp.array(True),
-                    ))
-                bs[0] = (rr.gain[0], rr.feature[0], rr.threshold_bin[0],
-                         rr.default_bin_for_zero[0], rr.left_sum_g[0],
-                         rr.left_sum_h[0], rr.left_cnt[0], 0.0)
-
-                ns = 0
-                while ns < L - 1:
-                    bl = int(np.argmax(bs[:, 0]))
-                    gain = float(bs[bl, 0])
-                    if not gain > 0.0:
-                        break
-                    feat = int(bs[bl, 1])
-                    thr = int(bs[bl, 2])
-                    dbz = int(bs[bl, 3])
-                    left = bs[bl, 4:7].astype(np.float64)
-                    totals = leaf[bl, 0:3].astype(np.float64)
-                    pval = float(leaf[bl, 3])
-                    child_depth = leaf[bl, 5] + 1.0
-                    start, cnt = int(seg[bl, 0]), int(seg[bl, 1])
-                    mrow = mtab[feat]
-                    col = int(mrow[2])
-                    with tracer.span("partition"):
-                        self.p, nl, lhist, rhist = progs["partition"](
-                            self.p, jnp.int32(start), jnp.int32(cnt),
-                            jnp.int32(col // per),
-                            jnp.int32((col % per) * params.bits),
-                            jnp.int32(mrow[0]), jnp.int32(dbz),
-                            jnp.int32(thr), jnp.int32(mrow[1]),
-                            jnp.int32(mrow[3]), jnp.int32(mrow[4]),
-                            jnp.int32(mrow[5]),
-                        )
-                        nl = int(nl)  # host pull == the fence
-                    right = totals - left
-                    sums2 = np.stack([left, right]).astype(np.float32)
-                    depth_ok = (max_depth <= 0) or (child_depth < max_depth)
-                    with tracer.span("split"):
-                        res2 = jax.device_get(progs["find"](
-                            jnp.stack([lhist, rhist]), jnp.asarray(sums2),
-                            fmask, jnp.array(bool(depth_ok)),
-                        ))
-                    rl = ns + 1
-                    vals2 = [_leaf_out(sums2[0, 0], sums2[0, 1]),
-                             _leaf_out(sums2[1, 0], sums2[1, 1])]
-                    recs[ns] = (bl, feat, thr, dbz, gain, vals2[0], vals2[1],
-                                sums2[0, 2], sums2[1, 2], pval, 0.0, 0.0)
-                    seg[bl] = (start, nl)
-                    seg[rl] = (start + nl, cnt - nl)
-                    for j, li in enumerate((bl, rl)):
-                        bs[li] = (res2.gain[j], res2.feature[j],
-                                  res2.threshold_bin[j],
-                                  res2.default_bin_for_zero[j],
-                                  res2.left_sum_g[j], res2.left_sum_h[j],
-                                  res2.left_cnt[j], 0.0)
-                        leaf[li] = (sums2[j, 0], sums2[j, 1], sums2[j, 2],
-                                    vals2[j], sums2[j, 2], child_depth,
-                                    0.0, 0.0)
-                    ns += 1
-
-                if irec is not None:
-                    irec["leaves"] = ns + 1
-                    if bag_on:
-                        irec["bagged_rows"] = int(jnp.sum(sel))
-                if ns == 0:
-                    break
-                with tracer.span("score_update"):
-                    lvals = np.clip(lr * leaf[:, 3], -100.0, 100.0)
-                    self.p, delta = progs["score"](
-                        self.p, jnp.asarray(seg[:, 0], jnp.int32),
-                        jnp.asarray(seg[:, 1], jnp.int32), jnp.int32(ns),
-                        jnp.asarray(lvals, jnp.float32),
-                    )
-                    fence(delta)
-                self._last_tree = delta
-                all_ns[t, 0] = ns
-                all_raw[t, 0] = recs
-                n_done += 1
-
-        recs_np = {"num_splits": all_ns[:n_done], "raw": all_raw[:n_done]}
-        return recs_np, self.scores_original_order(), n_done
-
     def stream_counts(self, recs_np, n_done: int) -> dict:
         """What the streaming kernels did for the first ``n_done``
         iterations of a chunk's records, for the ``trees_from_records``
@@ -936,10 +650,8 @@ class PartitionedTrainer:
         bins = self.params.num_bins_hist or self.params.num_bins
         out = {"hist_cells": hist_lanes(cols, bins), "channels": self.layout.C,
                "col_groups": col_groups(cols, self.params.bits).count}
-        lv = recs_np.get("levels")  # the defused traced mode keeps none
-        if lv is not None:
-            out.update(zip(("levels", "level_rows", "level_segments"),
-                           lv[:n_done].sum(axis=(0, 1)).tolist()))
+        out.update(zip(("levels", "level_rows", "level_segments"),
+                       recs_np["levels"][:n_done].sum(axis=(0, 1)).tolist()))
         return out
 
     def grow_result_view(self, recs_np, t, k: int = 0):
@@ -975,8 +687,6 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
     per-tree host round-trips (the reference's per-iteration
     ReduceScatter is the ONLY cross-device traffic, here one psum of the
     (G, BH, 3) tensor per split)."""
-
-    supports_traced = False  # defusing would serialize the collectives
 
     def __init__(self, train_set, config, objective, meta, hyper, mesh):
         import jax as _jax

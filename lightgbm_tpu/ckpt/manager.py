@@ -10,8 +10,7 @@ Used two ways:
 Capture is synchronous (device arrays are pulled at a consistent
 iteration boundary); serialization + the fsync'd write happen on a
 single background worker thread, so steady-state training overlaps the
-disk write — the bench ``checkpoint`` section measures the residual
-per-iteration overhead.  At most one write is in flight: the next save
+disk write.  At most one write is in flight: the next save
 waits for the previous one, bounding buffered checkpoint memory to one
 blob.
 

@@ -28,9 +28,9 @@ phase map on demand, + the first-compile HLO cost capture), ``costmodel``
 (per-program flops/bytes inventory, peak-spec roofline, per-phase
 efficiency attribution), ``memory`` (host/device gauges), ``report``
 (aggregation + the ``python -m lightgbm_tpu report`` CLI, incl. the
-cross-rank ``merge``, audit ``diff``, ``costs`` and ``bench-trend``
-subcommands), ``metrics`` (Prometheus text-format registry behind
-``GET /metrics``), ``audit`` (LIGHTGBM_TPU_AUDIT split-decision trail),
+cross-rank ``merge``, audit ``diff`` and ``costs`` subcommands),
+``metrics`` (Prometheus text-format registry behind ``GET /metrics``),
+``audit`` (LIGHTGBM_TPU_AUDIT split-decision trail),
 ``flight`` (crash flight recorder dumping to ``<trace>.crash.jsonl``).
 """
 

@@ -7,9 +7,9 @@ signature, XLA's HLO cost analysis (flops, bytes accessed,
 transcendentals) and — when a re-compile is cheap enough to afford —
 the compiled memory analysis (peak temp / argument / output bytes).
 Each capture lands as a ``jax_cost`` trace record AND in a
-process-global program inventory, so both the offline report
-(``python -m lightgbm_tpu report costs <trace>``) and the in-process
-bench harness can join program costs against measured phase spans.
+process-global program inventory, so the offline report
+(``python -m lightgbm_tpu report costs <trace>``) can join program
+costs against measured phase spans.
 
 The join produces, per phase, an **efficiency %**: the roofline
 lower-bound time (``max(flops/peak_flops, bytes/peak_bw)`` per call,
@@ -354,11 +354,9 @@ def efficiency_table(phase_stats: Dict[str, Dict[str, Any]],
                      spec: Dict[str, Any]) -> List[Dict[str, Any]]:
     """Join program rooflines against measured phase spans.
 
-    When several programs map to one phase (traced-mode ``update`` and
-    the standalone ``ops.build_histogram`` both tag ``histogram``), the
-    one with the largest per-call roofline represents the phase — the
-    others are variants of the same work, and one span = one call of
-    the representative.  Rows sort by measured wall, descending."""
+    When several programs map to one phase, the one with the largest
+    per-call roofline represents the phase — the others are variants of
+    the same work, and one span = one call of the representative.  Rows sort by measured wall, descending."""
     by_phase: Dict[str, List[str]] = {}
     for name, entry in programs.items():
         ph = entry.get("phase")
@@ -429,34 +427,6 @@ def costs_summary(records: List[Dict[str, Any]],
                         if e.get("backend")), None)
         spec = resolve_peak_spec(backend)
     table = efficiency_table(phase_stats_from_trace(records), programs, spec)
-    return {
-        "peak_spec": spec,
-        "n_programs": len(programs),
-        "n_signatures": sum(len(e["records"]) for e in programs.values()),
-        "programs": {n: program_stats(e, spec)
-                     for n, e in sorted(programs.items())},
-        "table": table,
-        "next_target": next_target(table),
-        "next_target_line": next_target_line(table),
-    }
-
-
-def process_summary(spec: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """Same summary from the LIVE process state: the global inventory
-    joined against the tracer's span aggregates — what bench.py embeds
-    as its ``cost_model`` section."""
-    from .trace import tracer
-
-    programs = inventory()
-    if spec is None:
-        backend = next((e.get("backend") for e in programs.values()
-                        if e.get("backend")), None)
-        spec = resolve_peak_spec(backend)
-    snap = tracer.snapshot()["spans"]
-    phase_stats = {name: {"total_s": float(v["total_s"]),
-                          "count": int(v["count"])}
-                   for name, v in snap.items()}
-    table = efficiency_table(phase_stats, programs, spec)
     return {
         "peak_spec": spec,
         "n_programs": len(programs),

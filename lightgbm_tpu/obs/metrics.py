@@ -398,8 +398,7 @@ class MetricsRegistry:
         return "\n".join(lines) + ("\n" if lines else "")
 
     def snapshot(self) -> Dict[str, float]:
-        """{name: scalar value} view (histograms report their count) —
-        what bench.py embeds and tests assert against."""
+        """{name: scalar value} view (histograms report their count)."""
         with self._lock:
             metrics = list(self._metrics.values())
         return {m.name: m.value() for m in metrics}

@@ -25,9 +25,6 @@ Subcommands:
                                   against measured phase spans into a
                                   per-phase efficiency table + "next
                                   kernel target" line (obs/costmodel)
-  report bench-trend [dir]        BENCH_r*.json trajectory: per-round
-                                  s/iter and gate verdicts as one
-                                  table
 
 Every subcommand takes ``--json`` for machine-readable output.
 
@@ -40,7 +37,6 @@ from __future__ import annotations
 import glob
 import json
 import os
-import re
 import sys
 from typing import Any, Dict, List, Optional
 
@@ -712,176 +708,23 @@ def costs_main(argv: List[str]) -> int:
     return 0
 
 
-# ----------------------------------------------------------------------
-# bench trajectory (report bench-trend [dir]) — BENCH_r*.json history
-# ----------------------------------------------------------------------
-def load_bench_rounds(bench_dir: str) -> List[Any]:
-    """[(basename, doc), ...] for every parseable BENCH_r*.json in
-    ``bench_dir``, in round order."""
-    out = []
-    for path in sorted(glob.glob(os.path.join(bench_dir, "BENCH_r*.json"))):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            sys.stderr.write(f"warning: skipping unparsable {path}\n")
-            continue
-        if isinstance(doc, dict):
-            out.append((os.path.basename(path), doc))
-    return out
-
-
-def _gate_verdict(parsed: Dict[str, Any]) -> str:
-    """One-word verdict from a capture's gate annotations (bench.py
-    apply_regression_gate): FAIL:<legs> when any regression_* flag is
-    set, pass when at least one gate_* section was evaluated, '-' when
-    nothing gated (first capture of a config, or gate opted out)."""
-    def _leg(k):
-        return "s_per_iter" if k == "regression" else k[len("regression_"):]
-
-    regs = sorted(k for k, v in parsed.items()
-                  if k.startswith("regression") and v)
-    if regs:
-        return "FAIL:" + ",".join(_leg(k) for k in regs)
-    if any(k.startswith("gate") for k in parsed):
-        return "pass"
-    return "-"
-
-
-def bench_trend_summary(rounds: List[Any]) -> Dict[str, Any]:
-    """Per-round trajectory of the driver-captured bench history:
-    metric/value/unit and gate verdict per round, plus a per-metric series with the best round —
-    the table form of what previously only lived in raw JSON."""
-    rows: List[Dict[str, Any]] = []
-    for name, doc in rounds:
-        parsed = doc.get("parsed")
-        if not isinstance(parsed, dict):
-            # tolerate raw bench-format files ({"metric": ...} at top)
-            parsed = doc if "metric" in doc else None
-        m = re.match(r"BENCH_(r\d+)", name)
-        row: Dict[str, Any] = {
-            "round": m.group(1) if m else name,
-            "file": name,
-            "rc": doc.get("rc"),
-        }
-        if parsed is None:
-            row["parsed"] = False
-            rows.append(row)
-            continue
-        row.update({
-            "parsed": True,
-            "metric": parsed.get("metric"),
-            "value": parsed.get("value"),
-            "unit": parsed.get("unit"),
-            "vs_baseline": parsed.get("vs_baseline"),
-            "device": parsed.get("device"),
-            "gate_verdict": _gate_verdict(parsed),
-        })
-        rows.append(row)
-    by_metric: Dict[str, List[Dict[str, Any]]] = {}
-    for row in rows:
-        if row.get("parsed") and isinstance(row.get("value"), (int, float)):
-            by_metric.setdefault(str(row["metric"]), []).append({
-                "round": row["round"],
-                "value": row["value"],
-            })
-    trends = {}
-    for metric, pts in by_metric.items():
-        best = min(pts, key=lambda p: p["value"])
-        trends[metric] = {
-            "points": pts,
-            "first": pts[0],
-            "last": pts[-1],
-            "best": best,
-        }
-    return {"rounds": rows, "by_metric": trends}
-
-
-def render_bench_trend(t: Dict[str, Any], bench_dir: str = "") -> str:
-    rows = t["rounds"]
-    lines = [
-        f"=== lightgbm_tpu bench trend"
-        f"{': ' + bench_dir if bench_dir else ''} "
-        f"({len(rows)} round(s)) ==="]
-    lines.append("")
-    lines.append(f"{'round':<7}{'value':>10}{' unit':<8}{'vs_base':>9}"
-                 f"{'backend':<17}{'gate':<22}metric")
-    for r in rows:
-        if not r.get("parsed"):
-            lines.append(f"{r['round']:<7}{'-':>10}{'':<8}{'-':>9}"
-                         f"{'-':<17}{'-':<22}"
-                         f"(unparsed; rc={r.get('rc')})")
-            continue
-        val = f"{r['value']:.4f}" if isinstance(
-            r.get("value"), (int, float)) else "-"
-        vsb = f"{r['vs_baseline']:.2f}x" if isinstance(
-            r.get("vs_baseline"), (int, float)) else "-"
-        dev = str(r.get("device") or "-")
-        metric = str(r.get("metric") or "-")
-        if len(metric) > 46:
-            metric = metric[:43] + "..."
-        lines.append(f"{r['round']:<7}{val:>10}{' ' + str(r.get('unit') or ''):<8}"
-                     f"{vsb:>9}{dev[:16]:<17}{r['gate_verdict'][:21]:<22}"
-                     f"{metric}")
-    for metric, tr in t["by_metric"].items():
-        if len(tr["points"]) < 2:
-            continue
-        first, last, best = tr["first"], tr["last"], tr["best"]
-        speedup = (first["value"] / last["value"]
-                   if last["value"] > 0 else None)
-        short = metric if len(metric) <= 46 else metric[:43] + "..."
-        lines.append("")
-        lines.append(
-            f"trend [{short}]: {first['round']} {first['value']:.4f} -> "
-            f"{last['round']} {last['value']:.4f}"
-            + (f" ({speedup:.2f}x vs first)" if speedup else "")
-            + f"; best {best['round']} {best['value']:.4f}")
-    return "\n".join(lines) + "\n"
-
-
-def bench_trend_main(argv: List[str]) -> int:
-    args = [a for a in argv if not a.startswith("--")]
-    as_json = "--json" in argv
-    if len(args) > 1:
-        sys.stderr.write(
-            "usage: python -m lightgbm_tpu report bench-trend [dir]"
-            " [--json]\n")
-        return 2
-    # default: the repo root (where the driver drops BENCH_r*.json)
-    bench_dir = args[0] if args else os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    rounds = load_bench_rounds(bench_dir)
-    if not rounds:
-        sys.stderr.write(f"no BENCH_r*.json under {bench_dir}\n")
-        return 1
-    t = bench_trend_summary(rounds)
-    if as_json:
-        sys.stdout.write(json.dumps(t) + "\n")
-    else:
-        sys.stdout.write(render_bench_trend(t, bench_dir))
-    return 0
-
-
 def main(argv: List[str]) -> int:
     """CLI entry: ``python -m lightgbm_tpu report
     {<trace.jsonl> | merge <dir|files...> | diff <a> <b> |
-    costs <trace.jsonl> | bench-trend [dir]} [--json]``."""
+    costs <trace.jsonl>} [--json]``."""
     if argv and argv[0] == "merge":
         return merge_main(argv[1:])
     if argv and argv[0] == "diff":
         return diff_main(argv[1:])
     if argv and argv[0] == "costs":
         return costs_main(argv[1:])
-    if argv and argv[0] == "bench-trend":
-        return bench_trend_main(argv[1:])
     args = [a for a in argv if not a.startswith("--")]
     as_json = "--json" in argv
     if not args:
         sys.stderr.write(
             "usage: python -m lightgbm_tpu report "
             "{<trace.jsonl> | merge <dir|files...> | diff <a> <b> | "
-            "costs <trace.jsonl> | bench-trend [dir]} "
-            "[--json]\n"
+            "costs <trace.jsonl>} [--json]\n"
         )
         return 2
     path = args[0]

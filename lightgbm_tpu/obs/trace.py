@@ -5,8 +5,8 @@ The reference ships compile-time TIMETAG phase timers
 destructor printf.  This tracer is the TPU-era replacement: nested
 host-side spans, counters and gauges written as one-record-per-line JSON
 (JSONL) so a failed run still leaves every record flushed before death,
-plus per-iteration summary records that the bench harness and the
-``python -m lightgbm_tpu report`` CLI aggregate.
+plus per-iteration summary records that the
+``python -m lightgbm_tpu report`` CLI aggregates.
 
 Enable with ``LIGHTGBM_TPU_TRACE=/path/to/trace.jsonl`` (re-read at every
 ``engine.train``/``GBDT.init``) or programmatically via
@@ -157,7 +157,6 @@ class Tracer:
         self._iter_t0 = 0.0
         self._iter_compiles0 = 0
         self._atexit_registered = False
-        self._phases_env = None  # cached LIGHTGBM_TPU_TRACE_PHASES
         # rank/world/run_id stamped onto every record in multi-rank runs
         # so `report merge` can correlate per-rank JSONLs (empty in
         # single-process runs: records stay byte-compatible with PR 1)
@@ -170,10 +169,9 @@ class Tracer:
 
     # -- lifecycle -----------------------------------------------------
     def refresh_from_env(self) -> None:
-        """(Re-)read LIGHTGBM_TPU_TRACE / LIGHTGBM_TPU_TRACE_PHASES; called
-        at the training entry points so tests and the CLI can toggle
-        tracing without importing this module early."""
-        self._phases_env = os.environ.get("LIGHTGBM_TPU_TRACE_PHASES", "")
+        """(Re-)read LIGHTGBM_TPU_TRACE; called at the training entry
+        points so tests and the CLI can toggle tracing without importing
+        this module early."""
         self._ident_from_env()
         self._max_bytes = _max_bytes_from_env()
         path = os.environ.get("LIGHTGBM_TPU_TRACE", "")
@@ -249,20 +247,6 @@ class Tracer:
                 pass
         self._f = None
         self.enabled = False
-
-    def phases_enabled(self, default: bool = False) -> bool:
-        """Per-phase (defused) tracing mode: '1' forces on, '0' forces
-        off, unset/'auto' -> caller's default (the partitioned trainer
-        defaults to ON in interpret mode and OFF on a real TPU, where
-        defusing the chunk program changes the very timings being
-        measured)."""
-        if self._phases_env is None:
-            self._phases_env = os.environ.get("LIGHTGBM_TPU_TRACE_PHASES", "")
-        if self._phases_env == "1":
-            return True
-        if self._phases_env == "0":
-            return False
-        return default
 
     # -- emission ------------------------------------------------------
     def _emit(self, rec: Dict[str, Any]) -> None:
@@ -406,8 +390,7 @@ class Tracer:
 
     # -- aggregates ----------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
-        """Host-side aggregate view (phase totals/counts, counters) —
-        what bench.py embeds into its JSON output."""
+        """Host-side aggregate view (phase totals/counts, counters)."""
         return {
             "spans": {
                 name: {"total_s": round(t, 6), "count": c,

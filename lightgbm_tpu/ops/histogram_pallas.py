@@ -76,7 +76,7 @@ def tune_fchunk(num_features: int, num_bins: int,
 def fchunk_cost(num_features: int, num_bins: int, fchunk: int) -> int:
     """Estimated per-block MXU row cost of a feature-chunk width: sum of
     128-padded one-hot rows over chunks plus a fixed per-dot issue
-    overhead.  Exposed for the bench kernel A/B report."""
+    overhead."""
     cost, rem, chunks = 0, num_features, 0
     while rem > 0:
         c = min(fchunk, rem)
@@ -468,8 +468,7 @@ def hist_segment_q(
     """(F, B, 3) EXACT int32 histogram of columns [lo, hi) of a
     quantized packed matrix (``pack_columns_q``) — the quantized-training
     twin of :func:`hist_segment`.  The output is order-invariant by
-    construction (integer adds), which the bench ``kernel_ab`` leg pins
-    against the f32 kernel.  Levels must fit int16."""
+    construction (integer adds).  Levels must fit int16."""
     c, s = p.shape
     assert s % BLK == 0, f"segment length {s} not a multiple of {BLK}"
     if rows is None:

@@ -99,22 +99,20 @@ class PGrowParams(NamedTuple):
     # device then takes the identical best split on its local segment).
     # None/"" = serial.
     axis_name: str = None
-    # level-batched expansion (phase 1) toggles.  These used to be env
-    # reads (LIGHTGBM_TPU_LEVELGROW / LIGHTGBM_TPU_MAXLVL) at trace time
-    # inside the jitted grower — invisible to the jit cache key, so a
-    # mid-process env change silently did nothing.  They are now read
-    # ONCE at trainer construction (boosting/ptrainer.py) and threaded
-    # here, where the static params tuple IS the cache key.
+    # level-batched expansion (phase 1) toggle.  It used to be an env
+    # read (LIGHTGBM_TPU_LEVELGROW) at trace time inside the jitted
+    # grower — invisible to the jit cache key, so a mid-process env
+    # change silently did nothing.  It is now read ONCE at trainer
+    # construction (boosting/ptrainer.py) and threaded here, where the
+    # static params tuple IS the cache key.
     levelwise: bool = True
-    max_levels: int = 24
 
 
 def levelgrow_env_params() -> dict:
-    """Read the level-grower env knobs once — construction-time helper
+    """Read the level-grower env knob once — construction-time helper
     for PGrowParams(**levelgrow_env_params())."""
     return {
         "levelwise": os.environ.get("LIGHTGBM_TPU_LEVELGROW", "1") != "0",
-        "max_levels": int(os.environ.get("LIGHTGBM_TPU_MAXLVL", "24")),
     }
 
 
@@ -320,7 +318,7 @@ def grow_tree_partitioned(
             # still free, and whether a tree wanted one moved a 255-leaf
             # iteration by 6% from one seed to the next.  Those splits are
             # the replay's, one at a time.
-            MAXLVL = min(params.max_levels, (SMAX - 1).bit_length() + 1)
+            MAXLVL = (SMAX - 1).bit_length() + 1
             c_seg0 = jnp.zeros((CANDMAX, 2), jnp.int32).at[0, 1].set(n)
             c_bs0 = jnp.full((CANDMAX, 8), NEG_INF, jnp.float32).at[0].set(root_bs)
             c_leaf0 = jnp.zeros((CANDMAX, 8), jnp.float32).at[0].set(root_leaf)
@@ -632,8 +630,8 @@ def level_hists(p, seg_tab, n_active, params: PGrowParams, rows=None,
 
     The fused grower normally gets level histograms for free from
     ``level_stream``'s partition pass; this helper serves callers that
-    need segment histograms OUTSIDE a partition (root histograms, the
-    kernel A/B harness in bench.py, numerics tripwires), at one launch
+    need segment histograms OUTSIDE a partition (root histograms,
+    numerics tripwires), at one launch
     per level instead of one per leaf.  seg_tab: (smax, 2) int32 rows of
     [start, cnt]."""
     G = params.num_cols or params.num_features
@@ -682,8 +680,8 @@ def split_audit_rows(gr):
     ``Tree.from_grow_result`` consumes (``ops/grow.GrowResult``, this
     module's :class:`PTreeResult`, ``ptrainer.grow_result_view``), which
     is exactly why audit trails are comparable across the mask, fused
-    classic (LEVELGROW=0), level-batched (LEVELGROW=1) and traced
-    trainer paths: they all converge on these records.  Values are
+    classic (LEVELGROW=0) and level-batched (LEVELGROW=1) trainer
+    paths: they all converge on these records.  Values are
     pulled once per tree (one host transfer for device-resident views)
     and floats keep their stored f32 identity so two bit-identical
     record buffers yield identical rows."""

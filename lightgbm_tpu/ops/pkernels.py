@@ -883,7 +883,7 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
 
 # ======================================================================
 # score_add: in-place score-row segment update (multiclass per-tree,
-# chunk-end settle, traced score_update)
+# chunk-end settle)
 # ======================================================================
 def _score_band_kernel(aux_any, p_in, p_any, buf, abuf, rsem, asem, wsem, *,
                        band0, bandn, nblk, score_off):

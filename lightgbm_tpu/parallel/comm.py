@@ -11,15 +11,14 @@ communicators implement that surface:
   identical to every other collective in the repo;
 - :class:`LocalComm` simulates R ranks inside one process with a
   barrier-synchronized slot exchange.  It exists for fast determinism
-  tests and the device-independent comms-volume bench: byte counts are
+  tests and device-independent comms-volume counts: byte counts are
   exact and identical to what NetComm would send, without subprocesses.
 
 Both keep an always-on ``ledger`` mapping purpose -> bytes sent by this
 rank (``hist`` / ``best_split`` / ``vote`` / ``elect``, plus ``hist_q``
 for the quantized-training int16 histogram wire and its scale/root-sum
-side channels), independent of whether tracing is enabled — the bench
-comms section and the per-iter ``net_bytes`` report field read it
-directly.  Under ``quantized_training`` the per-node histogram payload
+side channels), independent of whether tracing is enabled — the
+per-iter ``net_bytes`` report field reads it directly.  Under ``quantized_training`` the per-node histogram payload
 moves from f32x3 (``hist``, F*B*12 bytes) to int16x2 (``hist_q``,
 F*B*4 bytes — the count plane is derived at the receiver), a fixed 3x
 wire reduction; the report CLI surfaces the measured ratio per

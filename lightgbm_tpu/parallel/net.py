@@ -295,7 +295,7 @@ _fault_lock = threading.Lock()
 # row-sharded learner, so an injected per-collective slowdown models a
 # host whose PER-ROW compute is slow: moving rows off the straggler
 # shrinks its injected stall proportionally, making shard rebalancing
-# measurable on CPU (bench.py elastic section).
+# measurable on CPU (tests/test_rebalance.py).
 _delay_scale = 1.0
 
 

@@ -960,7 +960,7 @@ def spawn_replicas(n: int, serve_params: Dict[str, str],
     """Launch ``n`` ``python -m lightgbm_tpu serve`` subprocesses.
 
     ``envs[i]`` overlays extra environment onto replica ``i`` — how the
-    chaos harness and bench arm per-replica fault injection
+    chaos harness arms per-replica fault injection
     (``LIGHTGBM_TPU_SERVE_FAULT``) without touching the shared argv."""
     ports = ports or _free_ports(n, host)
     procs = []

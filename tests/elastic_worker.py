@@ -1,5 +1,5 @@
 """Worker for the elastic-training matrix (test_ckpt_fault.py topology
-legs, test_rebalance.py, bench.py's ``elastic`` section).
+legs, test_rebalance.py).
 
 argv: ``rank nproc port out mode ckdir``.  Every rank of one phase runs
 this script; the parent varies ``nproc`` between phases — that is the

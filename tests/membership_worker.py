@@ -1,5 +1,5 @@
 """Worker for the live-membership matrix (test_membership.py,
-bench.py's ``spot`` section, factory/spot.py fleets).
+factory/spot.py fleets).
 
 argv: ``member_id fleet_dir out`` — unlike elastic_worker.py there is
 NO jax.distributed bootstrap: every member runs single-process JAX and

@@ -1,5 +1,4 @@
-"""Worker for the distributed out-of-core matrix (tests/test_oocdist.py,
-bench.py's ``ooc_distributed`` section).
+"""Worker for the distributed out-of-core matrix (tests/test_oocdist.py).
 
 argv: ``rank nproc port out mode ckdir`` — the same shape as
 elastic_worker.py, and the same world-invariant data recipe: the GLOBAL

@@ -1,8 +1,8 @@
 """Cost-model tests: roofline arithmetic on a synthetic spec, peak-spec
 resolution + the LIGHTGBM_TPU_PEAK_SPECS override, the JitWatch
 first-compile HLO capture on CPU, the efficiency join (program costs x
-measured phase spans), the ``report costs`` / ``report bench-trend``
-CLIs, JSONL trace rotation, and the bounded xprof capture harness.
+measured phase spans), the ``report costs`` CLI, JSONL trace rotation,
+and the bounded xprof capture harness.
 """
 
 import glob
@@ -198,34 +198,35 @@ class TestCaptureOnCpu:
 
     def test_traced_training_populates_inventory_and_joins(
             self, global_trace, monkeypatch):
-        """Inventory completeness: a traced-phases training run must
-        yield cost records for the traced per-phase programs, and the
-        offline join must produce an efficiency table with a
-        next-target pick — the `report costs` acceptance path."""
+        """Inventory completeness: a traced training run on the fused
+        trainer must yield a cost record for the chunk program, and the
+        offline join must put it against the ``chunk_program`` span with
+        a next-target pick — the `report costs` acceptance path."""
         monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
-        monkeypatch.setenv("LIGHTGBM_TPU_TRACE_PHASES", "1")
         monkeypatch.setenv("LIGHTGBM_TPU_COSTMODEL_DEEP_BUDGET", "60")
-        # shape chosen to be unique across the test session so every
-        # traced program sees a fresh signature
+        # shape chosen to be unique across the test session so the
+        # chunk program sees a fresh signature
         X, y = _toy(613, 6, seed=3)
         lgb.train({"objective": "binary", "num_leaves": 7, "verbose": -1},
                   lgb.Dataset(X, label=y), num_boost_round=3,
                   verbose_eval=False)
 
         inv = costmodel.inventory()
-        traced = {n for n in inv if n.startswith("ptrainer.traced.")}
-        assert len(traced) >= 4, f"traced programs missing costs: {inv.keys()}"
+        chunk = [n for n in inv if n.startswith("ptrainer.chunk(")]
+        assert chunk, f"the chunk program has no cost record: {inv.keys()}"
+        assert inv[chunk[0]]["phase"] == "chunk_program"
 
         from lightgbm_tpu.obs import tracer
 
         tracer.close()
         recs = _read(global_trace)
+        assert any(r.get("name") == "jax_cost" and r["program"] == chunk[0]
+                   and r["phase"] == "chunk_program" for r in recs)
         summary = costmodel.costs_summary(recs)
-        assert summary["n_programs"] >= 4
+        assert summary["n_programs"] >= 1
         rows = summary["table"]
         assert rows, "no joinable phases"
-        phases = {r["phase"] for r in rows}
-        assert {"histogram", "partition"} <= phases
+        assert "chunk_program" in {r["phase"] for r in rows}
         for r in rows:
             assert r["calls"] > 0 and r["measured_s"] > 0
             assert r["roofline_s"] >= 0
@@ -393,68 +394,6 @@ class TestTraceRotation:
         assert _max_bytes_from_env() == 0
         monkeypatch.setenv("LIGHTGBM_TPU_TRACE_MAX_MB", "2")
         assert _max_bytes_from_env() == 2 * 1024 * 1024
-
-
-class TestBenchTrend:
-    def _write_rounds(self, d):
-        docs = {
-            # ungated first capture
-            "BENCH_r1.json": {"n": 1, "rc": 0, "parsed": {
-                "metric": "train.s_per_iter", "value": 2.0, "unit": "s",
-                "vs_baseline": 1.0, "device": "TPU v4"}},
-            # gated and passing
-            "BENCH_r2.json": {"n": 2, "rc": 0, "parsed": {
-                "metric": "train.s_per_iter", "value": 1.0, "unit": "s",
-                "vs_baseline": 2.0, "device": "TPU v4",
-                "gate_s_per_iter": {"baseline": 2.0}}},
-            # crashed round: no parsed payload
-            "BENCH_r3.json": {"n": 3, "rc": 1, "parsed": None,
-                              "tail": "boom"},
-            # regressed on two legs
-            "BENCH_r4.json": {"n": 4, "rc": 0, "parsed": {
-                "metric": "train.s_per_iter", "value": 1.5, "unit": "s",
-                "device": "TPU v4", "gate_s_per_iter": {"baseline": 1.0},
-                "regression": True, "regression_comms_payload": True}},
-        }
-        for name, doc in docs.items():
-            with open(os.path.join(d, name), "w") as f:
-                json.dump(doc, f)
-
-    def test_rounds_verdicts_and_best(self, tmp_path):
-        d = str(tmp_path)
-        self._write_rounds(d)
-        # an unparsable file is skipped with a warning, not fatal
-        with open(os.path.join(d, "BENCH_r0.json"), "w") as f:
-            f.write("{truncated")
-        rounds = report.load_bench_rounds(d)
-        assert [n for n, _ in rounds] == [
-            "BENCH_r1.json", "BENCH_r2.json", "BENCH_r3.json",
-            "BENCH_r4.json"]
-        t = report.bench_trend_summary(rounds)
-        r1, r2, r3, r4 = t["rounds"]
-        assert r1["gate_verdict"] == "-"
-        assert r2["gate_verdict"] == "pass"
-        assert r3["parsed"] is False and r3["rc"] == 1
-        assert r4["gate_verdict"] == "FAIL:s_per_iter,comms_payload"
-        trend = t["by_metric"]["train.s_per_iter"]
-        assert trend["first"]["round"] == "r1"
-        assert trend["last"]["round"] == "r4"
-        assert trend["best"]["round"] == "r2"
-
-    def test_render_and_cli_json(self, tmp_path, capsys):
-        d = str(tmp_path)
-        self._write_rounds(d)
-        assert report.bench_trend_main([d]) == 0
-        out = capsys.readouterr().out
-        assert "bench trend" in out
-        assert "trend [train.s_per_iter]" in out
-        assert "best r2" in out
-        assert report.main(["bench-trend", d, "--json"]) == 0
-        doc = json.loads(capsys.readouterr().out)
-        assert len(doc["rounds"]) == 4
-
-    def test_empty_dir_fails_cleanly(self, tmp_path, capsys):
-        assert report.bench_trend_main([str(tmp_path / "empty")]) == 1
 
 
 class TestXprofHarness:

@@ -60,6 +60,17 @@ def _result(out, rank):
         return json.load(fh)
 
 
+def _assert_terminated_by_xla_poller(rc, log):
+    """The survivor was stopped from C++ by XLA's coordination-service error
+    poller, not by an exception of ours: SIGABRT, or exit code 1 (jaxlib
+    0.9.0 ends the process with a quiet fatal log, which is `_exit(1)`), and
+    in either case the poller's own line is in the log."""
+    assert rc in (-signal.SIGABRT, 1), log[-2000:]
+    lines = (("JAX distributed service detected fatal errors",) if rc == 1
+             else ("another task died", "UNAVAILABLE"))
+    assert any(line in log for line in lines), log[-2000:]
+
+
 # ----------------------------------------------------------------------
 # tier-1 smoke legs
 # ----------------------------------------------------------------------
@@ -71,10 +82,12 @@ def test_sigkill_mid_allgather_detected_by_all_survivors(tmp_path):
     same two legitimate outcomes as coordinator death
     (docs/ROBUSTNESS.md): our sweeper classifies a typed
     PeerFailureError naming rank 2 within the detection bound, or XLA's
-    in-process error poller wins the race and fail-fast aborts the
-    survivor from C++ (SIGABRT, "another task died") — that poller is
-    not interceptable from Python and occasionally outruns the sweeper
-    on a loaded box."""
+    in-process error poller wins the race and fail-fast terminates the
+    survivor from C++ (SIGABRT or exit 1, "another task died") — that
+    poller is not interceptable from Python and outruns the sweeper on a
+    loaded box: rank 0 hosts the coordination service, so once it has
+    classified the failure and left, rank 1's poller sees the service
+    gone (5 runs of 10 beside seven busy processes, PR 31)."""
     import time
 
     out = str(tmp_path / "g")
@@ -97,10 +110,8 @@ def test_sigkill_mid_allgather_detected_by_all_survivors(tmp_path):
             assert 2 in res["ranks"], res
             assert res["wall"] <= DETECT_BOUND, res
             typed += 1
-        else:  # XLA's fail-fast poller aborted the survivor from C++
-            assert rc == -signal.SIGABRT, logs[r][-2000:]
-            assert ("another task died" in logs[r]
-                    or "UNAVAILABLE" in logs[r]), logs[r][-2000:]
+        else:  # XLA's fail-fast poller stopped the survivor from C++
+            _assert_terminated_by_xla_poller(rc, logs[r])
     # the whole point: nobody hangs on the dead peer
     assert wall <= DETECT_BOUND + 30.0
 
@@ -325,7 +336,8 @@ def test_coordinator_death_is_bounded_not_a_hang(tmp_path):
     must stop the survivor PROMPTLY.  Two legitimate outcomes
     (docs/ROBUSTNESS.md): our sweeper classifies PeerFailureError and
     exits 0 through the flush path, or XLA's in-process error poller
-    wins the race and fail-fast aborts the survivor from C++ (SIGABRT).
+    wins the race and fail-fast terminates the survivor from C++ (SIGABRT
+    or exit 1).
     Either way nothing hangs, and the atomic checkpoint store means the
     last durable checkpoint survives for auto-resume."""
     import time
@@ -346,10 +358,8 @@ def test_coordinator_death_is_bounded_not_a_hang(tmp_path):
         res = _result(out, 1)
         assert res["error"] == "PeerFailureError", res
         assert res["wall"] <= DETECT_BOUND, res
-    else:  # XLA's fail-fast poller aborted the survivor from C++
-        assert rc1 == -signal.SIGABRT, logs[1][-2000:]
-        assert "another task died" in logs[1] or "UNAVAILABLE" in logs[1], \
-            logs[1][-2000:]
+    else:  # XLA's fail-fast poller stopped the survivor from C++
+        _assert_terminated_by_xla_poller(rc1, logs[1])
     # the whole point: no indefinite hang on a dead coordinator
     assert wall <= DETECT_BOUND + 30.0
 

@@ -746,9 +746,9 @@ class TestGossFused:
 
 class TestLevelGrowerCaps:
     """Stress the level grower where its static caps bind (VERDICT item
-    7): num_leaves=1023 exceeds the default level budget unless MAXLVL
-    and the frontier sizing hold up, and the level-batched path must
-    stay tree-identical to the per-split grower."""
+    7): num_leaves=1023 exceeds the 512-slot frontier and the level
+    budget it implies, and the level-batched path must stay
+    tree-identical to the per-split grower."""
 
     def test_num_leaves_1023_parity_with_levelgrow_off(self, monkeypatch):
         import lightgbm_tpu as lgb

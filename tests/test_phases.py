@@ -312,7 +312,6 @@ def test_tracing_off_costs_nothing(tracing_off, counted):
 def traced_chunks(tmp_path, monkeypatch, counted):
     """Two fused chunks of two iterations with the JSONL sink on."""
     monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
-    monkeypatch.setenv("LIGHTGBM_TPU_TRACE_PHASES", "0")
     X, y, _ = _toy()
     bst = _booster(X, y)
     path = str(tmp_path / "trace.jsonl")
@@ -361,8 +360,10 @@ def test_trees_from_records_carries_the_stream_counts(traced_chunks):
         assert t["hist_cells"] == hist_lanes(f, b) and t["hist_cells"] % 128 == 0
         assert t["hist_cells"] >= f * b
         # two trees a chunk: every tree's first level streams every row once,
-        # and no level streams a row twice
-        assert 2 <= t["levels"] <= 2 * pt.params.max_levels
+        # no level streams a row twice, and a tree's level phase is bounded by
+        # its candidate table (log2(SMAX) + 1 levels, ops/pgrow.py)
+        smax = min(-(-(pt.params.num_leaves + 1) // 8) * 8, 512)
+        assert 2 <= t["levels"] <= 2 * ((smax - 1).bit_length() + 1)
         assert 2 * n <= t["level_rows"] <= t["levels"] * n
         assert t["levels"] <= t["level_segments"] <= 2 * (pt.params.num_leaves - 1) * t["levels"]
 
@@ -397,7 +398,6 @@ def test_program_records_only_on_demand(traced_chunks):
 
 def test_trees_and_scores_bit_equal_with_and_without_tracing(tmp_path, monkeypatch):
     monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
-    monkeypatch.setenv("LIGHTGBM_TPU_TRACE_PHASES", "0")
     monkeypatch.delenv("LIGHTGBM_TPU_TRACE", raising=False)
     X, y, _ = _toy(400, 5)
     out = {}
