@@ -11,10 +11,13 @@ jitted program.  Per iteration:
       fresh gradients from the score/label channels; bagging select; the
       root histogram of the fresh values)           [in-place Pallas]
     -> feature sampling -> grow_tree_partitioned    [split_stream kernels]
-    -> the tree's score delta is carried PENDING to the next iteration's
-       update (the row layout doesn't change in between: a tree starts
-       in the order the previous tree's partition left) and settled
-       by one extra pass at chunk end.
+    -> the tree's score delta (one (n,) vector, each row's leaf value
+       selected by comparing its position with the sorted segment
+       bounds: ops/pgrow.segment_values, no n-row gather or scatter) is
+       carried PENDING to the next iteration's update (the row layout
+       doesn't change in between: a tree starts in the order the
+       previous tree's partition left) and settled by one extra pass at
+       chunk end.
     GOSS prepends a gradient-only pass + device top_k/Bernoulli sampling
     with the (n-top_k)/other_k up-weighting folded into g/h (goss.hpp).
 

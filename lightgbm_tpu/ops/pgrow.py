@@ -656,20 +656,42 @@ def segment_values(tree: PTreeResult, num_rows: int, values: jnp.ndarray) -> jnp
     sum whose reassociation differs per position — and the physical
     order of rows inside a segment is NOT layout-stable (the level
     grower's speculative partitions shuffle it), so that residue made
-    training scores depend on partition history.  Instead: an integer
-    cumsum over segment-start marks (exact) ranks each position's
-    covering segment, and the value is gathered — every row of a leaf
-    gets the bit-identical ``values[leaf]``."""
-    L = tree.starts.shape[0]
-    active = jnp.arange(L) <= tree.num_splits
-    v = jnp.where(active, values, 0.0)
+    training scores depend on partition history.  Instead the value is
+    SELECTED, never computed: the live segments are sorted by start, a
+    position is compared with every sorted bound, and the int32 bits of
+    the one segment whose ``[start, next start)`` holds it are OR-ed
+    out of an otherwise zero column — every row of a leaf gets the
+    bit-identical ``values[leaf]`` (``-0.0`` included).
+
+    One fusion over the row vector: the ``(L, N)`` operand of the
+    reduction is never built (6.5 ms at 21M rows x 255 leaves where a
+    rank by scatter and cumsum and two gathers took 383; PERF.md, PR 32).
+    Jitted here so that an eager caller gets the fusion too.
+
+    Rows that no segment covers (none in the serial programs, whose
+    segments tile ``[0, N)``): before the first start they read the
+    first segment's value, past a segment's end that segment's, up to
+    the next start (a shard's padded rows)."""
+    return _segment_lookup(tree.starts, tree.cnts, tree.num_splits, values, num_rows)
+
+
+@functools.partial(jax.jit, static_argnums=4)
+def _segment_lookup(starts, cnts, num_splits, values, num_rows):
+    L = starts.shape[0]
+    active = jnp.arange(L) <= num_splits
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(active, values, 0.0).astype(jnp.float32), jnp.int32)
     # empty segments share their start with a neighbour: park them (and
-    # inactive slots) past the end so they never win the rank lookup
-    s = jnp.where(active & (tree.cnts > 0), tree.starts, num_rows)
-    marks = jnp.zeros((num_rows + 1,), jnp.int32).at[s].add(1)
-    rank = jnp.cumsum(marks)[:num_rows] - 1
-    order = jnp.argsort(s)  # segment slots in physical start order
-    return jnp.take(v, jnp.take(order, jnp.clip(rank, 0, L - 1)))
+    # inactive slots) past the end, where no position looks
+    s = jnp.where(active & (cnts > 0), starts, num_rows)
+    lo, bits = jax.lax.sort((s, bits), num_keys=1)  # physical start order
+    lo = lo.at[0].set(0)
+    hi = jnp.concatenate([lo[1:], jnp.full((1,), num_rows, lo.dtype)])
+    pos = jax.lax.broadcasted_iota(jnp.int32, (L, num_rows), 1)
+    mine = (pos >= lo[:, None]) & (pos < hi[:, None])  # one segment a column
+    out = jax.lax.reduce(jnp.where(mine, bits[:, None], 0), jnp.int32(0),
+                         jax.lax.bitwise_or, (0,))
+    return jax.lax.bitcast_convert_type(out, jnp.float32)
 
 
 def split_audit_rows(gr):

@@ -10,7 +10,9 @@ level grower speculatively partitions candidate levels best-first
 acceptance never takes), and ``segment_values``' float range-add
 cumsum carried position-dependent 1-ULP residue — so training scores,
 and from round 2 on the gradients, depended on partition history.
-Fixed by an exact integer-rank gather in ``segment_values``, so the
+Fixed by an exact lookup in ``segment_values`` (a value is selected by
+its bits, never computed: first by integer rank and gather, since PR 32
+by comparing the position with the sorted segment bounds), so the
 repro class asserts parity; ``report diff`` localization is covered on
 synthetic trails in TestReportDiff.
 
@@ -140,10 +142,11 @@ class TestLevelgrowDivergenceRepro:
     ``segment_values`` float-cumsum range-add gave different rows
     1-ULP-different score deltas depending on position — so from round
     2 on, gradients (hence one leaf value of tree 2) diverged.  Fixed
-    by the exact integer-rank ``segment_values`` gather; this class pins
-    the parity, which holds here without the canonical reorder PR 30
-    deleted (module docstring; the synthetic-trail localization coverage
-    lives in TestReportDiff)."""
+    by ``segment_values`` selecting each row's value bit for bit (by
+    range compare since PR 32); this class pins the parity, which holds
+    here without the canonical reorder PR 30 deleted (module docstring;
+    the synthetic-trail localization coverage lives in
+    TestReportDiff)."""
 
     @pytest.fixture(scope="class")
     def trails(self, tmp_path_factory):

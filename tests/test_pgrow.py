@@ -318,6 +318,66 @@ class TestUpdateChannels:
         np.testing.assert_array_equal(P2n[lay.ROWID], np.asarray(P)[lay.ROWID])
 
 
+def _tiled_case(L, n, live, seed, values=None, cuts=None):
+    """A ``segment_values`` case whose ``live`` non-empty segments tile
+    ``[0, n)`` in shuffled slots of an ``L``-slot table; the slots past
+    ``num_splits`` hold stale starts and counts, as a finished tree's do.
+    Returns (starts, cnts, num_splits, values, n, expected row vector)."""
+    rng = np.random.default_rng(seed)
+    if cuts is None:
+        cuts = np.sort(rng.choice(np.arange(1, n), size=live - 1, replace=False))
+    seg_start = np.concatenate([[0], cuts]).astype(np.int64)
+    seg_cnt = np.diff(np.concatenate([seg_start, [n]]))
+    slots = rng.permutation(live)
+    starts = rng.integers(0, n, size=L)  # stale
+    cnts = rng.integers(1, n, size=L)
+    starts[slots], cnts[slots] = seg_start, seg_cnt
+    if values is None:
+        values = rng.standard_normal(L)
+    values = np.asarray(values, np.float32)
+    expect = np.repeat(values[slots], seg_cnt)
+    return starts, cnts, live - 1, values, n, expect
+
+
+def _with_empty_neighbours():
+    """Live slots of count 0 that share their start with the segment after
+    them (a split that sent every row one way), one of them at 0 and one at
+    ``n``."""
+    starts = np.array([0, 0, 700, 700, 700, 1500, 2000, 11, 12], np.int64)
+    cnts = np.array([0, 700, 0, 0, 800, 500, 0, 5, 6], np.int64)
+    vals = np.arange(1, 10, dtype=np.float32)  # slots 7, 8 are inactive
+    expect = np.repeat(vals[[1, 4, 5]], [700, 800, 500])
+    return starts, cnts, 6, vals, 2000, expect
+
+
+def _uncovered():
+    """What the serial programs never hold and the sharded one does: rows no
+    segment covers.  Before the first start they read the first segment's
+    value, past the last segment's end the last one's (a shard's padded
+    rows)."""
+    starts, cnts = np.array([40, 10, 3000], np.int64), np.array([60, 30, 1], np.int64)
+    vals = np.array([2.0, -3.0, 9.0], np.float32)
+    expect = np.repeat(vals[[1, 0]], [40, 1160])
+    return starts, cnts, 1, vals, 1200, expect
+
+
+_SPECIAL = [-0.0, 0.0, 100.0, -100.0, 1e-38, -1e-38, 3.4e38, 0.1]
+_SEGMENT_CASES = {
+    "L4_the_old_case": lambda: _tiled_case(4, 20, 4, 0, [1.0, 2.0, 3.0, 4.0], cuts=[4, 10, 17]),
+    "L31_n_off_1024": lambda: _tiled_case(31, 5000, 31, 1),
+    "L255_full": lambda: _tiled_case(255, 3 * 1024 + 17, 255, 2),
+    "L255_half_grown_stale_slots": lambda: _tiled_case(255, 4096, 97, 3),
+    "no_split_one_segment": lambda: _tiled_case(31, 2500, 1, 4),
+    "bounds_on_and_off_1024": lambda: _tiled_case(
+        8, 8192, 8, 5, cuts=[1024, 2047, 2048, 2049, 4096, 7168, 8191]),
+    "one_row_segments": lambda: _tiled_case(6, 3000, 6, 6, cuts=[1, 2, 1024, 1025, 2999]),
+    "signed_zeros_and_clamps": lambda: _tiled_case(8, 2100, 8, 7, _SPECIAL),
+    "keep_0_negative_zero_everywhere": lambda: _tiled_case(31, 1500, 31, 8, [-0.0] * 31),
+    "empty_segments_share_a_start": _with_empty_neighbours,
+    "uncovered_rows": _uncovered,
+}
+
+
 class TestGrowParity:
     def test_tree_matches_mask_grower(self):
         """grow_tree_partitioned must reproduce grow_tree's split records
@@ -359,16 +419,21 @@ class TestGrowParity:
         lid = leaf_id_from_segments(pres, P2, lay, n)
         np.testing.assert_array_equal(np.asarray(lid), np.asarray(gres.leaf_id))
 
-    def test_segment_values(self):
+    @pytest.mark.parametrize("case", sorted(_SEGMENT_CASES))
+    def test_segment_values(self, case):
+        """Every row of a leaf gets the BITS of ``values[leaf]`` (-0.0 stays
+        -0.0): compared as int32 with numpy's repeat over the live segments in
+        start order."""
         import types
 
-        starts = jnp.asarray([0, 10, 4, 17], jnp.int32)
-        cnts = jnp.asarray([4, 7, 6, 3], jnp.int32)
-        tree = types.SimpleNamespace(starts=starts, cnts=cnts, num_splits=jnp.int32(3))
-        vals = jnp.asarray([1.0, 2.0, 3.0, 4.0])
-        out = np.asarray(segment_values(tree, 20, vals))
-        expect = np.concatenate([[1.0] * 4, [3.0] * 6, [2.0] * 7, [4.0] * 3])
-        np.testing.assert_allclose(out, expect)
+        starts, cnts, num_splits, vals, n, expect = _SEGMENT_CASES[case]()
+        tree = types.SimpleNamespace(starts=jnp.asarray(starts, jnp.int32),
+                                     cnts=jnp.asarray(cnts, jnp.int32),
+                                     num_splits=jnp.int32(num_splits))
+        out = np.asarray(segment_values(tree, n, jnp.asarray(vals, jnp.float32)))
+        assert out.shape == (n,) and out.dtype == np.float32
+        np.testing.assert_array_equal(out.view(np.int32),
+                                      np.asarray(expect, np.float32).view(np.int32))
 
 
 class TestFourBitPacking:

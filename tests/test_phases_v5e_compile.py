@@ -87,20 +87,33 @@ def _small_trainer(rows=ROWS, cols=28, small_rows=20_000, **more):
     return pt
 
 
+def _compiled_chunk_program(pt, one_chip, rows, cols):
+    """(text, argument bytes, temporary bytes) of ``pt``'s serial chunk
+    program at ``rows`` x ``cols``, compiled for the described chip."""
+    prog = pt._build_program(pt.CHUNK_ALLOC, False, 1, cols)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    key = pt._base_key
+    compiled = prog.lower(
+        spec((pt.p.shape[0], rows + 1024), jnp.int32), spec((), jnp.float32),
+        spec(key.shape, key.dtype), spec((), jnp.int32), spec((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    return compiled.as_text(), mem.argument_size_in_bytes, mem.temp_size_in_bytes
+
+
 @pytest.fixture(scope="module")
-def compiled_text(one_chip):
-    """Compiled HLO text of the serial chunk program at 21M rows x 28 features."""
+def higgs_compiled(one_chip):
+    """The serial chunk program at 21M rows x 28 features: (text, argument
+    bytes, temporary bytes)."""
     with _compiling_for_a_described_chip():
-        pt = _small_trainer()
-        prog = pt._build_program(pt.CHUNK_ALLOC, False, 1, 28)
+        return _compiled_chunk_program(_small_trainer(), one_chip, ROWS, 28)
 
-        def spec(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-        key = pt._base_key
-        lowered = prog.lower(spec((pt.p.shape[0], ROWS + 1024), jnp.int32), spec((), jnp.float32),
-                             spec(key.shape, key.dtype), spec((), jnp.int32), spec((), jnp.int32))
-        return lowered.compile().as_text()
+@pytest.fixture(scope="module")
+def compiled_text(higgs_compiled):
+    return higgs_compiled[0]
 
 
 @pytest.fixture(scope="module")
@@ -181,7 +194,8 @@ def test_no_program_reorders_the_matrix(request, cell):
     nothing in them gathers, scatters, sorts, transposes or copies an array of
     the matrix's size (the parent's: `fusion s32[21000000,16]`, 469 ms a tree,
     and at 512 channel rows a transposing copy around a gather of 2 KB rows).
-    Row vectors of `n` elements still move, in `leaf_delta` and the epilogue."""
+    A row vector of `n` elements still moves in the epilogue (its score
+    scatter); `leaf_delta`'s went in PR 32 (the test after this one)."""
     if cell == "higgs_21m_x_28":
         text, rows, channels = request.getfixturevalue("compiled_text"), ROWS, 16
     else:
@@ -192,8 +206,71 @@ def test_no_program_reorders_the_matrix(request, cell):
     assert "canon_reorder" not in text
     assert pm["matrix_copies"] == []
     assert _moves_of_matrix_size(text, channels * rows) == []
-    # the check can see a row vector's move, so it would have seen the matrix's
-    assert _moves_of_matrix_size(text, rows)
+    # the check sees such a move where there is one
+    planted = f"  %fusion.1 = s32[{rows},{channels}]{{1,0:T(8,128)}} gather(%p, %i), offset_dims={{1}}\n"
+    assert _moves_of_matrix_size(planted, channels * rows) == [
+        ("fusion.1", "gather", f"s32[{rows},{channels}]{{1,0:T(8,128)}}")]
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_ANY_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]*)\]")
+_CALLS = re.compile(r"\bcalls=%([\w.-]+)")
+
+
+_Inst = collections.namedtuple("_Inst", "name shape opcode calls")
+
+
+def _by_computation(text):
+    """computation -> its instructions (``calls``: the body of a fusion)"""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and (m := _RESULT.match(line)):
+            called = _CALLS.search(line)
+            cur.append(_Inst(*m.groups(), called.group(1) if called else None))
+    return comps
+
+
+def _dims(shape):
+    """The dimensions of every array in a result shape (a tuple has several)."""
+    return [[int(d) for d in dims.split(",") if d] for dims in _ANY_ARRAY.findall(shape)]
+
+
+@pytest.mark.parametrize("cell", ["higgs_21m_x_28", "epsilon_400k_x_2000"])
+def test_leaf_delta_looks_values_up_by_compare(request, cell):
+    """PR 32: a row's leaf value comes from comparing its position with the
+    255 sorted segment bounds in ONE fusion (``ops/pgrow.py::segment_values``).
+    Nothing the phase map gives to `leaf_delta`, fusion bodies included,
+    gathers, scatters, sorts or prefix-sums `n` or more elements (the parent's:
+    a scatter into ``s32[n+1]``, a ``reduce-window`` cumsum and two gathers of
+    ``[n]``, 377 of the phase's 383 ms at 21M rows); the ``(255, n)`` operand
+    of the lookup's reduction exists inside its fusion only; and the program's
+    temporaries are no more than the parent's."""
+    if cell == "higgs_21m_x_28":
+        (text, _, temps), rows, parent_temps = request.getfixturevalue("higgs_compiled"), ROWS, 845_272_064
+    else:
+        (text, _, temps), rows, parent_temps = (
+            request.getfixturevalue("epsilon_compiled"), EPS_ROWS, 6_976_412_160)
+    leaves = PARAMS["num_leaves"]
+    ops = parse_hlo_phases(text)["ops"]
+    comps = _by_computation(text)
+    launched = [i for body in comps.values() for i in body if i.name in ops]
+    mine = [i for i in launched if ops[i.name] == "leaf_delta"]
+    fusions = [i for i in mine if i.opcode == "fusion"]
+    assert fusions
+    inside = mine + [i for f in fusions for i in comps[f.calls]]
+    assert [i[:3] for i in inside
+            if i.opcode in ("gather", "scatter", "sort", "reduce-window")
+            and max(map(np.prod, _dims(i.shape))) >= rows] == []
+    lookups = [f for f in fusions
+               if any(i.opcode == "reduce" for i in comps[f.calls])
+               and any(sorted(d) == [leaves, rows] for i in comps[f.calls] for d in _dims(i.shape))]
+    assert len(lookups) == 1 and _dims(lookups[0].shape) == [[rows]]
+    assert [i[:3] for i in launched
+            if any({leaves, rows} <= set(d) for d in _dims(i.shape))] == []
+    assert temps <= parent_temps
 
 
 @pytest.mark.parametrize("kernel,phase", [
@@ -279,17 +356,7 @@ def epsilon_compiled(one_chip):
     with _compiling_for_a_described_chip():
         pt = _small_trainer(rows=EPS_ROWS, cols=EPS_COLS, small_rows=4096)
         assert type(pt).__name__ == "PartitionedTrainer" and pt.p.shape[0] == 512
-        prog = pt._build_program(pt.CHUNK_ALLOC, False, 1, EPS_COLS)
-
-        def spec(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-        key = pt._base_key
-        compiled = prog.lower(
-            spec((512, EPS_ROWS + 1024), jnp.int32), spec((), jnp.float32),
-            spec(key.shape, key.dtype), spec((), jnp.int32), spec((), jnp.int32)).compile()
-        mem = compiled.memory_analysis()
-        return compiled.as_text(), mem.argument_size_in_bytes, mem.temp_size_in_bytes
+        return _compiled_chunk_program(pt, one_chip, EPS_ROWS, EPS_COLS)
 
 
 @pytest.mark.parametrize("kernel", ["update_and_root_hist", "level_stream", "split_stream",
