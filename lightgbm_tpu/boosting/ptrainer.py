@@ -64,8 +64,9 @@ elementwise objectives are permutation-invariant.  Ranking objectives
 (query-grouped) are not — they keep the mask-based grower (ops/grow.py).
 
 ``ShardedPartitionedTrainer`` runs the same fused loop per shard under
-``shard_map`` with per-split histogram psums — the data-parallel learner
-(data_parallel_tree_learner.cpp) on the fast kernels.
+``shard_map`` with histogram all-reduces (the root's, one a level, one a
+tail split) — the data-parallel learner (data_parallel_tree_learner.cpp)
+on the fast kernels.
 
 Deliberate parity divergences from the reference (documented):
 - bagging draws a per-row Bernoulli(bagging_fraction) mask with JAX
@@ -101,6 +102,7 @@ from ..ops.pgrow import (
     BundleMeta,
     PGrowParams,
     grow_tree_partitioned,
+    level_slots,
     levelgrow_env_params,
     segment_values,
 )
@@ -648,11 +650,15 @@ class PartitionedTrainer:
         ``hist_cells`` (lanes of one leaf's histogram row as the kernels
         issue it, padding included), ``channels`` (rows of the packed
         matrix) and ``col_groups`` (column groups a kernel walks a block
-        in: 1 up to 31 columns).  Called only when tracing is on."""
+        in: 1 up to 31 columns).  And what crossed chips: ``shards`` (the
+        mesh's size, 1 here), ``allreduce_calls`` and ``allreduce_bytes``
+        (0 here; ``ShardedPartitionedTrainer`` counts them).  Called only
+        when tracing is on."""
         cols = self.params.num_cols or self.params.num_features
         bins = self.params.num_bins_hist or self.params.num_bins
         out = {"hist_cells": hist_lanes(cols, bins), "channels": self.layout.C,
-               "col_groups": col_groups(cols, self.params.bits).count}
+               "col_groups": col_groups(cols, self.params.bits).count,
+               "shards": 1, "allreduce_calls": 0, "allreduce_bytes": 0}
         out.update(zip(("levels", "level_rows", "level_segments"),
                        recs_np["levels"][:n_done].sum(axis=(0, 1)).tolist()))
         return out
@@ -687,9 +693,20 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
     own packed matrix + BLK tail; child/root histograms are psum'd so
     every device takes the bit-identical split on its local segment.
     Grad/hess/scores stay device-resident across trees and chunks — no
-    per-tree host round-trips (the reference's per-iteration
-    ReduceScatter is the ONLY cross-device traffic, here one psum of the
-    (G, BH, 3) tensor per split)."""
+    per-tree host round-trips.  The histograms are the ONLY cross-device
+    traffic, at three sites: the root's ``(G, BH, 3)`` once a tree; in
+    the level phase ONE all-reduce a level of all ``level_slots`` slots'
+    kernel-layout rows, ``(SMAX, 16, hist_lanes)`` float32 with the
+    inactive slots zeroed (2.1 GB a level at 2,000 columns x 63 bins,
+    whatever the rows); and both children's g, h and count planes,
+    ``(6, hist_lanes)``, of each replayed split that takes the
+    ``split_stream`` tail (``pkernels.child_planes``).  Where the
+    reference's data-parallel learner reduce-SCATTERS so that each worker
+    searches a slice of the columns, every device here holds the reduced
+    histograms whole and repeats the whole split search.
+    ``stream_counts`` reports what one chip handed to those all-reduces
+    (``allreduce_calls``, ``allreduce_bytes``), from the device program's
+    own counts."""
 
     def __init__(self, train_set, config, objective, meta, hyper, mesh):
         import jax as _jax
@@ -746,33 +763,43 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         label = np.asarray(md.label, np.float32)
         weight = (np.asarray(md.weights, np.float32)
                   if self.has_weights else np.ones(n, np.float32))
-        shards = []
-        for k in range(d_local):
-            lo, hi = k * nl, min((k + 1) * nl, n)
-            nreal = max(0, hi - lo)
-            mb = np.zeros((nl, matrix.shape[1]), np.uint8)
-            lb = np.zeros((nl,), np.float32)
-            wb = np.zeros((nl,), np.float32)
-            if nreal:
-                mb[:nreal] = matrix[lo:hi]
-                lb[:nreal] = label[lo:hi]
-                wb[:nreal] = weight[lo:hi]
-            shards.append(np.asarray(
-                pack_matrix(mb, self.layout, label=lb, weight=wb, num_real=nreal)
-            ))
-        local = np.stack(shards)  # (d_local, C, nl + BLK)
-        sharding = NamedSharding(mesh, P("data"))
-        if nproc > 1:
-            gshape = (d, local.shape[1], local.shape[2])
-            # each per-device buffer keeps the leading shard axis: the
-            # (d, C, n) global array sharded on axis 0 has (1, C, n) shards
-            bufs = [
-                _jax.device_put(local[i][None], dev)
-                for i, dev in enumerate(mesh.local_devices)
-            ]
-            self.p = _jax.make_array_from_single_device_arrays(gshape, sharding, bufs)
-        else:
-            self.p = _jax.device_put(jnp.asarray(local), sharding)
+        # The shards are packed with numpy on the host, one after another,
+        # and then uploaded.  A shard's block is (C, nl + BLK) widened to
+        # whole 128-lane tiles: where C is a multiple of 128 and the width
+        # is not (512 channel rows x 101,024 at 2,000 columns and 100,000
+        # rows a shard), the TPU's default layout for the array puts the
+        # CHANNELS on the lanes, and the chunk program then transposes the
+        # whole shard on the way in and on the way out (compiled for the
+        # v5e, PR 33: two `copy s32[1,512,101024]`, +397 MB of temporaries).
+        # The kernels address rows by `num_rows`, never by the width.
+        width = -(-(nl + BLK) // 128) * 128
+        with tracer.span("shard_pack", rows=n, shards=d_local):
+            local = np.zeros((d_local, self.layout.C, width), np.int32)
+            for k in range(d_local):
+                lo, hi = k * nl, min((k + 1) * nl, n)
+                nreal = max(0, hi - lo)
+                mb = np.zeros((nl, matrix.shape[1]), np.uint8)
+                lb = np.zeros((nl,), np.float32)
+                wb = np.zeros((nl,), np.float32)
+                if nreal:
+                    mb[:nreal] = matrix[lo:hi]
+                    lb[:nreal] = label[lo:hi]
+                    wb[:nreal] = weight[lo:hi]
+                local[k, :, : nl + BLK] = np.asarray(
+                    pack_matrix(mb, self.layout, label=lb, weight=wb, num_real=nreal)
+                )
+            sharding = NamedSharding(mesh, P("data"))
+            if nproc > 1:
+                gshape = (d, local.shape[1], local.shape[2])
+                # each per-device buffer keeps the leading shard axis: the
+                # (d, C, n) global array sharded on axis 0 has (1, C, n) shards
+                bufs = [
+                    _jax.device_put(local[i][None], dev)
+                    for i, dev in enumerate(mesh.local_devices)
+                ]
+                self.p = _jax.make_array_from_single_device_arrays(gshape, sharding, bufs)
+            else:
+                self.p = _jax.device_put(jnp.asarray(local), sharding)
 
         self.meta = meta
         self.hyper = hyper
@@ -1011,7 +1038,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         bufs, devs = [], []
         for s in self._local_shards_sorted():
             g = s.index[0].start or 0
-            arr = np.array(s.data)  # (1, C, nl + BLK) host copy
+            arr = np.array(s.data)  # (1, C, width) host copy
             arr[0, :, :nl] = arr[0, :, :nl][:, rowid[g]]
             bufs.append(arr)
             devs.append(s.device)
@@ -1093,6 +1120,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                 ns_t = recs["num_splits"][t]
                 raw_t = recs["raw"][t]
                 lv_t = recs["levels"][t]
+                tp_t = recs["tail_psums"][t]
                 if K == 1:
                     if goss_on:
                         # settle pending delta + fresh gradients first
@@ -1167,6 +1195,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                     ns_t = ns_t.at[0].set(tree.num_splits)
                     raw_t = raw_t.at[0].set(tree.recs_raw)
                     lv_t = lv_t.at[0].set(tree.level_counts)
+                    tp_t = tp_t.at[0].set(tree.tail_psums)
                 else:
                     # K trees per iteration from one gradient pass; each
                     # class's delta lands on its score row immediately
@@ -1197,11 +1226,13 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                         ns_t = ns_t.at[k].set(tree.num_splits)
                         raw_t = raw_t.at[k].set(tree.recs_raw)
                         lv_t = lv_t.at[k].set(tree.level_counts)
+                        tp_t = tp_t.at[k].set(tree.tail_psums)
 
                 recs = {
                     "num_splits": recs["num_splits"].at[t].set(ns_t),
                     "raw": recs["raw"].at[t].set(raw_t),
                     "levels": recs["levels"].at[t].set(lv_t),
+                    "tail_psums": recs["tail_psums"].at[t].set(tp_t),
                 }
                 return (t + 1, ~any_split, p, recs, delta, last_kept)
 
@@ -1213,6 +1244,10 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                 # the segments they partitioned (one shard's rows, in the
                 # sharded program)
                 "levels": jnp.zeros((T, K, 3), jnp.int32),
+                # per tree: replayed splits whose children were all-reduced
+                # (the tail); with `levels` and the root's, every histogram
+                # all-reduce the program issued
+                "tail_psums": jnp.zeros((T, K), jnp.int32),
             }
             # (t, stopped, p, recs, pending delta, last kept delta)
             state0 = (jnp.int32(0), jnp.array(False), p, recs0,
@@ -1241,8 +1276,8 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         mapped = self._shard_map(
             shard_body,
             (P("data"), P("data"), P(), P(), P(), P()),
-            (P("data"), {"num_splits": P(), "raw": P(), "levels": P()}, P(None, "data"),
-             P("data")),
+            (P("data"), {"num_splits": P(), "raw": P(), "levels": P(), "tail_psums": P()},
+             P(None, "data"), P("data")),
         )
         return jax.jit(mapped, donate_argnums=(0,))
 
@@ -1322,6 +1357,31 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         got = jnp.asarray(self._gather_rows(scores))
         scores_orig = got[0] if self.K == 1 else got
         return recs_np, scores_orig, n_done
+
+    def stream_counts(self, recs_np, n_done: int) -> dict:
+        """The serial trainer's counts (one shard's rows) and what ONE chip
+        handed to the histogram all-reduces over those iterations:
+        ``allreduce_calls`` and ``allreduce_bytes`` (payload: the operand's
+        float32 bytes, not what an algorithm then moves through the links).
+        Three sites (ops/pgrow.py ``PGrowParams.axis_name``): the root's
+        ``(G, BH, 3)`` (one call a tree; K > 1 reduces its K roots in one
+        call an iteration), a level's ``(level_slots, 16, hist_lanes)`` and
+        a tail split's ``(6, hist_lanes)``.  How many levels and tail splits
+        a tree took only the device program knows: ``recs["levels"]`` and
+        ``recs["tail_psums"]``."""
+        out = super().stream_counts(recs_np, n_done)
+        cols = self.params.num_cols or self.params.num_features
+        bins = self.params.num_bins_hist or self.params.num_bins
+        root = 4 * cols * bins * 3
+        level = 4 * level_slots(self.params.num_leaves) * 16 * out["hist_cells"]
+        tail = 4 * 6 * out["hist_cells"]
+        tails = int(recs_np["tail_psums"][:n_done].sum())
+        out.update(
+            shards=self.d,
+            allreduce_calls=n_done + out["levels"] + tails,
+            allreduce_bytes=n_done * self.K * root + out["levels"] * level + tails * tail,
+        )
+        return out
 
 
 def eligible(config, train_set, objective, num_tree_per_iteration: int) -> bool:

@@ -76,6 +76,13 @@ def _elements(shape: str) -> int:
     return n
 
 
+def _unsharded(shape: str) -> str:
+    """``s32[1,512,101024]`` -> ``s32[512,101024]``: under ``shard_map`` a
+    device's block of the ``(shards, C, N)`` matrix keeps the sharded axis
+    as a leading 1, which the program drops (``pg[0]``) before its loops."""
+    return re.sub(r"\[(?:1,)+(?=\d)", "[", shape)
+
+
 def parse_hlo_phases(text: str) -> Dict[str, object]:
     """``{"module", "matrix", "ops", "matrix_copies"}`` of one compiled
     module's text (``compiled.as_text()``).
@@ -99,10 +106,11 @@ def parse_hlo_phases(text: str) -> Dict[str, object]:
       really left outside every scope stays ``None``.
 
     ``matrix`` is the shape of the entry computation's largest parameter,
-    layout dropped (the packed matrix, ``s32[16,21001024]``), and
+    layout dropped (the packed matrix, ``s32[16,21001024]``; one device's
+    block of it in the data-parallel program, ``s32[1,16,5251024]``), and
     ``matrix_copies`` names the ``copy`` instructions among ``ops`` whose
-    result has that shape: the static sites at which the program copies
-    the whole matrix."""
+    result has that shape, with or without the leading 1 of a shard's
+    block: the static sites at which the program copies the whole matrix."""
     module = None
     comps = {}    # computation -> [(instruction, shape if a copy, phase or None, has a path)]
     callers = {}  # computation -> (calling computation, calling instruction)
@@ -147,6 +155,7 @@ def parse_hlo_phases(text: str) -> Dict[str, object]:
                 callers[c] = (cur, name)
 
     ops, copies = {}, []
+    matrix_2d = _unsharded(matrix) if matrix else None
     reach = [entry] if entry else []
     while reach:  # callers before the computations they call
         comp = reach.pop(0)
@@ -160,7 +169,7 @@ def parse_hlo_phases(text: str) -> Dict[str, object]:
         for (name, copied, phase, has_path), after in zip(rows, fill):
             prev = phase or prev
             ops[name] = phase or (inherited if has_path else (after or prev))
-            if copied is not None and copied == matrix:
+            if copied is not None and _unsharded(copied) == matrix_2d:
                 copies.append(name)
         reach += [c for c, (caller, _) in callers.items() if caller == comp and c in comps]
     return {"module": module, "matrix": matrix, "ops": ops, "matrix_copies": copies}

@@ -63,6 +63,7 @@ from .pkernels import (
     PLayout,
     _hist_from_rows,
     hist_dyn,
+    hists_from_planes,
     level_stream,
     split_stream,
 )
@@ -94,10 +95,15 @@ class PGrowParams(NamedTuple):
     # column fits 16 bins, else 8
     bits: int = 8
     # data-parallel mode: shard_map mesh axis to psum histograms over
-    # (DataParallelTreeLearner, data_parallel_tree_learner.cpp:148-161 —
-    # the ReduceScatter of local histograms becomes one psum; every
-    # device then takes the identical best split on its local segment).
-    # None/"" = serial.
+    # (DataParallelTreeLearner, data_parallel_tree_learner.cpp:148-161).
+    # Where the reference reduce-SCATTERS local histograms so that each
+    # worker searches a slice of the columns, this grower ALL-reduces
+    # them and every device searches every column, then takes the
+    # identical best split on its local segment.  Three sites: the root
+    # histogram (psum'd by the chunk program), one all-reduce a LEVEL of
+    # all ``level_slots`` slots' kernel-layout rows, (slots, 16, lanes)
+    # with inactive slots zeroed and not left out, and both children's
+    # g, h and count planes, (6, lanes), of a tail split.  None/"" = serial.
     axis_name: str = None
     # level-batched expansion (phase 1) toggle.  It used to be an env
     # read (LIGHTGBM_TPU_LEVELGROW) at trace time inside the jitted
@@ -106,6 +112,14 @@ class PGrowParams(NamedTuple):
     # construction (boosting/ptrainer.py) and threaded here, where the
     # static params tuple IS the cache key.
     levelwise: bool = True
+
+
+def level_slots(num_leaves: int) -> int:
+    """Slots of the level phase's candidate frontier (``SMAX``): what one
+    ``level_stream`` launch can partition, the leading dimension of the
+    histograms it returns and, under ``axis_name``, of what a level
+    all-reduces."""
+    return min(-(-(num_leaves + 1) // 8) * 8, 512)
 
 
 def levelgrow_env_params() -> dict:
@@ -169,6 +183,9 @@ class PTreeResult(NamedTuple):
     # (3,) int32: level_stream launches, the rows they streamed and the
     # segments they partitioned, each summed over the levels
     level_counts: jnp.ndarray = None
+    # data-parallel only (None in a serial program): replayed splits whose
+    # children's histograms were all-reduced (the ``split_stream`` tail)
+    tail_psums: jnp.ndarray = None
 
 
 class _PState(NamedTuple):
@@ -181,6 +198,9 @@ class _PState(NamedTuple):
     #                                   rval, lcnt, rcnt, ival, 0, 0]
     pslot: jnp.ndarray  # (L,) i32 candidate-table slot of each pool leaf
     #   (>= 0: node came from the level-batched expansion; -1: classic)
+    tail_psums: jnp.ndarray = None  # i32 splits that took the all-reduced
+    #   tail, counted under ``axis_name`` alone (a serial program carries
+    #   no such counter)
 
 
 def _meta_table(meta: FeatureMeta, bmeta, f: int, bits: int) -> jnp.ndarray:
@@ -308,7 +328,7 @@ def grow_tree_partitioned(
     # ---- phase 1: level-batched expansion into candidate tables ------
     with jax.named_scope(LEVEL_PHASE):
         if levelwise:
-            SMAX = min(-(-(L + 1) // 8) * 8, 512)
+            SMAX = level_slots(L)
             CANDMAX = 2 * SMAX
             # A table of 2 * SMAX candidates holds a complete tree of
             # log2(SMAX) levels; one level more takes the slots that nodes
@@ -452,6 +472,7 @@ def grow_tree_partitioned(
         leaf=leaf0,
         recs=jnp.zeros((L - 1, 12), jnp.float32),
         pslot=pslot0,
+        tail_psums=jnp.int32(0) if params.axis_name else None,
     )
 
     # "no leaf left with a positive gain" is part of the predicate, not a
@@ -495,15 +516,17 @@ def grow_tree_partitioned(
         off_hi = mrow[4].astype(jnp.int32)
         bias = mrow[5].astype(jnp.int32)
         with jax.named_scope(REPLAY_TAIL):
-            p, nl, lhist, rhist = split_stream(
+            # hists: (left, right), each (G, BH, 3); under ``axis_name`` the
+            # six ``child_planes`` in one array, for the all-reduce below
+            p, nl, *hists = split_stream(
                 st.p, jnp.where(has_pre, 0, start), jnp.where(has_pre, 0, cnt),
                 colidx // per, (colidx % per) * params.bits, zb, dbz, thr, cat,
                 off_lo=off_lo, off_hi=off_hi, bias=bias,
                 num_features=G, num_bins=BH, bits=params.bits, rows=rows,
-                interpret=interpret,
+                interpret=interpret, planes=bool(params.axis_name),
             )
 
-        def take_pre(nl, lhist, rhist):
+        def take_pre(nl, *hists):
             clo = jnp.clip(childlo, 0, CANDMAX - 1)
             chi = jnp.clip(childlo + 1, 0, CANDMAX - 1)
             seg2 = jnp.stack([c_seg[clo], c_seg[chi]])
@@ -512,16 +535,25 @@ def grow_tree_partitioned(
             ps2 = jnp.stack([clo, chi])
             return seg2, bs2, leaf2, ps2
 
-        def take_classic(nl, lhist, rhist):
+        def take_classic(nl, *hists):
             with jax.named_scope(REPLAY_TAIL):
-                hist2 = jnp.stack([lhist, rhist])
                 if params.axis_name:
                     # global children histograms; the split decision below is
                     # then bit-identical on every device (local segments
                     # diverge, the tree does not).  Inside the branch: a
                     # precomputed split needs no collective, and has_pre
-                    # is replicated
-                    hist2 = jax.lax.psum(hist2, params.axis_name)
+                    # is replicated.  Reduced as PLANES, one lane a cell,
+                    # and laid out as (2, G, BH, 3) after: that array has
+                    # its 3 on the lanes (`{3,0,2,1:T(2,128)}`), 129 MB for
+                    # 3 MB at 2,000 columns, and an all-reduce of it takes
+                    # 2.65 ms where this one takes 0.12.  The seed moves
+                    # how many tail splits a tree takes, so what one costs
+                    # is what an iteration differs by from seed to seed (my
+                    # chip runs, PR 33).  The same 2 x G x BH x 3 sums.
+                    hist2 = hists_from_planes(
+                        jax.lax.psum(hists[0], params.axis_name), G, BH)
+                else:
+                    hist2 = jnp.stack(hists)
 
             right = totals - left
             sums2 = jnp.stack([left, right])  # (2, 3)
@@ -553,7 +585,7 @@ def grow_tree_partitioned(
             return seg2, bs2, leaf2, ps2
 
         seg2, bs2, leaf2, ps2 = jax.lax.cond(
-            has_pre, take_pre, take_classic, nl, lhist, rhist
+            has_pre, take_pre, take_classic, nl, *hists
         )
         # child outputs are recomputed HERE, at one shared (2,)-shaped
         # site outside the cond, from the children's g/h sums.  The
@@ -588,6 +620,8 @@ def grow_tree_partitioned(
             leaf=st.leaf.at[idx2].set(leaf2),
             recs=st.recs.at[s].set(rec),
             pslot=st.pslot.at[idx2].set(ps2),
+            tail_psums=(st.tail_psums + jnp.where(has_pre, 0, 1)
+                        if params.axis_name else None),
         )
 
     with jax.named_scope(REPLAY):
@@ -611,6 +645,7 @@ def grow_tree_partitioned(
             rec_rcnt=recs[:, 8],
             rec_internal_value=recs[:, 9],
             level_counts=level_counts,
+            tail_psums=st.tail_psums,
         )
     return res, st.p
 

@@ -433,15 +433,19 @@ def _tile_bytes(c: int, n: int = 1) -> int:
     return n * c * BLK * 4
 
 
-def _hist_from_rows(out, num_features, num_bins, row0=0):
+def _planes_from_rows(out, row0=0):
     """(Σ 3-term g, Σ 3-term h, cnt) kernel rows of ``hist_lanes`` lanes
-    -> (F, B, 3) histogram."""
-    return _hist_cells(
+    -> the g, h and count planes, one lane a cell."""
+    return (
         out[row0 + 0] + (out[row0 + 1] + out[row0 + 2]),
         out[row0 + 3] + (out[row0 + 4] + out[row0 + 5]),
         out[row0 + 6],
-        num_features, num_bins,
     )
+
+
+def _hist_from_rows(out, num_features, num_bins, row0=0):
+    """Those kernel rows -> (F, B, 3) histogram."""
+    return _hist_cells(*_planes_from_rows(out, row0), num_features, num_bins)
 
 
 def _hist_cells(g, h, cnt, num_features, num_bins):
@@ -1549,18 +1553,18 @@ def level_stream(p, seg_tab, n_active, *, num_features, num_bins, bits=8,
     return p, nl, hist
 
 
-@functools.partial(jax.jit, static_argnames=("num_features", "num_bins", "bits", "rows", "interpret"),
+@functools.partial(jax.jit, static_argnames=("num_features", "num_bins", "bits", "rows", "interpret", "planes"),
                    donate_argnums=(0,))
 def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
                  off_lo=0, off_hi=256, bias=0, *, num_features, num_bins,
-                 bits=8, rows=None, interpret=False):
+                 bits=8, rows=None, interpret=False, planes=False):
     """Partition the leaf segment [start, start+cnt) of ``p`` in place by
     the split predicate AND return both children's histograms from the
     same pass.
 
     Lefts land at [start, start+nl), rights at [start+nl, start+cnt)
-    (order within each child unspecified).  Returns
-    (p', nl, left_hist (F, B, 3), right_hist)."""
+    (order within each child unspecified).  Returns (p', nl, left_hist
+    (F, B, 3), right_hist), or (p', nl, ``child_planes``) under ``planes``."""
     if rows is None:
         wpad = -(-num_words(num_features, bits) // 8) * 8
         rows = (wpad, wpad + 1, wpad + 2)
@@ -1604,9 +1608,26 @@ def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
         interpret=interpret,
         name="split_stream",
     )(sv, p)
+    if planes:
+        return p, nl[0], child_planes(hist)
     left = _hist_from_rows(hist, num_features, num_bins, row0=0)
     right = _hist_from_rows(hist, num_features, num_bins, row0=7)
     return p, nl[0], left, right
+
+
+def child_planes(out):
+    """``split_stream``'s kernel rows -> (6, hist_lanes): the left child's
+    g, h and count planes, then the right's.  What the data-parallel tail
+    all-reduces: the planes are dense, where a ``(F, B, 3)`` histogram has
+    its 3 on the lanes and 125 of every 128 lanes padding."""
+    return jnp.stack(_planes_from_rows(out, 0) + _planes_from_rows(out, 7))
+
+
+def hists_from_planes(planes, num_features, num_bins):
+    """``child_planes`` (summed over the shards) -> (2, F, B, 3): what
+    ``split_stream`` returns without ``planes``, left child then right."""
+    return jnp.stack([_hist_cells(*planes[0:3], num_features, num_bins),
+                      _hist_cells(*planes[3:6], num_features, num_bins)])
 
 
 # ======================================================================

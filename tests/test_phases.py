@@ -248,6 +248,27 @@ def test_sharded_chunk_program_maps_too(monkeypatch):
     assert {"update_root_hist", "level_phase", "replay", "leaf_delta",
             "chunk_epilogue"} <= set(m["ops"].values())
     assert "canon_reorder" not in m["ops"].values()  # the sharded program has none
+    # the matrix is one device's block of the (shards, C, N) array, which the
+    # program squeezes before its loops: a copy of either form is a copy of
+    # the matrix (ROADMAP S9; until PR 33 `matrix_copy_ms_per_iter` could
+    # only read 0.0 in a data-parallel cell)
+    c, n = pt.p.shape[1:]
+    # (on the CPU the interpreted kernels alias nothing, so this program has
+    # copy sites of its own; the v5e compile test holds the chip's to none)
+    assert m["matrix"] == f"s32[1,{c},{n}]"
+    args, kwargs = watch._compiled_spec
+    lines = watch._fn.lower(*args, **kwargs).compile().as_text().splitlines()
+    # beside an instruction of the replay loop's body, and at the entry's end
+    inside = next(k for k, v in m["ops"].items() if v == "replay_tail")
+    body = next(i for i, ln in enumerate(lines) if re.match(rf"\s+(ROOT )?%{re.escape(inside)} = ", ln))
+    root = max(i for i, ln in enumerate(lines) if ln.lstrip().startswith("ROOT "))
+    planted = list(lines)
+    planted.insert(root, f"  %copy.9001 = s32[1,{c},{n}]{{2,1,0}} copy(%p)")
+    planted.insert(body, f"  %copy.9002 = s32[{c},{n}]{{1,0}} copy(%p)")
+    planted.insert(body, f"  %copy.9003 = s32[{c},{n - 1}]{{1,0}} copy(%p)")  # not the matrix
+    got = parse_hlo_phases("\n".join(planted))
+    assert set(got["matrix_copies"]) - set(m["matrix_copies"]) == {"copy.9001", "copy.9002"}
+    assert got["ops"]["copy.9002"] == "replay_tail" and "copy.9003" in got["ops"]
 
 
 # -- the host spans -----------------------------------------------------------
@@ -289,19 +310,26 @@ def counted(monkeypatch):
     return calls
 
 
-def _booster(X, y):
-    params = {"objective": "binary", "num_leaves": 7, "verbose": -1}
+def _booster(X, y, learner="serial"):
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1, "tree_learner": learner}
     bst = lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y, params=params))
     assert bst.boosting.ptrainer is not None
     return bst
 
 
-def test_tracing_off_costs_nothing(tracing_off, counted):
+@pytest.mark.parametrize("learner", ["serial", "data"])
+def test_tracing_off_costs_nothing(tracing_off, counted, learner):
     """No record, no TraceAnnotation, no block_until_ready from the new spans,
-    no re-lowering: the untraced path issues the calls it issued before."""
+    no re-lowering: the untraced path issues the calls it issued before.  The
+    data-parallel trainer's `shard_pack` span and all-reduce counters too:
+    construction is inside what is counted."""
+    if learner == "data" and len(jax.devices()) < 4:
+        pytest.skip("needs a multi-device mesh")
     X, y, _ = _toy()
-    bst = _booster(X, y)
     work = tracer.work_ops
+    bst = _booster(X, y, learner)
+    assert type(bst.boosting.ptrainer).__name__ == (
+        "ShardedPartitionedTrainer" if learner == "data" else "PartitionedTrainer")
     for _ in range(2):
         bst.boosting.train_iters_partitioned(2, is_eval=False)
     assert tracer.work_ops == work
@@ -350,6 +378,7 @@ def test_nested_spans_are_each_right_alone(traced_chunks):
 def test_trees_from_records_carries_the_stream_counts(traced_chunks):
     """Beside `trees` and `splits`: what the operation and byte models of the
     streaming kernels are made of (benchmarks/harness/hist_ops.py)."""
+    from lightgbm_tpu.ops.pgrow import level_slots
     from lightgbm_tpu.ops.pkernels import hist_lanes
 
     bst, recs, _, _ = traced_chunks
@@ -362,10 +391,63 @@ def test_trees_from_records_carries_the_stream_counts(traced_chunks):
         # two trees a chunk: every tree's first level streams every row once,
         # no level streams a row twice, and a tree's level phase is bounded by
         # its candidate table (log2(SMAX) + 1 levels, ops/pgrow.py)
-        smax = min(-(-(pt.params.num_leaves + 1) // 8) * 8, 512)
+        smax = level_slots(pt.params.num_leaves)
         assert 2 <= t["levels"] <= 2 * ((smax - 1).bit_length() + 1)
         assert 2 * n <= t["level_rows"] <= t["levels"] * n
         assert t["levels"] <= t["level_segments"] <= 2 * (pt.params.num_leaves - 1) * t["levels"]
+
+
+def test_serial_trees_from_records_reduce_nothing(traced_chunks):
+    _, recs, _, _ = traced_chunks
+    trees = [r for r in recs if r["ev"] == "span" and r["name"] == "trees_from_records"]
+    assert [(t["shards"], t["allreduce_calls"], t["allreduce_bytes"]) for t in trees] == [(1, 0, 0)] * 2
+    assert not [r for r in recs if r["ev"] == "span" and r["name"] == "shard_pack"]
+
+
+@pytest.mark.parametrize("levelgrow", ["1", "0"])
+def test_sharded_trees_from_records_count_the_allreduces(tmp_path, monkeypatch, levelgrow):
+    """`allreduce_calls` and `allreduce_bytes`: what ONE chip handed to the
+    histogram `psum`s over the span's trees (the root's, one a level, two
+    histograms a tail split as six planes of `hist_lanes` lanes), out of the
+    device program's own counts; `shards`;
+    and the host span around the numpy packing.  Under LEVELGROW=0 no level is
+    reduced and every split takes the tail, so the count is known from the
+    trees alone."""
+    from lightgbm_tpu.ops.pgrow import level_slots
+    from lightgbm_tpu.ops.pkernels import hist_lanes
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs a multi-device mesh")
+    monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+    monkeypatch.setenv("LIGHTGBM_TPU_LEVELGROW", levelgrow)
+    X, y, _ = _toy(1203, 6)
+    path = str(tmp_path / "trace.jsonl")
+    tracer.configure(path)
+    try:
+        bst = _booster(X, y, "data")
+        bst.boosting.train_iters_partitioned(3, is_eval=False)
+    finally:
+        tracer.close()
+        tracer.path = None
+    with open(path) as f:
+        spans = [r for r in map(json.loads, f) if r["ev"] == "span"]
+    pt = bst.boosting.ptrainer
+    (pack,) = [s for s in spans if s["name"] == "shard_pack"]
+    assert (pack["rows"], pack["shards"]) == (1203, pt.d) and pack["dur_s"] > 0
+    (t,) = [s for s in spans if s["name"] == "trees_from_records"]
+    f_, b = pt.params.num_features, pt.params.num_bins
+    root = 4 * f_ * b * 3
+    level = 4 * level_slots(pt.params.num_leaves) * 16 * hist_lanes(f_, b)
+    assert t["shards"] == pt.d == len(jax.devices()) and t["trees"] == 3
+    tails = t["allreduce_calls"] - t["trees"] - t["levels"]
+    tail = 4 * 6 * hist_lanes(f_, b)
+    assert t["allreduce_bytes"] == t["trees"] * root + t["levels"] * level + tails * tail
+    if levelgrow == "0":
+        assert t["levels"] == 0 and tails == t["splits"] == 3 * 6
+    else:
+        # 7 leaves: the level phase holds whole trees of 3 levels, so a tree
+        # takes its 6 splits from the candidate tables unless it grew deeper
+        assert 3 <= t["levels"] <= 3 * 4 and 0 <= tails < t["splits"]
 
 
 @pytest.mark.parametrize("name", ["chunk_program", "records_fetch"])

@@ -1,4 +1,5 @@
-"""The 21M-row chunk program of the benchmark's configuration, compiled for a
+"""The chunk programs of the benchmark's configurations (21M x 28 and 400,000 x
+2,000 on one chip; 400,000 x 2,000 split over four), compiled for a
 described (not attached) TPU v5e: what the chip's own compiler leaves of the
 names the program gives itself, and where it copies the whole packed matrix.
 Counts from a compile, never speeds; nothing runs.
@@ -22,6 +23,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from lightgbm_tpu.obs.phases import PHASES, parse_hlo_phases  # noqa: E402
+from lightgbm_tpu.ops.pkernels import hist_lanes  # noqa: E402
 
 ROWS = 21_000_000
 # the cells' configuration (benchmarks/configs/higgs.json)
@@ -116,11 +118,20 @@ def compiled_text(higgs_compiled):
     return higgs_compiled[0]
 
 
-@pytest.fixture(scope="module")
-def sharded_text(topo):
-    """The data-parallel chunk program (``tree_learner=data``, which no cell of
-    the benchmark runs) at 21M rows a chip, compiled for the four described
-    chips: the trainer is built on four CPU devices and then handed their mesh."""
+# rows a chip, columns, rows of the small table the trainer is built on
+SHARDED_SHAPES = {"higgs_21m_x_28_a_chip": (ROWS, 28, 20_000),
+                  "epsilon_100k_x_2000_a_chip": (100_000, 2_000, 4096)}
+
+
+@pytest.fixture(scope="module", params=sorted(SHARDED_SHAPES))
+def sharded_compiled(request, topo):
+    """The data-parallel chunk program (``tree_learner=data``) compiled for the
+    four described chips, at 21M rows a chip (which no cell of the benchmark
+    runs) and at the benchmark's ``epsilon-dp4`` shape, 400,000 x 2,000 split
+    100,000 rows a chip: the trainer is built on four CPU devices and then
+    handed the chips' mesh.  (text, argument bytes and temporary bytes of ONE
+    chip, rows a chip, channel rows, the width of a shard's block, the shape's
+    name in SHARDED_SHAPES)."""
     from unittest import mock
 
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -129,23 +140,33 @@ def sharded_text(topo):
 
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 devices to build the sharded trainer on")
+    rows, cols, small_rows = SHARDED_SHAPES[request.param]
     cpu_mesh = par.make_mesh(4)
     with _compiling_for_a_described_chip():
         with mock.patch.object(par, "make_mesh", lambda n_devices=None: cpu_mesh):
-            pt = _small_trainer(tree_learner="data")
+            pt = _small_trainer(rows=rows, cols=cols, small_rows=small_rows, tree_learner="data")
         assert type(pt).__name__ == "ShardedPartitionedTrainer" and pt.d == 4
         pt.mesh = mesh = Mesh(np.array(topo.devices[:4]), ("data",))
-        prog = pt._build_program(pt.CHUNK_ALLOC, False, 1, 28)
+        prog = pt._build_program(pt.CHUNK_ALLOC, False, 1, cols)
 
         def spec(shape, dtype, *axes):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
 
         key = pt._base_key
-        lowered = prog.lower(
-            spec((4, pt.p.shape[1], ROWS + 1024), jnp.int32, "data"), spec((4,), jnp.int32, "data"),
+        channels = pt.p.shape[1]
+        width = -(-(rows + 1024) // 128) * 128  # as ShardedPartitionedTrainer.__init__ packs it
+        compiled = prog.lower(
+            spec((4, channels, width), jnp.int32, "data"), spec((4,), jnp.int32, "data"),
             spec((), jnp.float32), spec(key.shape, key.dtype), spec((), jnp.int32),
-            spec((), jnp.int32))
-        return lowered.compile().as_text()
+            spec((), jnp.int32)).compile()
+        mem = compiled.memory_analysis()
+        return (compiled.as_text(), mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+                rows, channels, width, request.param)
+
+
+@pytest.fixture(scope="module")
+def sharded_text(sharded_compiled):
+    return sharded_compiled[0]
 
 
 @pytest.fixture(scope="module")
@@ -168,6 +189,7 @@ def test_the_compiler_keeps_the_phase(phase_map, phase):
 
 
 _RESULT = re.compile(r"^\s+(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(", re.M)
+_LAYOUT = re.compile(r"\{[^{}]*\}")
 _ARRAY = re.compile(r"s32\[([\d,]*)\]")  # the packed matrix is int32 words
 
 
@@ -329,17 +351,65 @@ def test_what_no_phase_claims_is_bookkeeping(compiled_text, phase_map):
     assert len(unclaimed) < 0.1 * len(phase_map["ops"])
 
 
-def test_sharded_program_copies_no_shard(sharded_text):
-    """The same contract in the data-parallel program (the parent compiled to
-    six ``copy s32[16,...]`` sites and three conditionals here too, and its one
-    trace on record had the copies at 66% of the time): no copy of a shard of
-    the matrix, one conditional, and no more collectives than the parent's
-    three ``all-reduce`` sites (root, level, replay tail): the tail's stays
-    inside the ``has_pre`` conditional's classic branch."""
-    assert "tpu_custom_call" in sharded_text
-    assert re.findall(r" = s32\[(?:1,)?16,21001024\]\S* copy\(", sharded_text) == []
-    assert len(re.findall(r" conditional\(", sharded_text)) == 1
-    assert len(re.findall(r" all-reduce(?:-start)?\(", sharded_text)) == 3
+_ALL_REDUCE = re.compile(r"^\s+(?:ROOT )?%\S+ = (\S+) all-reduce(?:-start)?\(", re.M)
+
+
+def test_sharded_program_copies_no_shard(sharded_compiled):
+    """The same contract in the data-parallel program, at both shapes (the
+    parent of PR 27 compiled to six ``copy s32[16,...]`` sites and three
+    conditionals here too, and its one trace on record had the copies at 66%
+    of the time): no copy of a shard of the matrix, with or without the
+    leading 1 of a device's block, and nothing that gathers, scatters, sorts or
+    transposes an int32 array of its size; one conditional.  At 512 channel
+    rows this also holds the width of a shard's block to whole 128-lane tiles:
+    at 101,024 the TPU's default layout for the block put the channels on the
+    lanes and the program transposed the shard in and out of every chunk (two
+    ``copy s32[1,512,101024]``, 397 MB more temporaries; PR 33)."""
+    text, _, _, rows, channels, width, _ = sharded_compiled
+    assert "tpu_custom_call" in text
+    pm = parse_hlo_phases(text)
+    assert pm["module"] == "jit_shard_body"
+    assert pm["matrix"] == f"s32[1,{channels},{width}]" and width % 128 == 0
+    assert pm["matrix_copies"] == []
+    assert re.findall(rf" = s32\[(?:1,)?{channels},{width}\]\S* copy\(", text) == []
+    assert _moves_of_matrix_size(text, channels * rows) == []
+    assert len(re.findall(r" conditional\(", text)) == 1
+
+
+def test_what_the_sharded_program_all_reduces(sharded_compiled):
+    """WHAT is reduced and WHO searches, pinned: three ``all-reduce`` sites and
+    no other collective (the root histogram, a level's, a tail split's two
+    children as six planes of ``hist_lanes`` lanes, so that no lane is padding:
+    the tail's stays inside the ``has_pre`` conditional's classic branch), and
+    the largest operand is a level's histograms of ALL 256 slots
+    in the kernel's layout, 16 rows of ``hist_lanes`` lanes a slot: 2.097 GB at
+    2,000 columns x 63 bins.  A change to reduce-scatter by column group, to
+    the active slots alone or to re-summed rows shows here, and is the
+    ``perf_opt`` that has this program for its parent (ISSUE 33)."""
+    text, name = sharded_compiled[0], sharded_compiled[-1]
+    cols = SHARDED_SHAPES[name][1]
+    lanes = hist_lanes(cols, PARAMS["max_bin"])  # 1,792 and 128,000
+    shapes = [_LAYOUT.sub("", sh) for sh in _ALL_REDUCE.findall(text)]
+    assert sorted(shapes) == sorted([f"f32[256,16,{lanes}]", f"f32[{cols},63,3]", f"f32[6,{lanes}]"])
+    assert max(shapes, key=lambda sh: max(map(np.prod, _dims(sh)))) == f"f32[256,16,{lanes}]"
+    for other in ("all-gather", "reduce-scatter", "all-to-all", "collective-permute"):
+        assert f" {other}(" not in text and f" {other}-start(" not in text
+
+
+def test_what_one_chip_of_the_sharded_program_holds(sharded_compiled):
+    """Arguments + temporaries of ONE chip.  At the ``epsilon-dp4`` shape: its
+    207 MB quarter of the matrix but the WHOLE level histogram, its all-reduced
+    copy and the split search's temporaries over them, 7.18 GB: over an eighth
+    of the chip (the contract's floor for a cell whose device is busy) and
+    inside it.  The number is in benchmarks/configs/epsilon-dp4.json
+    (``per_chip_bytes``) and PERF.md; a change that moves it says so there."""
+    _, args, temps, _, channels, width, name = sharded_compiled
+    assert 4 * channels * width <= args < 4 * channels * width + 4096
+    if name == "epsilon_100k_x_2000_a_chip":
+        assert abs(args + temps - 7_183_929_344) < 2 ** 20  # the configuration file quotes it
+        assert 2.15e9 < args + temps < 14e9
+    else:  # 104 bytes a row, as the serial program at 21M rows
+        assert 2.15e9 < args + temps < 2.3e9
 
 
 # -- the wide configuration (benchmarks/configs/epsilon.json) ----------------

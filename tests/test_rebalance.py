@@ -382,11 +382,16 @@ def test_lambdarank_two_rank_parity(tmp_path):
 def test_lambdarank_rebalance_moves_whole_groups(tmp_path):
     """Rebalance leg: rank 0 is an injected straggler; the controller
     must move load at QUERY-GROUP granularity — every shard edge of the
-    final plan is a group boundary and no group spans ranks."""
+    final plan is a group boundary and no group spans ranks.
+
+    The delay is 120 ms: at 40, a four-device benchmark rehearsal that
+    tier-1 ran beside this test starved rank 1 more than the injected
+    straggle slowed rank 0, and the one group that moved went the wrong
+    way (263 / 249) in every whole run of PR 33's tree."""
     res, models = _lambdarank_fleet(
         tmp_path, "rb", 2,
         {"ELASTIC_REBALANCE": "1", "ELASTIC_TREES": "12",
-         "LIGHTGBM_TPU_FAULT": "delay:40:after:5",
+         "LIGHTGBM_TPU_FAULT": "delay:120:after:5",
          "LIGHTGBM_TPU_FAULT_RANK": "0"})
     counts = res[0]["final_counts"]
     assert counts == res[1]["final_counts"], res
