@@ -528,10 +528,10 @@ class PartitionedTrainer:
             recs0 = {
                 "num_splits": jnp.zeros((T, K), jnp.int32),
                 "raw": jnp.zeros((T, K, m, 12)),
-                # per tree: level_stream launches, the rows they streamed and
-                # the segments they partitioned (one shard's rows, in the
-                # sharded program)
-                "levels": jnp.zeros((T, K, 3), jnp.int32),
+                # per tree (PTreeResult.level_counts): level_stream launches,
+                # the rows they streamed (one shard's, in the sharded program),
+                # the segments they partitioned, the slots the search visited
+                "levels": jnp.zeros((T, K, 4), jnp.int32),
             }
             # (t, stopped, p, recs, pending delta, last kept delta)
             state0 = (jnp.int32(0), jnp.array(False), p, recs0,
@@ -646,7 +646,9 @@ class PartitionedTrainer:
         span: ``levels`` (level_stream launches), ``level_rows`` and
         ``level_segments`` (the rows they streamed and the segments they
         partitioned, summed over levels; one shard's rows under
-        ``tree_learner=data``), and the shapes a launch works on:
+        ``tree_learner=data``), ``scan_slots`` (the slots the levels' split
+        search visited: ``level_segments`` over it is the share that held
+        a segment), and the shapes a launch works on:
         ``hist_cells`` (lanes of one leaf's histogram row as the kernels
         issue it, padding included), ``channels`` (rows of the packed
         matrix) and ``col_groups`` (column groups a kernel walks a block
@@ -659,7 +661,7 @@ class PartitionedTrainer:
         out = {"hist_cells": hist_lanes(cols, bins), "channels": self.layout.C,
                "col_groups": col_groups(cols, self.params.bits).count,
                "shards": 1, "allreduce_calls": 0, "allreduce_bytes": 0}
-        out.update(zip(("levels", "level_rows", "level_segments"),
+        out.update(zip(("levels", "level_rows", "level_segments", "scan_slots"),
                        recs_np["levels"][:n_done].sum(axis=(0, 1)).tolist()))
         return out
 
@@ -1240,10 +1242,10 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
             recs0 = {
                 "num_splits": jnp.zeros((T, K), jnp.int32),
                 "raw": jnp.zeros((T, K, m, 12)),
-                # per tree: level_stream launches, the rows they streamed and
-                # the segments they partitioned (one shard's rows, in the
-                # sharded program)
-                "levels": jnp.zeros((T, K, 3), jnp.int32),
+                # per tree (PTreeResult.level_counts): level_stream launches,
+                # the rows they streamed (one shard's, in the sharded program),
+                # the segments they partitioned, the slots the search visited
+                "levels": jnp.zeros((T, K, 4), jnp.int32),
                 # per tree: replayed splits whose children were all-reduced
                 # (the tail); with `levels` and the root's, every histogram
                 # all-reduce the program issued

@@ -63,6 +63,7 @@ from .pkernels import (
     PLayout,
     _hist_from_rows,
     hist_dyn,
+    hist_lanes,
     hists_from_planes,
     level_stream,
     split_stream,
@@ -102,8 +103,11 @@ class PGrowParams(NamedTuple):
     # identical best split on its local segment.  Three sites: the root
     # histogram (psum'd by the chunk program), one all-reduce a LEVEL of
     # all ``level_slots`` slots' kernel-layout rows, (slots, 16, lanes)
-    # with inactive slots zeroed and not left out, and both children's
-    # g, h and count planes, (6, lanes), of a tail split.  None/"" = serial.
+    # with inactive slots zeroed and not left out (the search after it
+    # visits the active ones alone, ``scan_batch`` at a time: the count
+    # comes from tables every shard holds, so every shard takes the same
+    # trips), and both children's g, h and count planes, (6, lanes), of a
+    # tail split.  None/"" = serial.
     axis_name: str = None
     # level-batched expansion (phase 1) toggle.  It used to be an env
     # read (LIGHTGBM_TPU_LEVELGROW) at trace time inside the jitted
@@ -120,6 +124,64 @@ def level_slots(num_leaves: int) -> int:
     histograms it returns and, under ``axis_name``, of what a level
     all-reduces."""
     return min(-(-(num_leaves + 1) // 8) * 8, 512)
+
+
+# Kernel rows one batch of the level's split search reads (``scan_batch``).
+# Measured at 2,000 columns on the v5e (my chip run, PR 34, call A; ms of
+# `split_scan` an iteration at 4 / 8 / 16 / 32 slots a batch: 72.7 / 81.2 /
+# 108.0 / 159.8, and 953.3 at all 256): a slot costs less in a short batch, and
+# a level's last batch visits fewer slots that hold nothing.
+SCAN_BATCH_BYTES = 32 << 20
+
+
+def scan_batch(num_leaves: int, lanes: int) -> int:
+    """Slots the level's split search takes at a time (``SB``): the largest
+    power of two whose kernel rows, 16 of ``lanes`` float32 a slot, fit
+    ``SCAN_BATCH_BYTES``, between 4 and ``level_slots``.  A function of the
+    shape alone: 4 at 2,000 columns x 63 bins, 32 at 200, and all
+    ``level_slots(255)`` = 256 up to 32 columns, where the search is the
+    straight-line one and the program has no loop for it."""
+    fit = max(SCAN_BATCH_BYTES // (16 * lanes * 4), 1)
+    return min(max(1 << (fit.bit_length() - 1), 4), level_slots(num_leaves))
+
+
+def level_split_scan(find2, hists, sums2, dok2, n_act, batch: int,
+                     num_cols: int, num_bins: int):
+    """The level phase's split search: ``find2`` on both children of the
+    first ``n_act`` slots of a level's kernel-layout histograms ``hists``
+    (SMAX, 16, lanes), with ``sums2`` (SMAX, 2, 3) and ``dok2`` (SMAX, 2).
+    Returns (``find2``'s fields as (SMAX, 2) tables, slots visited).
+
+    ``batch`` >= SMAX searches every slot at once.  Below it the search
+    runs over ``batch`` slots at a time, ceil(n_act / batch) times: a
+    slot's reductions never cross slots, so rows < ``n_act`` are the same
+    whatever the batch; rows past the last batch visited keep the
+    tables' initial zeros (the level phase drops every row >= ``n_act``).
+    The loop carries the small tables alone; ``hists`` is its invariant."""
+    smax = hists.shape[0]
+
+    def scan(hists, sums2, dok2):
+        hist_l = jax.vmap(lambda h: _hist_from_rows(h, num_cols, num_bins, row0=0))(hists)
+        hist_r = jax.vmap(lambda h: _hist_from_rows(h, num_cols, num_bins, row0=7))(hists)
+        hist2 = jnp.stack([hist_l, hist_r], axis=1)  # (slots, 2, G, BH, 3)
+        return jax.vmap(find2)(hist2, sums2, dok2)  # fields (slots, 2)
+
+    if batch >= smax:
+        return scan(hists, sums2, dok2), jnp.int32(smax)
+
+    def step(i, tables):
+        res = scan(*(jax.lax.dynamic_slice_in_dim(a, i * batch, batch)
+                     for a in (hists, sums2, dok2)))
+        return jax.tree.map(
+            lambda t, r: jax.lax.dynamic_update_slice_in_dim(t, r, i * batch, 0),
+            tables, res)
+
+    trips = (n_act + batch - 1) // batch
+    one_batch = jax.eval_shape(scan, *(
+        jax.ShapeDtypeStruct((batch,) + a.shape[1:], a.dtype) for a in (hists, sums2, dok2)))
+    tables0 = jax.tree.map(
+        lambda a: jnp.zeros((smax,) + a.shape[1:], a.dtype), one_batch)
+    return jax.lax.fori_loop(0, trips, step, tables0), trips * batch
 
 
 def levelgrow_env_params() -> dict:
@@ -180,8 +242,10 @@ class PTreeResult(NamedTuple):
     rec_rcnt: jnp.ndarray
     rec_internal_value: jnp.ndarray
     # what the level phase did for this tree (zeros under LEVELGROW=0), as
-    # (3,) int32: level_stream launches, the rows they streamed and the
-    # segments they partitioned, each summed over the levels
+    # (4,) int32: level_stream launches, the rows they streamed, the
+    # segments they partitioned and the slots the split search visited
+    # (``scan_batch`` x its trips; all ``level_slots`` a level where it
+    # has no loop), each summed over the levels
     level_counts: jnp.ndarray = None
     # data-parallel only (None in a serial program): replayed splits whose
     # children's histograms were all-reduced (the ``split_stream`` tail)
@@ -221,6 +285,32 @@ def _meta_table(meta: FeatureMeta, bmeta, f: int, bits: int) -> jnp.ndarray:
         bias = jnp.zeros((f,), jnp.float32)
     z = jnp.zeros((f,), jnp.float32)
     return jnp.stack([db, cat, col, off_lo, off_hi, bias, z, z], axis=1)
+
+
+def sibling_split_search(params: PGrowParams, meta: FeatureMeta, hyper: SplitHyper,
+                         feature_mask, bmeta=None):
+    """``find2`` of ``params``: the best split of two sibling leaves at once."""
+    F, B = params.num_features, params.num_bins
+
+    def find2(hist2, sums2, depth_ok):
+        """Best split for sibling leaves at once: hist2 (2, G/F, B, 3),
+        sums2 (2, 3) -> per-leaf scalars stacked on axis 0."""
+        if bmeta is not None:
+            hist2 = jax.vmap(
+                lambda hh, ss: _expand_bundle_hist(hh, ss, bmeta, F, B)
+            )(hist2, sums2)
+
+        def one(hist, s):
+            gain_f, thr_f, dbz_f, left_f = best_split_per_feature(
+                hist, s[0], s[1], s[2], meta, hyper, feature_mask,
+                params.use_missing, has_categorical=params.has_categorical,
+            )
+            return finalize_split(gain_f, thr_f, dbz_f, left_f, s[0], s[1], s[2], hyper)
+
+        res = jax.vmap(one)(hist2, sums2)
+        return res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF))
+
+    return find2
 
 
 @functools.partial(jax.jit, static_argnames=("params", "interpret", "rows"),
@@ -263,7 +353,6 @@ def grow_tree_partitioned(
     # physical columns the kernels stream (EFB bundles or plain features)
     G = params.num_cols or F
     BH = params.num_bins_hist or B
-    bundled = bmeta is not None
     if rows is None:
         # default single-class channel rows; multiclass callers pass
         # PLayout.class_rows(k) so tree k reads its own g/h pair
@@ -272,23 +361,7 @@ def grow_tree_partitioned(
     mtab = _meta_table(meta, bmeta, F, params.bits)
     levelwise = params.levelwise and L > 4
 
-    def find2(hist2, sums2, depth_ok):
-        """Best split for sibling leaves at once: hist2 (2, G/F, B, 3),
-        sums2 (2, 3) -> per-leaf scalars stacked on axis 0."""
-        if bundled:
-            hist2 = jax.vmap(
-                lambda hh, ss: _expand_bundle_hist(hh, ss, bmeta, F, B)
-            )(hist2, sums2)
-
-        def one(hist, s):
-            gain_f, thr_f, dbz_f, left_f = best_split_per_feature(
-                hist, s[0], s[1], s[2], meta, hyper, feature_mask,
-                params.use_missing, has_categorical=params.has_categorical,
-            )
-            return finalize_split(gain_f, thr_f, dbz_f, left_f, s[0], s[1], s[2], hyper)
-
-        res = jax.vmap(one)(hist2, sums2)
-        return res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF))
+    find2 = sibling_split_search(params, meta, hyper, feature_mask, bmeta)
 
     with jax.named_scope(UPDATE_ROOT_HIST):
         if root_hist is None:
@@ -332,13 +405,15 @@ def grow_tree_partitioned(
             CANDMAX = 2 * SMAX
             # A table of 2 * SMAX candidates holds a complete tree of
             # log2(SMAX) levels; one level more takes the slots that nodes
-            # without a split left free.  Every level searches all SMAX slots
-            # whatever it holds (2.1 GB of histograms at 2,000 columns), so a
-            # level after that would pay a whole search for the few slots
-            # still free, and whether a tree wanted one moved a 255-leaf
-            # iteration by 6% from one seed to the next.  Those splits are
-            # the replay's, one at a time.
+            # without a split left free.  Every level streams, and under
+            # ``axis_name`` all-reduces, the histograms of all SMAX slots
+            # whatever it holds (2.1 GB at 2,000 columns; the search alone
+            # stops at the active ones), so a level after that would pay
+            # them for the few slots still free, and whether a tree wanted
+            # one moved a 255-leaf iteration by 6% from one seed to the
+            # next.  Those splits are the replay's, one at a time.
             MAXLVL = (SMAX - 1).bit_length() + 1
+            SB = scan_batch(L, hist_lanes(G, BH))
             c_seg0 = jnp.zeros((CANDMAX, 2), jnp.int32).at[0, 1].set(n)
             c_bs0 = jnp.full((CANDMAX, 8), NEG_INF, jnp.float32).at[0].set(root_bs)
             c_leaf0 = jnp.zeros((CANDMAX, 8), jnp.float32).at[0].set(root_leaf)
@@ -399,13 +474,12 @@ def grow_tree_partitioned(
                 sums2 = jnp.stack([lsums, rsums], axis=1)  # (SMAX, 2, 3)
                 dok2 = (jnp.ones((SMAX, 2), bool) if params.max_depth <= 0
                         else jnp.stack([cdepth < params.max_depth] * 2, axis=1))
-                # the level's whole-array work: SMAX x 2 x G x BH cells, 29 MB
-                # of histograms a level at 28 columns, 2.1 GB at 2,000
+                # 2 x G x BH cells a slot: all SMAX slots at once where that
+                # is small (29 MB of histograms a level at 28 columns), SB at
+                # a time up to n_act where it is not (2.1 GB at 2,000)
                 with jax.named_scope(SPLIT_SCAN):
-                    hist_l = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=0))(hists)
-                    hist_r = jax.vmap(lambda h: _hist_from_rows(h, G, BH, row0=7))(hists)
-                    hist2 = jnp.stack([hist_l, hist_r], axis=1)  # (SMAX, 2, G, BH, 3)
-                    res = jax.vmap(find2)(hist2, sums2, dok2)  # fields (SMAX, 2)
+                    res, scanned = level_split_scan(
+                        find2, hists, sums2, dok2, n_act, SB, G, BH)  # fields (SMAX, 2)
                 vals2 = leaf_output(sums2[..., 0], sums2[..., 1],
                                     hyper.lambda_l1, hyper.lambda_l2)  # (SMAX, 2)
                 il = jnp.where(arow, cand_n + 2 * idx, CANDMAX)
@@ -444,13 +518,13 @@ def grow_tree_partitioned(
                 )
                 return (p, c_seg, c_bs, c_leaf, c_childlo, cand_n + 2 * n_act,
                         children, 2 * n_act, level + 1,
-                        streamed + jnp.stack([jnp.sum(seg_tab[:, 1]), n_act]))
+                        streamed + jnp.stack([jnp.sum(seg_tab[:, 1]), n_act, scanned]))
 
             (p, c_seg, c_bs, c_leaf, c_childlo, _, _, _, levels, streamed) = (
                 jax.lax.while_loop(
                     lcond, lbody,
                     (p, c_seg0, c_bs0, c_leaf0, c_childlo0, jnp.int32(1), frontier0,
-                     jnp.int32(1), jnp.int32(0), jnp.zeros((2,), jnp.int32)),
+                     jnp.int32(1), jnp.int32(0), jnp.zeros((3,), jnp.int32)),
                 ))
             level_counts = jnp.concatenate([levels[None], streamed])
             pslot0 = jnp.full((L,), -1, jnp.int32).at[0].set(0)
@@ -461,7 +535,7 @@ def grow_tree_partitioned(
             c_leaf = jnp.zeros((1, 8), jnp.float32)
             c_childlo = jnp.full((1,), -1, jnp.int32)
             pslot0 = jnp.full((L,), -1, jnp.int32)
-            level_counts = jnp.zeros((3,), jnp.int32)
+            level_counts = jnp.zeros((4,), jnp.int32)
 
     # ---- phase 2: exact best-first selection ------------------------
     st = _PState(

@@ -7,6 +7,7 @@ grow_tree_partitioned and the mask-based grow_tree, and the fused
 trainer end-to-end against the default path.
 """
 
+import functools
 import os
 import random
 
@@ -378,26 +379,33 @@ _SEGMENT_CASES = {
 }
 
 
+def _plain_meta_hyper(f, b):
+    """(FeatureMeta, SplitHyper) of ``f`` numerical columns of ``b`` bins."""
+    from lightgbm_tpu.ops.split import FeatureMeta, SplitHyper
+
+    meta = FeatureMeta(
+        num_bins=jnp.full((f,), b, jnp.int32),
+        default_bin=jnp.zeros((f,), jnp.int32),
+        is_categorical=jnp.zeros((f,), bool),
+    )
+    hyper = SplitHyper(
+        lambda_l1=jnp.float32(0.0), lambda_l2=jnp.float32(0.01),
+        min_data_in_leaf=jnp.float32(20), min_sum_hessian_in_leaf=jnp.float32(1e-3),
+        min_gain_to_split=jnp.float32(0.0),
+    )
+    return meta, hyper
+
+
 class TestGrowParity:
     def test_tree_matches_mask_grower(self):
         """grow_tree_partitioned must reproduce grow_tree's split records
         on identical inputs (same histogram math to f32 tolerance; any
         divergence means a partition/histogram bug)."""
         from lightgbm_tpu.ops.grow import GrowParams, grow_tree
-        from lightgbm_tpu.ops.split import FeatureMeta, SplitHyper
 
         n, f, b, L = 6000, 11, 32, 15
         P, lay, bins, g, h, sel = _make_packed(n, f, b)
-        meta = FeatureMeta(
-            num_bins=jnp.full((f,), b, jnp.int32),
-            default_bin=jnp.zeros((f,), jnp.int32),
-            is_categorical=jnp.zeros((f,), bool),
-        )
-        hyper = SplitHyper(
-            lambda_l1=jnp.float32(0.0), lambda_l2=jnp.float32(0.01),
-            min_data_in_leaf=jnp.float32(20), min_sum_hessian_in_leaf=jnp.float32(1e-3),
-            min_gain_to_split=jnp.float32(0.0),
-        )
+        meta, hyper = _plain_meta_hyper(f, b)
         fmask = jnp.ones((f,), jnp.float32)
         pres, P2 = grow_tree_partitioned(
             P, fmask, meta, hyper,
@@ -846,9 +854,9 @@ class TestLevelGrowerCaps:
     def test_level_phase_is_bounded_by_its_table(self, monkeypatch, num_leaves, rows,
                                                  min_hess, binds):
         """The level phase runs at most log2(SMAX) + 1 levels and none on a
-        full candidate table (each one searches all SMAX slots whatever it
-        holds); what it leaves is the replay's tail, and the trees are those
-        of the per-split grower."""
+        full candidate table (each one streams all SMAX slots' histograms
+        whatever it holds); what it leaves is the replay's tail, and the trees
+        are those of the per-split grower."""
         import lightgbm_tpu as lgb
 
         rng = np.random.default_rng(5)
@@ -878,9 +886,10 @@ class TestLevelGrowerCaps:
             leaves[mode] = [t.num_leaves for t in bst.boosting.models]
         np.testing.assert_array_equal(preds["1"], preds["0"])
         assert leaves["1"] == leaves["0"] and max(leaves["1"]) == num_leaves
-        levels, _, segments = np.array(counts[:6]).T  # LEVELGROW=0 counts none
+        levels, _, segments, scanned = np.array(counts[:6]).T  # LEVELGROW=0 counts none
         assert not np.array(counts[6:]).any()
         smax = -(-(num_leaves + 1) // 8) * 8
+        assert (scanned == levels * smax).all()  # 6 columns: the search has no loop
         cap = (smax - 1).bit_length() + 1
         assert levels.max() <= cap and segments.max() <= smax - 1, counts
         if binds == "table":
@@ -1069,29 +1078,22 @@ class TestChunkStops:
 GOLDEN_RECS = os.path.join(os.path.dirname(__file__), "golden", "pgrow_recs.npz")
 
 
-def chunk_records(levelgrow: str, sharded: bool):
-    """Split records of 3 iterations on a seeded 65,536-row table, as the
-    chunk program returns them.  LIGHTGBM_TPU_PGROW=force must be set."""
+def _captured_chunk(X, y, params, sharded: bool, iterations: int, env=None):
+    """(records, trainer) of one chunk of ``iterations`` iterations, as the
+    chunk program returns them; four shards under ``sharded``.
+    LIGHTGBM_TPU_PGROW=force must be set."""
     from unittest import mock
 
     import lightgbm_tpu as lgb
     import lightgbm_tpu.parallel as par
 
-    rng = np.random.default_rng(20270927)
-    n, f = 65536, 10
-    X = rng.standard_normal((n, f)).astype(np.float32)
-    w = rng.standard_normal(f)
-    y = (rng.random(n) < 1 / (1 + np.exp(-(X @ w + X[:, 0] * X[:, 1])))).astype(np.float32)
-    params = dict(objective="binary", num_leaves=31, learning_rate=0.1, max_bin=63,
-                  min_data_in_leaf=20, verbose=-1,
-                  tree_learner="data" if sharded else "serial")
+    params = dict(params, tree_learner="data" if sharded else "serial")
     mesh4 = par.make_mesh(4) if sharded else None
-    with mock.patch.dict(os.environ, {"LIGHTGBM_TPU_LEVELGROW": levelgrow}), \
+    with mock.patch.dict(os.environ, env or {}), \
             mock.patch.object(par, "make_mesh", lambda n_devices=None: mesh4):
         b = lgb.Booster(params=params,
                         train_set=lgb.Dataset(X, label=y, params=dict(params))).boosting
     pt = b.ptrainer
-    assert pt.params.levelwise == (levelgrow == "1")
     assert getattr(pt, "d", 1) == (4 if sharded else 1)
     seen = {}
     run = pt.train_chunk
@@ -1102,9 +1104,25 @@ def chunk_records(levelgrow: str, sharded: bool):
         return out
 
     with mock.patch.object(pt, "train_chunk", capture):
-        b.train_iters_partitioned(3, is_eval=False)
-    assert seen["recs"]["num_splits"].tolist() == [[30]] * 3
-    return seen["recs"]["raw"]
+        b.train_iters_partitioned(iterations, is_eval=False)
+    return seen["recs"], pt
+
+
+def chunk_records(levelgrow: str, sharded: bool):
+    """Split records of 3 iterations on a seeded 65,536-row table, as the
+    chunk program returns them.  LIGHTGBM_TPU_PGROW=force must be set."""
+    rng = np.random.default_rng(20270927)
+    n, f = 65536, 10
+    X = rng.standard_normal((n, f)).astype(np.float32)
+    w = rng.standard_normal(f)
+    y = (rng.random(n) < 1 / (1 + np.exp(-(X @ w + X[:, 0] * X[:, 1])))).astype(np.float32)
+    params = dict(objective="binary", num_leaves=31, learning_rate=0.1, max_bin=63,
+                  min_data_in_leaf=20, verbose=-1)
+    recs, pt = _captured_chunk(X, y, params, sharded, 3,
+                               env={"LIGHTGBM_TPU_LEVELGROW": levelgrow})
+    assert pt.params.levelwise == (levelgrow == "1")
+    assert recs["num_splits"].tolist() == [[30]] * 3
+    return recs["raw"]
 
 
 GOLDEN_CASES = [("serial_lg1", "1", False), ("serial_lg0", "0", False),
@@ -1129,3 +1147,110 @@ class TestRecordsGolden:
         got = chunk_records(levelgrow, sharded)
         assert got.dtype == want.dtype and got.shape == want.shape == (3, 1, 30, 12)
         assert got.tobytes() == want.tobytes()
+
+
+def _wide_chunk(sharded: bool):
+    """(records, trainer) of a chunk of 2 iterations, 63 leaves, on a seeded
+    2,048 x 600 table.  LIGHTGBM_TPU_PGROW=force must be set."""
+    rng = np.random.default_rng(20340600)
+    n, f = 2048, 600
+    X = rng.standard_normal((n, f)).astype(np.float32)
+    w = rng.standard_normal(6)
+    y = (rng.random(n) < 1 / (1 + np.exp(-(X[:, [0, 5, 299, 300, 598, 599]] @ w)))
+         ).astype(np.float32)
+    params = dict(objective="binary", num_leaves=63, learning_rate=0.1, max_bin=63,
+                  min_data_in_leaf=1, min_sum_hessian_in_leaf=1e-3, verbose=-1)
+    return _captured_chunk(X, y, params, sharded, 2)
+
+
+class TestLevelSplitScan:
+    """PR 34: the level phase's split search visits ``scan_batch`` slots at a
+    time up to the level's active count, and a slot's search is the same
+    ``find2`` on the same histogram whatever the batch: every field of every
+    slot below ``n_act`` bit for bit."""
+
+    SMAX, COLS, BINS = 32, 7, 32
+
+    @pytest.fixture(scope="class")
+    def level(self):
+        from lightgbm_tpu.ops import pgrow
+
+        s, f, b = self.SMAX, self.COLS, self.BINS
+        rng = np.random.default_rng(20340034)
+        lanes = pk.hist_lanes(f, b)
+        rows = rng.standard_normal((s, 16, lanes)).astype(np.float32)
+        rows[:, [3, 4, 5, 10, 11, 12]] = np.abs(rows[:, [3, 4, 5, 10, 11, 12]])  # hessians
+        rows[:, [6, 13]] = rng.integers(0, 40, (s, 2, lanes))  # counts
+        rows[:, [7, 14, 15]] = 0.0
+        hists = jnp.asarray(rows)
+        hist2 = jnp.stack([jax.vmap(lambda h, r=r: pk._hist_from_rows(h, f, b, row0=r))(hists)
+                           for r in (0, 7)], axis=1)
+        sums2 = jnp.sum(hist2[:, :, 0], axis=2)  # (SMAX, 2, 3): totals via feature 0
+        dok2 = jnp.asarray(rng.random((s, 2)) < 0.8)
+        find2 = pgrow.sibling_split_search(
+            PGrowParams(2 * s - 1, b, f, 1000, -1, True, False), *_plain_meta_hyper(f, b),
+            jnp.ones((f,), jnp.float32))
+
+        @functools.partial(jax.jit, static_argnums=1)
+        def search(n_act, batch):
+            return pgrow.level_split_scan(find2, hists, sums2, dok2, n_act, batch, f, b)
+
+        whole, visited = search(jnp.int32(s // 2), s)
+        assert int(visited) == s
+        gains = np.asarray(whole.gain)
+        assert (gains > 0).any() and (gains == -np.inf).any()  # both kinds compared
+        return search, whole
+
+    @pytest.mark.parametrize("n_act", [0, 1, 7, 8, 9, SMAX // 2])
+    @pytest.mark.parametrize("batch", [4, 8])
+    def test_batched_search_is_the_whole_one_bit_for_bit(self, level, batch, n_act):
+        search, whole = level
+        got, visited = search(jnp.int32(n_act), batch)
+        assert int(visited) == -(-n_act // batch) * batch
+        for name, want, have in zip(whole._fields, whole, got):
+            want, have = np.asarray(want), np.asarray(have)
+            assert have.shape == want.shape == (self.SMAX, 2) and have.dtype == want.dtype
+            # whole batches are searched (n_act <= visited); what no batch
+            # reached was never written
+            assert have[:int(visited)].tobytes() == want[:int(visited)].tobytes(), name
+            assert not have[int(visited):].any(), name
+
+    def test_the_batch_is_a_function_of_the_shape(self):
+        from lightgbm_tpu.ops.pgrow import level_slots, scan_batch
+
+        for cols, want in ((28, 256), (32, 256), (33, 128), (200, 32), (600, 8), (2000, 4),
+                           (4000, 4)):
+            assert scan_batch(255, pk.hist_lanes(cols, 63)) == want, cols
+        assert scan_batch(31, pk.hist_lanes(28, 63)) == level_slots(31) == 32
+        assert scan_batch(15, pk.hist_lanes(2000, 63)) == 4 < level_slots(15)
+
+    @pytest.mark.parametrize("sharded", [False, True], ids=["serial", "four-shards"])
+    def test_records_byte_equal_to_the_straight_search(self, monkeypatch, sharded):
+        """End to end at a width where the shape rule itself picks a batch
+        under SMAX (63 leaves x 600 columns: 8 of 64 slots, four trips at the
+        fullest level): the chunk's records are those of the straight-line
+        search byte for byte, serial and under a 4-device ``shard_map``, and
+        ``scan_slots`` says which of the two ran."""
+        from lightgbm_tpu.ops import pgrow
+
+        if sharded and len(jax.devices()) < 4:
+            pytest.skip("needs 4 devices")
+        monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+        batched, pt = _wide_chunk(sharded)
+        smax = pgrow.level_slots(63)
+        sb = pgrow.scan_batch(63, pk.hist_lanes(600, 63))
+        assert (smax, sb) == (64, 8)
+        counts = pt.stream_counts(batched, 2)
+        assert counts["levels"] * sb < counts["scan_slots"] < counts["levels"] * smax
+        assert counts["level_segments"] <= counts["scan_slots"] <= (
+            counts["level_segments"] + counts["levels"] * (sb - 1))
+        # the same program with the straight-line search: the grower is traced
+        # anew, under a budget that holds all SMAX slots in one batch
+        monkeypatch.setattr(pgrow, "SCAN_BATCH_BYTES", 1 << 40)
+        jax.clear_caches()
+        straight, pt = _wide_chunk(sharded)
+        jax.clear_caches()
+        assert pt.stream_counts(straight, 2)["scan_slots"] == counts["levels"] * smax
+        assert batched["num_splits"].tolist() == straight["num_splits"].tolist() == [[62]] * 2
+        assert batched["raw"].tobytes() == straight["raw"].tobytes()
+        assert batched["levels"][..., :3].tolist() == straight["levels"][..., :3].tolist()

@@ -397,6 +397,51 @@ def test_trees_from_records_carries_the_stream_counts(traced_chunks):
         assert t["levels"] <= t["level_segments"] <= 2 * (pt.params.num_leaves - 1) * t["levels"]
 
 
+@pytest.mark.parametrize("objective,rows,loop", [
+    ("binary", 300, False), ("binary", 310, True), ("multiclass", 320, False)])
+def test_scan_slots_counts_what_the_split_search_visited(tmp_path, monkeypatch, objective, rows,
+                                                         loop):
+    """`scan_slots` (PR 34), beside `level_segments` on the same span: all
+    `level_slots` of every level where the search has no loop (any shape up to
+    32 columns), whole batches of `scan_batch` slots up to the level's active
+    count where it has one, summed over trees and classes like its neighbours.
+    The loop is steered in the test, by a budget no 4 slots fit; each case has
+    a row count of its own, because a grower traced once for a shape stays
+    traced."""
+    from lightgbm_tpu.ops import pgrow
+
+    monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+    if loop:
+        monkeypatch.setattr(pgrow, "SCAN_BATCH_BYTES", 1)
+    X, yb, ym = _toy(rows)
+    params = {"objective": objective, "num_leaves": 15, "min_data_in_leaf": 2, "verbose": -1}
+    if objective == "multiclass":
+        params["num_class"] = 3
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(
+        X, label=ym if objective == "multiclass" else yb, params=params))
+    pt = bst.boosting.ptrainer
+    path = str(tmp_path / "trace.jsonl")
+    tracer.configure(path)
+    try:
+        bst.boosting.train_iters_partitioned(2, is_eval=False)
+    finally:
+        tracer.close()
+        tracer.path = None
+    with open(path) as f:
+        (t,) = [r for r in map(json.loads, f)
+                if r["ev"] == "span" and r["name"] == "trees_from_records"]
+    smax = pgrow.level_slots(15)
+    sb = pgrow.scan_batch(15, t["hist_cells"])
+    assert t["trees"] == 2 * pt.K and t["levels"] >= 3 * t["trees"]
+    if loop:
+        assert sb == 4 < smax
+        assert t["level_segments"] <= t["scan_slots"] <= t["level_segments"] + t["levels"] * (sb - 1)
+        assert t["scan_slots"] % sb == 0 and t["scan_slots"] < t["levels"] * smax
+    else:
+        assert sb == smax == 16
+        assert t["scan_slots"] == t["levels"] * smax
+
+
 def test_serial_trees_from_records_reduce_nothing(traced_chunks):
     _, recs, _, _ = traced_chunks
     trees = [r for r in recs if r["ev"] == "span" and r["name"] == "trees_from_records"]
