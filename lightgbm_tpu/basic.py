@@ -16,6 +16,7 @@ import numpy as np
 from .boosting import create_boosting
 from .config import Config
 from .io.dataset import BinnedDataset
+from .io.sparse import is_sparse, map_dense_blocks
 from .metric import create_metric
 from .objective import create_objective
 from .utils.log import Log
@@ -48,17 +49,13 @@ def _to_2d_float(data, want_cats: bool = False):
             return (arr, names, cat_idx, levels) if want_cats else (arr, names)
     except ImportError:
         pass
-    # scipy CSR/CSC input (basic.py __init_from_csr/__init_from_csc):
-    # the TPU pipeline is dense by design (README sparse-bins decision) —
-    # densify here; EFB re-compacts exclusive columns downstream
-    if hasattr(data, "tocsr") and hasattr(data, "toarray"):
-        Log.warning(
-            "Sparse input is densified for the TPU pipeline "
-            "(%d x %d); EFB bundling recovers the memory on device",
-            *data.shape,
-        )
-        arr = np.asarray(data.toarray(), dtype=np.float64)
-        return (arr, None, [], []) if want_cats else (arr, None)
+    # scipy CSR/CSC input (basic.py __init_from_csr/__init_from_csc) stays
+    # as it is: Dataset bins and bundles it through io/sparse.py and
+    # Booster.predict walks it in row blocks, so neither densifies the
+    # table.  What the device then holds is dense (README sparse-bins
+    # decision): the bundled matrix.
+    if is_sparse(data):
+        return (data, None, [], []) if want_cats else (data, None)
     arr = np.asarray(data, dtype=np.float64)
     if arr.ndim == 1:
         arr = arr.reshape(-1, 1)
@@ -237,7 +234,8 @@ class Dataset:
         ref = self.reference.construct() if self.reference is not None else None
         if self.reference is not None:
             self._remap_categorical_to_reference(self.reference)
-        self._constructed = BinnedDataset.from_raw(
+        make = BinnedDataset.from_sparse if is_sparse(self.data) else BinnedDataset.from_raw
+        self._constructed = make(
             self.data,
             cfg,
             label=self.label,
@@ -345,7 +343,7 @@ class Dataset:
     def num_data(self) -> int:
         if self._constructed is not None:
             return self._constructed.num_data
-        return len(self.data) if self.data is not None else 0
+        return self.data.shape[0] if self.data is not None else 0
 
     def num_feature(self) -> int:
         if self._constructed is not None:
@@ -577,6 +575,13 @@ class Booster:
         else:
             data = _map_pandas_categorical(data, self.pandas_categorical)
             data, _ = _to_2d_float(data)
+        if is_sparse(data):
+            # row blocks of one size: the table is never dense whole, and the
+            # blocks share one compiled predictor
+            return np.concatenate(map_dense_blocks(
+                lambda block: self.boosting.predict(
+                    block, num_iteration=num_iteration, raw_score=raw_score,
+                    pred_leaf=pred_leaf), data))
         return self.boosting.predict(
             data, num_iteration=num_iteration, raw_score=raw_score, pred_leaf=pred_leaf
         )

@@ -228,7 +228,14 @@ class GBDT:
                 config.quantized_training = False
 
         # device-resident training state
-        self.bins = None if ooc_on else jnp.asarray(train_set.binned)
+        # A dataset made from sparse input holds its bundles alone: the
+        # fused trainer packs those, and the (N, F) bins are decoded and
+        # uploaded only if something that works by feature reads `bins`
+        # (the mask grower, rollback, DART's rescoring)
+        self._bins = None
+        self._bins_lazy = not ooc_on and not train_set.has_dense_bins
+        if not ooc_on and not self._bins_lazy:
+            self._bins = jnp.asarray(train_set.binned)
         self.num_bins = int(train_set.max_num_bin)
         self.meta = FeatureMeta.from_dataset(train_set)
         self.hyper = SplitHyper.from_config(config)
@@ -388,7 +395,7 @@ class GBDT:
             if _pt_eligible(config, train_set, objective, self.num_tree_per_iteration):
                 self.ptrainer = PartitionedTrainer(
                     train_set, config, objective, self.meta, self.hyper,
-                    bins_dev=self.bins,
+                    bins_dev=self._bins,
                 )
                 Log.info("Using partitioned (fused) TPU tree learner")
         k = self.num_tree_per_iteration
@@ -438,6 +445,25 @@ class GBDT:
         # the survivors are mid-iteration when a joiner initializes.
         if self._membership is not None and self._membership.joined_mid_run:
             self._membership_join_restore()
+
+    @property
+    def bins(self):
+        """The ``(N, F)`` bin matrix on the device; None when training
+        streams out of core."""
+        if self._bins is None and self._bins_lazy:
+            self._bins_lazy = False
+            self._bins = jnp.asarray(self.train_set.binned)
+        return self._bins
+
+    @bins.setter
+    def bins(self, value) -> None:
+        self._bins = value
+
+    @property
+    def has_device_bins(self) -> bool:
+        """Whether the ``(N, F)`` matrix is on the device now (asking does
+        not decode it)."""
+        return self._bins is not None
 
     def add_valid(self, valid_set, valid_metrics, name: str):
         """GBDT::AddValidDataset (gbdt.cpp:220-250)."""

@@ -119,9 +119,12 @@ def resolve_out_of_core(config, train_set) -> Tuple[bool, int, str]:
     if mode not in ("true", "1", "on", "yes", "auto"):
         Log.fatal("Unknown out_of_core mode %r (expected true/false/auto)",
                   mode)
-    binned = train_set.binned
-    packed = int(train_set.num_data) * int(train_set.num_features) * \
-        int(binned.dtype.itemsize)
+    # what would be resident: the per-feature bins or, for a dataset made
+    # from sparse input, its bundle columns
+    itemsize = int(train_set.bin_dtype.itemsize)
+    cols = (train_set.num_features if train_set.has_dense_bins
+            else train_set.bundle.num_cols)
+    packed = int(train_set.num_data) * int(cols) * itemsize
     if mode == "auto":
         budget = _device_budget_bytes()
         if budget is None:
@@ -134,7 +137,7 @@ def resolve_out_of_core(config, train_set) -> Tuple[bool, int, str]:
     else:
         reason = "out_of_core=true (forced)"
     chunk_rows = resolve_chunk_rows(
-        config, train_set.num_features, binned.dtype.itemsize)
+        config, train_set.num_features, itemsize)
     return True, chunk_rows, reason
 
 

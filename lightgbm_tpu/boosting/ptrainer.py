@@ -167,9 +167,8 @@ class PartitionedTrainer:
 
     def __init__(self, train_set, config, objective, meta: FeatureMeta, hyper: SplitHyper,
                  bins_dev=None):
-        binned = train_set.binned
-        n, f = binned.shape
-        assert binned.dtype == np.uint8
+        n, f = train_set.num_data, train_set.num_features
+        assert train_set.bin_dtype == np.uint8
         md = train_set.metadata
         self.has_weights = md.weights is not None
         # K > 1: multiclass — K score channels, K trees per iteration
@@ -189,7 +188,7 @@ class PartitionedTrainer:
             bins_dev = None  # the unbundled device matrix is not what we pack
             max_col_bin = num_bins_hist
         else:
-            matrix = binned
+            matrix = train_set.binned
             max_col_bin = int(train_set.max_num_bin)
         # 4-bit packed words when every column fits 16 bins
         # (dense_nbits_bin.hpp:37): half the resident bin bytes/traffic
@@ -406,6 +405,7 @@ class PartitionedTrainer:
                 ns_t = recs["num_splits"][t]
                 raw_t = recs["raw"][t]
                 lv_t = recs["levels"][t]
+                tl_t = recs["tail"][t]
                 if K == 1:
                     if goss_on:
                         # GOSS (goss.hpp:126-198): settle the pending
@@ -480,6 +480,7 @@ class PartitionedTrainer:
                     ns_t = ns_t.at[0].set(tree.num_splits)
                     raw_t = raw_t.at[0].set(tree.recs_raw)
                     lv_t = lv_t.at[0].set(tree.level_counts)
+                    tl_t = tl_t.at[0].set(tree.tail_counts)
                 else:
                     # K trees per iteration (per-class loop,
                     # gbdt.cpp:445-480): ALL K gradient planes + K root
@@ -512,6 +513,7 @@ class PartitionedTrainer:
                         ns_t = ns_t.at[k].set(tree.num_splits)
                         raw_t = raw_t.at[k].set(tree.recs_raw)
                         lv_t = lv_t.at[k].set(tree.level_counts)
+                        tl_t = tl_t.at[k].set(tree.tail_counts)
                     delta = delta  # unused for K > 1 (scores always settled)
 
                 # ONE packed record buffer: per-op dispatch inside the
@@ -521,6 +523,7 @@ class PartitionedTrainer:
                     "num_splits": recs["num_splits"].at[t].set(ns_t),
                     "raw": recs["raw"].at[t].set(raw_t),
                     "levels": recs["levels"].at[t].set(lv_t),
+                    "tail": recs["tail"].at[t].set(tl_t),
                 }
                 return (t + 1, ~any_split, p, recs, delta, last_kept)
 
@@ -532,6 +535,9 @@ class PartitionedTrainer:
                 # the rows they streamed (one shard's, in the sharded program),
                 # the segments they partitioned, the slots the search visited
                 "levels": jnp.zeros((T, K, 4), jnp.int32),
+                # per tree (PTreeResult.tail_counts): the replayed splits that
+                # took the split_stream tail, and the rows those passes streamed
+                "tail": jnp.zeros((T, K, 2), jnp.int32),
             }
             # (t, stopped, p, recs, pending delta, last kept delta)
             state0 = (jnp.int32(0), jnp.array(False), p, recs0,
@@ -652,17 +658,24 @@ class PartitionedTrainer:
         ``hist_cells`` (lanes of one leaf's histogram row as the kernels
         issue it, padding included), ``channels`` (rows of the packed
         matrix) and ``col_groups`` (column groups a kernel walks a block
-        in: 1 up to 31 columns).  And what crossed chips: ``shards`` (the
-        mesh's size, 1 here), ``allreduce_calls`` and ``allreduce_bytes``
-        (0 here; ``ShardedPartitionedTrainer`` counts them).  Called only
-        when tracing is on."""
+        in: 1 up to 31 columns), ``bundle_cols`` (EFB bundle columns the
+        matrix holds in place of the features; 0 unbundled); ``tail_splits``
+        and ``tail_rows`` (the replayed splits that took the classic
+        ``split_stream`` tail and the rows of their parents' segments,
+        which those passes read and wrote).  And what crossed chips:
+        ``shards`` (the mesh's size, 1 here), ``allreduce_calls`` and
+        ``allreduce_bytes`` (0 here; ``ShardedPartitionedTrainer`` counts
+        them).  Called only when tracing is on."""
         cols = self.params.num_cols or self.params.num_features
         bins = self.params.num_bins_hist or self.params.num_bins
         out = {"hist_cells": hist_lanes(cols, bins), "channels": self.layout.C,
                "col_groups": col_groups(cols, self.params.bits).count,
+               "bundle_cols": self.params.num_cols,
                "shards": 1, "allreduce_calls": 0, "allreduce_bytes": 0}
         out.update(zip(("levels", "level_rows", "level_segments", "scan_slots"),
                        recs_np["levels"][:n_done].sum(axis=(0, 1)).tolist()))
+        out.update(zip(("tail_splits", "tail_rows"),
+                       recs_np["tail"][:n_done].astype(np.int64).sum(axis=(0, 1)).tolist()))
         return out
 
     def grow_result_view(self, recs_np, t, k: int = 0):
@@ -714,8 +727,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         import jax as _jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        binned = train_set.binned
-        n, f = binned.shape
+        n, f = train_set.num_data, train_set.num_features
         md = train_set.metadata
         self.has_weights = md.weights is not None
         self.mesh = mesh
@@ -746,7 +758,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
             self.bmeta = _build_bundle_meta(bundle, train_set, int(train_set.max_num_bin))
             max_col_bin = num_bins_hist
         else:
-            matrix = np.asarray(binned)
+            matrix = np.asarray(train_set.binned)
             max_col_bin = int(train_set.max_num_bin)
         force_bits = os.environ.get("LIGHTGBM_TPU_FORCE_BITS", "")
         bits = 4 if max_col_bin <= 16 else 8
@@ -1122,7 +1134,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                 ns_t = recs["num_splits"][t]
                 raw_t = recs["raw"][t]
                 lv_t = recs["levels"][t]
-                tp_t = recs["tail_psums"][t]
+                tl_t = recs["tail"][t]
                 if K == 1:
                     if goss_on:
                         # settle pending delta + fresh gradients first
@@ -1197,7 +1209,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                     ns_t = ns_t.at[0].set(tree.num_splits)
                     raw_t = raw_t.at[0].set(tree.recs_raw)
                     lv_t = lv_t.at[0].set(tree.level_counts)
-                    tp_t = tp_t.at[0].set(tree.tail_psums)
+                    tl_t = tl_t.at[0].set(tree.tail_counts)
                 else:
                     # K trees per iteration from one gradient pass; each
                     # class's delta lands on its score row immediately
@@ -1228,13 +1240,13 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                         ns_t = ns_t.at[k].set(tree.num_splits)
                         raw_t = raw_t.at[k].set(tree.recs_raw)
                         lv_t = lv_t.at[k].set(tree.level_counts)
-                        tp_t = tp_t.at[k].set(tree.tail_psums)
+                        tl_t = tl_t.at[k].set(tree.tail_counts)
 
                 recs = {
                     "num_splits": recs["num_splits"].at[t].set(ns_t),
                     "raw": recs["raw"].at[t].set(raw_t),
                     "levels": recs["levels"].at[t].set(lv_t),
-                    "tail_psums": recs["tail_psums"].at[t].set(tp_t),
+                    "tail": recs["tail"].at[t].set(tl_t),
                 }
                 return (t + 1, ~any_split, p, recs, delta, last_kept)
 
@@ -1246,10 +1258,11 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                 # the rows they streamed (one shard's, in the sharded program),
                 # the segments they partitioned, the slots the search visited
                 "levels": jnp.zeros((T, K, 4), jnp.int32),
-                # per tree: replayed splits whose children were all-reduced
-                # (the tail); with `levels` and the root's, every histogram
-                # all-reduce the program issued
-                "tail_psums": jnp.zeros((T, K), jnp.int32),
+                # per tree (PTreeResult.tail_counts): the replayed splits that
+                # took the tail, each all-reducing its children (with `levels`
+                # and the root's, every histogram all-reduce the program
+                # issued), and the rows of THIS shard their passes streamed
+                "tail": jnp.zeros((T, K, 2), jnp.int32),
             }
             # (t, stopped, p, recs, pending delta, last kept delta)
             state0 = (jnp.int32(0), jnp.array(False), p, recs0,
@@ -1278,7 +1291,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         mapped = self._shard_map(
             shard_body,
             (P("data"), P("data"), P(), P(), P(), P()),
-            (P("data"), {"num_splits": P(), "raw": P(), "levels": P(), "tail_psums": P()},
+            (P("data"), {"num_splits": P(), "raw": P(), "levels": P(), "tail": P()},
              P(None, "data"), P("data")),
         )
         return jax.jit(mapped, donate_argnums=(0,))
@@ -1370,14 +1383,14 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         call an iteration), a level's ``(level_slots, 16, hist_lanes)`` and
         a tail split's ``(6, hist_lanes)``.  How many levels and tail splits
         a tree took only the device program knows: ``recs["levels"]`` and
-        ``recs["tail_psums"]``."""
+        ``recs["tail"]``."""
         out = super().stream_counts(recs_np, n_done)
         cols = self.params.num_cols or self.params.num_features
         bins = self.params.num_bins_hist or self.params.num_bins
         root = 4 * cols * bins * 3
         level = 4 * level_slots(self.params.num_leaves) * 16 * out["hist_cells"]
         tail = 4 * 6 * out["hist_cells"]
-        tails = int(recs_np["tail_psums"][:n_done].sum())
+        tails = out["tail_splits"]
         out.update(
             shards=self.d,
             allreduce_calls=n_done + out["levels"] + tails,
@@ -1432,7 +1445,7 @@ def eligible(config, train_set, objective, num_tree_per_iteration: int) -> bool:
     # across processes — their per-node exchanges don't fuse.
     if config.tree_learner not in ("serial", "data"):
         return False
-    if np.asarray(train_set.binned).dtype != np.uint8:
+    if train_set.bin_dtype != np.uint8:
         return False
     if train_set.max_num_bin > 256:
         return False

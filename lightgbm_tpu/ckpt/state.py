@@ -112,6 +112,15 @@ def config_fingerprint(config) -> str:
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 
 
+def _resident_matrix(binned_ds) -> np.ndarray:
+    """What identifies the dataset's rows: its per-feature bins or, for a
+    dataset made from sparse input, the bundle columns it holds in their
+    place (fingerprinting must not decode an ``(N, F)`` matrix)."""
+    if getattr(binned_ds, "has_dense_bins", True):
+        return np.asarray(binned_ds.binned)
+    return np.asarray(binned_ds.bundled)
+
+
 def data_fingerprint(binned_ds) -> str:
     """Digest of the constructed dataset (binned matrix + label).  CRC32
     keeps this cheap even at large N; cached on the dataset object so
@@ -119,7 +128,7 @@ def data_fingerprint(binned_ds) -> str:
     cached = getattr(binned_ds, "_ckpt_fingerprint", None)
     if cached is not None:
         return cached
-    binned = np.asarray(binned_ds.binned)
+    binned = _resident_matrix(binned_ds)
     # block-wise CRC: chunked zlib.crc32 equals the whole-buffer value,
     # and never materializes a memmapped (out-of-core) matrix
     crc = 0
@@ -199,7 +208,7 @@ def data_fingerprint_parts(binned_ds) -> Dict[str, int]:
     cached = getattr(binned_ds, "_ckpt_fp_parts", None)
     if cached is not None:
         return dict(cached)
-    binned = np.asarray(binned_ds.binned)
+    binned = _resident_matrix(binned_ds)
     crc_b = 0
     step = 65536
     for s in range(0, binned.shape[0], step):

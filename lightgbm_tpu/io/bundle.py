@@ -7,7 +7,12 @@ columns; bundling packs mutually-(almost-)exclusive features into one
 dense column so histogram and partition cost scale with the number of
 BUNDLES, not features — the memory/compute win the reference gets from
 sparse bins, in the dense form the TPU MXU rewards (see README's sparse
-storage decision).
+storage decision).  Two ways in: ``find_bundles`` /
+``build_bundled_matrix`` read a dense ``(N, F)`` bin matrix (lazily, when
+the fused trainer is eligible), and io/sparse.py reaches the same
+``bundles_from_masks`` from a scipy matrix's column index sets and writes
+the bundled matrix from its rows, byte for byte the same, with no
+``(N, F)`` array on the way.
 
 Bundle bin layout (feature_group.h:34-48, PushData :128-136):
     bin 0            : every feature at its default bin
@@ -103,28 +108,44 @@ def _find_groups(nonzero: List[np.ndarray], order: np.ndarray,
     return groups
 
 
+def bundle_sample_rows(n: int, config) -> np.ndarray:
+    """The rows the conflict search looks at (in the order drawn)."""
+    sample_cnt = min(n, int(getattr(config, "bin_construct_sample_cnt", 200000)))
+    rng = np.random.RandomState(getattr(config, "data_random_seed", 1))
+    return rng.choice(n, size=sample_cnt, replace=False) if sample_cnt < n else np.arange(n)
+
+
+def pack_mask(mask: np.ndarray) -> np.ndarray:
+    """A bool mask as uint64 words, for fast AND+popcount conflict tests."""
+    packed = np.packbits(mask).view(np.uint8)
+    return np.pad(packed, (0, (-len(packed)) % 8)).view(np.uint64)
+
+
 def find_bundles(binned: np.ndarray, mappers, config) -> Optional[BundleInfo]:
     """FastFeatureBundling (dataset.cpp:136-208) over the binned matrix.
 
-    Returns None when bundling gains nothing (G == F) or is disabled."""
+    Returns None when bundling gains nothing (G == F) or is disabled.
+    (io/sparse.py reaches ``bundles_from_masks`` from column index sets,
+    with no binned matrix.)"""
     n, f = binned.shape
     if f < 2:
         return None
-    sample_cnt = min(n, int(getattr(config, "bin_construct_sample_cnt", 200000)))
-    rng = np.random.RandomState(getattr(config, "data_random_seed", 1))
-    rows = rng.choice(n, size=sample_cnt, replace=False) if sample_cnt < n else np.arange(n)
-    sub = binned[rows]
+    sub = binned[bundle_sample_rows(n, config)]
+    nonzero_b = [sub[:, i] != mappers[i].default_bin for i in range(f)]
+    nz_cnt = np.asarray([int(m.sum()) for m in nonzero_b])
+    return bundles_from_masks([pack_mask(m) for m in nonzero_b], nz_cnt, len(sub),
+                              mappers, config)
 
+
+def bundles_from_masks(nonzero: List[np.ndarray], nz_cnt: np.ndarray, sample_cnt: int,
+                       mappers, config) -> Optional[BundleInfo]:
+    """The grouping and the bundle layout from each feature's packed mask
+    of sampled rows whose bin is not its default (``pack_mask``), the
+    masks' counts and the sample's size.  None when nothing bundles."""
+    f = len(mappers)
     default_bin = np.asarray([m.default_bin for m in mappers], np.int64)
     num_bin = np.asarray([m.num_bin for m in mappers], np.int64)
     default0 = default_bin == 0
-
-    nonzero_b = [sub[:, i] != default_bin[i] for i in range(f)]
-    nz_cnt = np.asarray([int(m.sum()) for m in nonzero_b])
-    # pack to uint64 words for fast AND+popcount conflict tests
-    nonzero = [np.packbits(m).view(np.uint8) for m in nonzero_b]
-    pad = (-len(nonzero[0])) % 8
-    nonzero = [np.pad(m, (0, pad)).view(np.uint64) for m in nonzero]
     max_error_cnt = int(sample_cnt * float(getattr(config, "max_conflict_rate", 0.0)))
 
     natural = np.arange(f)
@@ -195,9 +216,17 @@ def build_bundled_matrix(binned: np.ndarray, mappers, info: BundleInfo) -> np.nd
     return out
 
 
+def decode_bundled(bundled: np.ndarray, info: BundleInfo, mappers, out: np.ndarray) -> None:
+    """``out[:] = `` the per-feature bins of the bundled rows (every column
+    through ``decode_bundled_column``): what a consumer that works by
+    feature gets from a dataset that holds its bundles alone."""
+    for fe, m in enumerate(mappers):
+        out[:, fe] = decode_bundled_column(bundled[:, info.col[fe]], fe, info, m.default_bin)
+
+
 def decode_bundled_column(colv: np.ndarray, fe: int, info: BundleInfo, default_bin: int) -> np.ndarray:
-    """Recover feature fe's bin from its bundle column (test helper —
-    exact except where another feature's conflict overwrote the slot)."""
+    """Recover feature fe's bin from its bundle column (exact except where
+    another feature's conflict overwrote the slot)."""
     lo, hi, bias = int(info.off_lo[fe]), int(info.off_hi[fe]), int(info.bias[fe])
     v = colv.astype(np.int32)
     if lo == 0:  # singleton raw column

@@ -3,12 +3,19 @@
 
 TPU-first design: instead of per-feature-group Bin objects (dense /
 sparse / 4-bit / ordered variants, feature_group.h), the whole dataset is
-ONE dense row-major ``(N, F)`` uint8/uint16 matrix of bin indices that is
-transferred to HBM once and stays resident.  Histogram construction over it
-is a single XLA/Pallas kernel (ops/histogram.py) rather than per-group
-virtual dispatch.  Sparse/EFB storage optimizations are deliberately
-deferred: on TPU, dense with ``sparse_threshold=1.0`` is the recommended
-configuration in the reference's own GPU docs (docs/GPU-Performance.md:112).
+ONE dense row-major matrix of bin indices that is transferred to HBM once
+and stays resident: ``(N, F)`` uint8/uint16 per-feature bins, or, where
+EFB finds exclusive features (io/bundle.py), the ``(N, G)`` uint8 matrix
+of its bundles.  Histogram construction over it is a single XLA/Pallas
+kernel (ops/histogram.py) rather than per-group virtual dispatch.
+Storage stays dense on purpose: on TPU, dense with
+``sparse_threshold=1.0`` is the recommended configuration in the
+reference's own GPU docs (docs/GPU-Performance.md:112).  What may be
+sparse is the INPUT: a scipy CSR/CSC table goes through io/sparse.py
+(``from_sparse``) to its mappers, bundles and bundled matrix without
+being densified, and the dataset then holds ``bundled`` and no ``binned``;
+a consumer that works by feature (the mask grower, a checkpoint's
+fingerprint) gets ``binned`` decoded from the bundles when it asks.
 
 Parity notes:
 - trivial-feature filtering and used-feature mapping ↔ Dataset::Construct
@@ -89,14 +96,16 @@ class BinnedDataset:
     Attributes
     ----------
     binned : (num_data, num_used_features) np.uint8 or np.uint16
-        Bin index of each (row, used-feature).
+        Bin index of each (row, used-feature).  A dataset made from sparse
+        input holds its bundles alone (``has_dense_bins`` is False) and
+        decodes this matrix, once, for whoever reads it.
     bin_mappers : list[BinMapper], one per used feature.
     used_feature_map : original feature index of each used feature.
     num_total_features : raw feature count before trivial filtering.
     """
 
     def __init__(self):
-        self.binned: np.ndarray = np.zeros((0, 0), dtype=np.uint8)
+        self._binned: Optional[np.ndarray] = np.zeros((0, 0), dtype=np.uint8)
         self.bin_mappers: List[BinMapper] = []
         self.used_feature_map: np.ndarray = np.array([], dtype=np.int32)
         self.num_total_features: int = 0
@@ -106,6 +115,7 @@ class BinnedDataset:
         self.label_idx: int = 0
         self.bundle = None  # EFB BundleInfo (io/bundle.py); None = unbundled
         self.bundled: Optional[np.ndarray] = None  # (N, G) uint8 bundle bins
+        self.bundle_conflicts = 0  # entries a later feature of their bundle overwrote
         # set when loaded from a v2 binary cache: the out-of-core trainer
         # streams checksummed chunks straight from this file
         self.cache_path: Optional[str] = None
@@ -114,13 +124,46 @@ class BinnedDataset:
 
     # ------------------------------------------------------------------
     @property
+    def has_dense_bins(self) -> bool:
+        """Whether the ``(N, F)`` per-feature bins exist on the host."""
+        return self._binned is not None
+
+    @property
+    def binned(self) -> np.ndarray:
+        if self._binned is None:
+            # made from sparse input: the bundles are all there is.  Exact
+            # but for the cells a conflict overwrote (bundle_conflicts).
+            from .bundle import decode_bundled
+
+            n, f = self.num_data, self.num_features
+            Log.warning("decoding the (%d, %d) per-feature bins from %d bundle columns: a "
+                        "consumer that works by feature asked for them%s", n, f,
+                        self.bundle.num_cols,
+                        f" ({self.bundle_conflicts} cells lost to bundle conflicts)"
+                        if self.bundle_conflicts else "")
+            out = np.empty((n, f), np.uint8)
+            _map_threads(lambda lo: decode_bundled(
+                self.bundled[lo:lo + _DECODE_BLOCK_ROWS], self.bundle, self.bin_mappers,
+                out[lo:lo + _DECODE_BLOCK_ROWS]), range(0, n, _DECODE_BLOCK_ROWS))
+            self._binned = out
+        return self._binned
+
+    @binned.setter
+    def binned(self, value: np.ndarray) -> None:
+        self._binned = value
+
+    @property
+    def bin_dtype(self):
+        return np.dtype(np.uint8) if self._binned is None else self._binned.dtype
+
+    @property
     def num_data(self) -> int:
-        return self.binned.shape[0]
+        return (self.bundled if self._binned is None else self._binned).shape[0]
 
     @property
     def num_features(self) -> int:
         """Number of used (non-trivial) features."""
-        return self.binned.shape[1]
+        return len(self.bin_mappers) if self._binned is None else self._binned.shape[1]
 
     def num_bin(self, fidx: int) -> int:
         return self.bin_mappers[fidx].num_bin
@@ -136,6 +179,29 @@ class BinnedDataset:
         return int(self.used_feature_map[fidx])
 
     # ------------------------------------------------------------------
+    @classmethod
+    def _shell(cls, n, num_features, config, label, weight, group, init_score,
+               feature_names, reference) -> "BinnedDataset":
+        """A dataset of ``n`` rows with its metadata and names and no bins
+        yet; what is the reference's (a validation set's) comes from it."""
+        ds = cls()
+        ds.metadata = Metadata(n)
+        if label is not None:
+            ds.metadata.set_label(label)
+        ds.metadata.set_weights(weight)
+        ds.metadata.set_query(group)
+        ds.metadata.set_init_score(init_score)
+        if reference is not None:
+            ds.num_total_features = reference.num_total_features
+            ds.feature_names = reference.feature_names
+            ds.max_bin = reference.max_bin
+        else:
+            ds.num_total_features = num_features
+            ds.feature_names = list(feature_names) if feature_names else [
+                f"Column_{i}" for i in range(num_features)]
+            ds.max_bin = config.max_bin
+        return ds
+
     @classmethod
     def from_raw(
         cls,
@@ -168,25 +234,12 @@ class BinnedDataset:
         if data.ndim != 2:
             Log.fatal("data must be 2-dimensional")
         n, num_features = data.shape
-        ds = cls()
-        ds.num_total_features = num_features
-        ds.max_bin = config.max_bin
-        ds.metadata = Metadata(n)
-        if label is not None:
-            ds.metadata.set_label(label)
-        ds.metadata.set_weights(weight)
-        ds.metadata.set_query(group)
-        ds.metadata.set_init_score(init_score)
-        ds.feature_names = list(feature_names) if feature_names else [
-            f"Column_{i}" for i in range(num_features)
-        ]
+        ds = cls._shell(n, num_features, config, label, weight, group, init_score,
+                        feature_names, reference)
 
         if reference is not None:
             ds.bin_mappers = reference.bin_mappers
             ds.used_feature_map = reference.used_feature_map
-            ds.num_total_features = reference.num_total_features
-            ds.feature_names = reference.feature_names
-            ds.max_bin = reference.max_bin
         else:
             cat_set = set(int(c) for c in categorical_features) if categorical_features else set()
             mappers = _find_bin_mappers_distributed(data, config, cat_set, sample_indices)
@@ -197,6 +250,41 @@ class BinnedDataset:
             ds.used_feature_map = np.asarray(used, dtype=np.int32)
 
         ds.binned = _bin_matrix(data, ds.bin_mappers, ds.used_feature_map)
+        return ds
+
+    @classmethod
+    def from_sparse(
+        cls,
+        data,
+        config: Config,
+        *,
+        label: Optional[Sequence[float]] = None,
+        weight: Optional[Sequence[float]] = None,
+        group: Optional[Sequence[int]] = None,
+        init_score: Optional[Sequence[float]] = None,
+        feature_names: Optional[List[str]] = None,
+        categorical_features: Optional[Sequence[int]] = None,
+        reference: Optional["BinnedDataset"] = None,
+        sample_indices: Optional[np.ndarray] = None,
+    ) -> "BinnedDataset":
+        """Construct from a scipy CSR/CSC matrix without densifying it
+        (io/sparse.py): the mappers, bundles and matrix ``from_raw`` and
+        ``ensure_bundles`` give the densified table, byte for byte.  Where
+        EFB finds bundles the dataset holds ``bundled`` and no ``binned``;
+        bundling is decided here and not lazily, because the alternative
+        is the ``(N, F)`` matrix this path exists to avoid.  With
+        ``reference`` (a validation set) the per-feature bins are built."""
+        from . import sparse
+
+        csr = sparse.to_csr(data)
+        ds = cls._shell(*csr.shape, config, label, weight, group, init_score, feature_names,
+                        reference)
+        (ds.bin_mappers, ds.used_feature_map, ds._binned, ds.bundled, ds.bundle,
+         ds.bundle_conflicts) = sparse.ingest(
+            csr, config, categorical=set(int(c) for c in categorical_features or ()),
+            sample_indices=sample_indices, reference=reference,
+            bundle=bool(getattr(config, "enable_bundle", True)))
+        ds._bundle_checked = True
         return ds
 
     def ensure_bundles(self, config) -> None:
@@ -227,7 +315,11 @@ class BinnedDataset:
         """Row subset sharing bin mappers (Dataset::CopySubset)."""
         indices = np.asarray(indices)
         ds = BinnedDataset()
-        ds.binned = self.binned[indices]
+        if self._binned is None:  # made from sparse input: the bundles are the rows
+            ds._binned, ds.bundled, ds.bundle = None, self.bundled[indices], self.bundle
+            ds._bundle_checked = True
+        else:
+            ds.binned = self.binned[indices]
         ds.bin_mappers = self.bin_mappers
         ds.used_feature_map = self.used_feature_map
         ds.num_total_features = self.num_total_features
@@ -272,18 +364,27 @@ class BinnedDataset:
         into them (data/cache.py).  The ``__cache_meta__`` header records
         the format version, per-block CRCs and — when ``source_path`` is
         given — the source file's identity, so a cache that no longer
-        matches its source is refused instead of silently trusted."""
+        matches its source is refused instead of silently trusted.
+
+        A dataset made from sparse input stores its bundled matrix and
+        ``BundleInfo`` in place of ``binned`` (header ``layout: bundled``;
+        the CRCs are the bundled matrix's)."""
         from ..data.cache import build_cache_meta, chunk_crcs
 
-        meta = build_cache_meta(self.binned, self.metadata.label,
+        dense = self._binned is not None
+        matrix = self._binned if dense else self.bundled
+        meta = build_cache_meta(matrix, self.metadata.label,
                                 source_path=source_path)
         import json
 
+        if not dense:
+            meta["layout"] = "bundled"
         payload: Dict[str, np.ndarray] = {
             "magic": np.asarray(_BINARY_MAGIC),
             "__cache_meta__": np.asarray(json.dumps(meta)),
-            "chunk_crc": chunk_crcs(self.binned),
-            "binned": self.binned,
+            "chunk_crc": chunk_crcs(matrix),
+            **({"binned": matrix} if dense else
+               {"bundled": matrix, **_bundle_state(self.bundle, self.bundle_conflicts)}),
             "used_feature_map": self.used_feature_map,
             "num_total_features": np.asarray(self.num_total_features),
             "feature_names": np.asarray(self.feature_names),
@@ -366,18 +467,22 @@ class BinnedDataset:
             # prefer a read-only memmap of the stored matrix: demand-paged
             # host residency, and the out-of-core trainer can stream
             # checksummed chunks straight from the same file
-            reader = open_cache_reader(path)
+            reader = open_cache_reader(path) if "binned" in z.files else None
             if reader is not None:
                 ds.binned = reader.memmap()
                 ds.cache_path = path
                 reader.close()
-            else:
+            elif "binned" in z.files:
                 ds.binned = z["binned"]
+            else:  # made from sparse input: the bundles alone
+                ds._binned, ds.bundled = None, z["bundled"]
+                ds.bundle, ds.bundle_conflicts = _bundle_from_state(z)
+                ds._bundle_checked = True
             ds.used_feature_map = z["used_feature_map"]
             ds.num_total_features = int(z["num_total_features"])
             ds.feature_names = [str(s) for s in z["feature_names"]]
             ds.max_bin = int(z["max_bin"])
-            ds.metadata = Metadata(ds.binned.shape[0])
+            ds.metadata = Metadata(ds.num_data)
             ds.metadata.set_label(z["label"])
             if "weights" in z:
                 ds.metadata.set_weights(z["weights"])
@@ -407,11 +512,35 @@ class BinnedDataset:
 
 
 # ----------------------------------------------------------------------
+_BUNDLE_FIELDS = ("col", "off_lo", "off_hi", "bias", "num_bin_col")
+
+
+def _bundle_state(info, conflicts: int) -> Dict[str, np.ndarray]:
+    """A ``BundleInfo`` as arrays of the binary cache."""
+    out = {f"bundle_{k}": np.asarray(getattr(info, k)) for k in _BUNDLE_FIELDS}
+    out["bundle_group_sizes"] = np.asarray([len(g) for g in info.groups], np.int32)
+    out["bundle_groups"] = np.asarray([fe for g in info.groups for fe in g], np.int32)
+    out["bundle_conflicts"] = np.asarray(int(conflicts))
+    return out
+
+
+def _bundle_from_state(z):
+    from .bundle import BundleInfo
+
+    flat = z["bundle_groups"].tolist()
+    ends = np.cumsum(z["bundle_group_sizes"]).tolist()
+    groups = [flat[lo:hi] for lo, hi in zip([0] + ends[:-1], ends)]
+    fields = {k: z[f"bundle_{k}"] for k in _BUNDLE_FIELDS}
+    info = BundleInfo(groups=groups, max_col_bin=int(fields["num_bin_col"].max()), **fields)
+    return info, int(z["bundle_conflicts"])
+
+
 def _find_bin_mappers_distributed(
     data: np.ndarray,
     config: Config,
     categorical: set,
     sample_indices: Optional[np.ndarray],
+    find=None,
 ) -> List[BinMapper]:
     """Distributed find-bin (dataset_loader.cpp:733-835): in a
     multi-process runtime each process finds bins only for its contiguous
@@ -420,16 +549,19 @@ def _find_bin_mappers_distributed(
     allgathered so every process ends with the identical full list.  The
     reference's max_bin Allreduce exists only to size its fixed-width
     copy buffers; here the pickled states are length-prefixed instead.
-    Falls through to the single-process path otherwise."""
+    Falls through to the single-process path otherwise.  ``find`` is the
+    single-process finder: ``_find_bin_mappers`` for a dense table,
+    io/sparse.py's for a CSR one (both slice by column)."""
+    find = find or _find_bin_mappers
     if not getattr(config, "is_parallel_find_bin", False):
-        return _find_bin_mappers(data, config, categorical, sample_indices)
+        return find(data, config, categorical, sample_indices)
 
     import jax
 
     from ..parallel.distributed import ensure_initialized
 
     if not ensure_initialized(config):
-        return _find_bin_mappers(data, config, categorical, sample_indices)
+        return find(data, config, categorical, sample_indices)
 
     import pickle
 
@@ -444,7 +576,7 @@ def _find_bin_mappers_distributed(
 
     local_cats = {c - start for c in categorical if start <= c < stop}
     if stop > start:
-        local = _find_bin_mappers(data[:, start:stop], config, local_cats, sample_indices)
+        local = find(data[:, start:stop], config, local_cats, sample_indices)
     else:
         local = []
     blobs = [pickle.dumps(m.state()) for m in local]
@@ -513,6 +645,7 @@ def find_bin_mappers_from_sample(
 
 _HOST_THREADS = 8
 _BIN_BLOCK_ROWS = 1 << 14
+_DECODE_BLOCK_ROWS = 1 << 18
 
 
 def _map_threads(fn, items) -> list:

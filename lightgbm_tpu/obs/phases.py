@@ -35,11 +35,28 @@ REPLAY_TAIL = "replay_tail"          # inside replay: split_stream, once a repla
 LEAF_DELTA = "leaf_delta"            # segment values -> per-row score delta
 SCORE_ADD = "score_add"              # a class's delta onto its score row (K > 1)
 CHUNK_EPILOGUE = "chunk_epilogue"    # settle the last delta, scores to original order
+# Opened by ops/pgrow._expand_bundle_hist, and so only by a program that
+# streams EFB bundles, inside whichever phase searches a histogram: the
+# root's (update_root_hist), a level's (split_scan) or a tail split's
+# (replay).  One word cannot be enclosed by three, so `phase_of` keys such an
+# instruction "<that phase>/bundle_expand" (`nested`), and ENCLOSING hands each
+# key to the phase whose readers should still count it.
+BUNDLE_EXPAND = "bundle_expand"      # (G, BH, 3) bundle histograms -> (F, B, 3) per feature
 
 PHASES = (CANON_REORDER, SAMPLE, UPDATE_ROOT_HIST, LEVEL_PHASE, SPLIT_SCAN, REPLAY,
-          REPLAY_TAIL, LEAF_DELTA, SCORE_ADD, CHUNK_EPILOGUE)
-# a phase that only ever sits inside another: readers of the outer one add it
-ENCLOSING = {REPLAY_TAIL: REPLAY, SPLIT_SCAN: LEVEL_PHASE}
+          REPLAY_TAIL, LEAF_DELTA, SCORE_ADD, CHUNK_EPILOGUE, BUNDLE_EXPAND)
+
+
+def nested(outer: str) -> str:
+    return f"{outer}/{BUNDLE_EXPAND}"
+
+
+# a phase that only ever sits inside another: readers of the outer one add it.
+# A reader's sum is one level deep, so a level's bundle expansion goes to
+# `level_phase` (whose total stays whole) and `split_scan` reads the search alone.
+ENCLOSING = {REPLAY_TAIL: REPLAY, SPLIT_SCAN: LEVEL_PHASE,
+             nested(SPLIT_SCAN): LEVEL_PHASE, nested(REPLAY): REPLAY,
+             nested(UPDATE_ROOT_HIST): UPDATE_ROOT_HIST}
 
 _COMPUTATION = re.compile(r"^(ENTRY )?%(\S+) \(.*\) -> .*\{$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(")
@@ -48,22 +65,29 @@ _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 _CALLED = re.compile(r"\b(?:condition|body|to_apply|true_computation|false_computation)=%([^\s,)}]+)")
 _BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
 _DIMS = re.compile(r"\[([\d,]*)\]")
+_VMAPPED = re.compile(r"^(?:vmap\()+(\w+)\)+$")
 # instructions that hand launches to other computations of the module
 _CONTROL = ("while", "conditional", "call")
 
 
 def phase_of(op_name: str) -> Optional[str]:
-    """The innermost vocabulary word on an ``op_name`` path, or None.  A
+    """The innermost vocabulary word on an ``op_name`` path, or None; where
+    that word is ``bundle_expand``, the word around it with it
+    (``split_scan/bundle_expand``).  A
     Pallas kernel's own name (the component before ``pallas_call``) is a
     name, not a scope: ``chunk_epilogue/jit(score_add)/score_add/pallas_call``
     is the epilogue's."""
     parts = op_name.split("/")
     if parts[-1] == "pallas_call":
         parts = parts[:-2]
-    for part in reversed(parts):
-        if part in PHASES:
-            return part
-    return None
+    # a scope opened under jax.vmap reads "vmap(<word>)", once a level of vmap
+    words = [w for w in (_VMAPPED.sub(r"\1", part) for part in parts) if w in PHASES]
+    if not words:
+        return None
+    if words[-1] == BUNDLE_EXPAND:
+        outer = [w for w in words if w != BUNDLE_EXPAND]
+        return nested(outer[-1]) if outer else BUNDLE_EXPAND
+    return words[-1]
 
 
 def _elements(shape: str) -> int:

@@ -35,6 +35,18 @@ def _toy(n=300, f=4, seed=0):
     return X, (X[:, 0] + X[:, 1] > 0).astype(float), rng.randint(0, 3, n).astype(float)
 
 
+def _one_hot_csr(n=300, fields=(5, 7, 4), seed=0):
+    """Three categorical fields one-hot encoded, as scipy CSR: EFB bundles each
+    field's columns, so the fused trainer streams bundles."""
+    import scipy.sparse
+
+    rng = np.random.RandomState(seed)
+    cols = np.stack([rng.randint(0, c, n) for c in fields], 1) + np.cumsum((0,) + fields[:-1])
+    return scipy.sparse.csr_matrix(
+        (np.ones(cols.size), cols.reshape(-1), np.arange(0, cols.size + 1, len(fields))),
+        shape=(n, sum(fields)))
+
+
 # -- the names inside the programs -------------------------------------------
 def _lowered_chunk_program(params, y, X):
     """StableHLO text, with locations, of the fused chunk program for this
@@ -64,6 +76,8 @@ def lowered():
                  "feature_fraction": 0.75}, yb, X),
             "multiclass": _lowered_chunk_program(
                 {**base, "objective": "multiclass", "num_class": 3}, ym, X),
+            "bundled": _lowered_chunk_program(
+                {**base, "objective": "binary", "min_data_in_leaf": 1}, yb, _one_hot_csr()),
         }
     finally:
         if old is None:
@@ -81,10 +95,19 @@ def test_chunk_program_carries_every_phase_word(lowered, phase):
     """Every word of the vocabulary is a scope of the lowered chunk program
     (`score_add` as a scope of its own only where K > 1 lands deltas in the
     loop), but for `canon_reorder`: the word stays for the benchmark's reader,
-    and since PR 30 no program, bagged binary or multiclass, opens it."""
+    and since PR 30 no program, bagged binary or multiclass, opens it.
+    `bundle_expand` is a scope of a program that streams EFB bundles alone,
+    keyed with the phase it sits in: the root's search, a level's, the tail's."""
     if phase == "canon_reorder":
         for text in lowered.values():
             assert phase not in {phase_of(n) for n in _loc_names(text)}
+        return
+    if phase == "bundle_expand":
+        assert not any("bundle_expand" in n for n in _loc_names(lowered["binary"]))
+        keys = {phase_of(n) for n in _loc_names(lowered["bundled"])}
+        assert {"update_root_hist/bundle_expand", "split_scan/bundle_expand",
+                "replay/bundle_expand"} <= keys and "bundle_expand" not in keys
+        assert all(ENCLOSING[k] in PHASES for k in keys if k and "/" in k)
         return
     text = lowered["multiclass" if phase == "score_add" else "binary"]
     if phase == "score_add":
@@ -201,8 +224,14 @@ def test_parser_on_text_without_a_module():
 
 
 def test_vocabulary_is_flat_and_enclosing_is_inside_it():
-    assert len(set(PHASES)) == len(PHASES) == 10
-    assert all(inner in PHASES and outer in PHASES for inner, outer in ENCLOSING.items())
+    assert len(set(PHASES)) == len(PHASES) == 11
+    # an inner phase is a word, or `bundle_expand` keyed with the word around it
+    assert all(outer in PHASES and all(w in PHASES for w in inner.split("/"))
+               and inner.split("/")[1:] in ([], ["bundle_expand"])
+               for inner, outer in ENCLOSING.items())
+    assert phase_of("jit(prog)/while/body/level_phase/while/body/split_scan/vmap()/"
+                    "bundle_expand/gather") == "split_scan/bundle_expand"
+    assert phase_of("jit(f)/bundle_expand/gather") == "bundle_expand"
 
 
 # -- JitWatch.phase_map -------------------------------------------------------

@@ -181,10 +181,12 @@ def test_module_and_matrix(phase_map):
 
 
 @pytest.mark.parametrize("phase", [p for p in PHASES
-                                   if p not in ("canon_reorder", "sample", "score_add")])
+                                   if p not in ("canon_reorder", "sample", "score_add",
+                                                "bundle_expand")])
 def test_the_compiler_keeps_the_phase(phase_map, phase):
     """Every phase the cells' program runs (it draws no sample, lands no
-    per-class delta and, since PR 30, reorders nothing at a tree's start) still
+    per-class delta, streams no bundle and, since PR 30, reorders nothing at a
+    tree's start) still
     owns instructions after XLA's passes."""
     assert phase in phase_map["ops"].values()
 
