@@ -111,6 +111,7 @@ from ..ops.pkernels import (
     col_groups,
     hist_lanes,
     level_stream_vmem_bytes,
+    perm_tiles,
     pack_matrix_device,
     score_add,
     update_and_root_hist,
@@ -658,7 +659,9 @@ class PartitionedTrainer:
         ``hist_cells`` (lanes of one leaf's histogram row as the kernels
         issue it, padding included), ``channels`` (rows of the packed
         matrix) and ``col_groups`` (column groups a kernel walks a block
-        in: 1 up to 31 columns), ``bundle_cols`` (EFB bundle columns the
+        in: 1 up to 31 columns), ``perm_tiles`` (one-hot tiles one block's
+        compaction and row count multiply: 33 of the 192 a dense permutation
+        takes), ``bundle_cols`` (EFB bundle columns the
         matrix holds in place of the features; 0 unbundled); ``tail_splits``
         and ``tail_rows`` (the replayed splits that took the classic
         ``split_stream`` tail and the rows of their parents' segments,
@@ -670,6 +673,7 @@ class PartitionedTrainer:
         bins = self.params.num_bins_hist or self.params.num_bins
         out = {"hist_cells": hist_lanes(cols, bins), "channels": self.layout.C,
                "col_groups": col_groups(cols, self.params.bits).count,
+               "perm_tiles": perm_tiles(),
                "bundle_cols": self.params.num_cols,
                "shards": 1, "allreduce_calls": 0, "allreduce_bytes": 0}
         out.update(zip(("levels", "level_rows", "level_segments", "scan_slots"),
@@ -1457,9 +1461,9 @@ def eligible(config, train_set, objective, num_tree_per_iteration: int) -> bool:
     # program size does not grow with the columns) and ask Mosaic for the
     # VMEM their whole-block buffers and histogram rows need, so K == 1
     # rides at any width that VMEM holds: at 2,000 columns x 63 bins
-    # level_stream holds 12 (512, BLK) blocks and two (16, 128,000)
-    # histograms, 42 MB of the v5e's 128 MiB.  The ceiling that is left is
-    # that budget.  Multiclass keeps the old 512 columns: its update
+    # level_stream holds 12 (512, BLK) blocks, two (16, 128,000)
+    # histograms and its compaction's 2 MB, 44 MB of the v5e's 128 MiB.
+    # The ceiling that is left is that budget.  Multiclass keeps the old 512 columns: its update
     # kernel holds 6K+1 histogram rows of ALL columns at once (53 MB at
     # K = 16 and 2,000 columns, twice with its output block).
     bundle = getattr(train_set, "bundle", None)

@@ -22,8 +22,8 @@ DataPartition asymptotics (O(N_leaf) per split, not O(N)) without any
 gather (streaming DMA + MXU against a row gather; neither rate is
 measured on this machine).
 
-Two backend observations shape this file (made on a v5e under a retired
-runtime; not re-measured on this machine):
+Two backend observations shape this file (the first made on a v5e under a
+retired runtime and re-met in PR 27, the second measured in PR 37):
   1. ANY XLA-level write to the 64 MB packed matrix — even a one-element
      `.at[0,0].add(1)` on a donated loop carry — triggered a whole-array
      copy.  Only Pallas kernels with ``input_output_aliases`` mutate it
@@ -44,13 +44,23 @@ runtime; not re-measured on this machine):
      ``score_add`` stream only the 8-aligned mutable band for
      score/gradient maintenance — the bin words are never re-read or
      re-written by a pass that doesn't need them.
-  2. The kernels are VPU-compute-bound, not HBM-bound: the (B, BLK)
-     bin-equality one-hots and the (BLK, BLK) permutation one-hots cost
-     ~1 us per 64 compares/lane-block, while the DMA itself is tens of
-     GB/s.  So histogram work is fused INTO the partition pass
+  2. The streaming kernels wait for the MXU to take in one-hot WEIGHT
+     TILES, not for HBM and not for the compares that build them (v5e,
+     PR 37, ``level_stream`` alone on a 21M x 16 matrix).  A 1,024-row
+     block's 128 KB in and out are 0.16 us of HBM; the block took 5.45 us:
+     1.45 for the histogram's 112 tiles of 128 x 128 (14 streamed rows
+     each), 3.35 for the 192 tiles of a dense (BLK, BLK) permutation and
+     running count (~105 cycles a tile on each of four MXUs, whatever the
+     tile holds), 0.65 for the rest (DMA waits, the split predicate, the
+     merge under the carries, the stage copies).  So the compaction
+     multiplies only the tiles its one-hot can be non-zero in
+     (``_staircase``: 33 tiles, 0.76 us, of it 0.12 the count, the 16
+     window offsets that leave the vector unit as scalars and the
+     one-hots' compares) and a block takes 2.84 us, half of it the
+     histogram's.  Histogram work is fused INTO the partition pass
      (``split_stream``): the partition must stream the parent segment
-     anyway, and adding both children's histograms only widens the MXU
-     operand from 7 to 14 sublanes — free on a 128-wide systolic array.
+     anyway, and both children's histograms only widen the MXU operand
+     from 7 to 14 sublanes: the same weight tiles.
 
 Width (PR 29).  Every kernel here is ``grid=(1,)`` with hand-written DMA
 over whole (C, BLK) blocks, and a block is 2 MB at 2,000 columns (C =
@@ -78,11 +88,18 @@ It needs NO scratch copy of the matrix (the old design kept a second
 full-size buffer: 670 MB at Higgs scale) and halves per-split traffic.
 
 Why matmuls everywhere: Mosaic has no vector scatter/gather and no
-cumsum, but the MXU is nearly free next to the VPU.  So
-- cumsum(goes_left) = one dot with a triangular ones matrix,
+cumsum, and a one-hot matmul is both; what it costs is the weight tiles
+it hands the MXU (observation 2), so each is kept to the tiles that can
+hold a one.  So
+- cumsum(goes_left) = within a lane tile, one dot with a (128, 128)
+  triangular ones matrix (every tile's flags stacked on sublanes: one
+  weight tile a block); across tiles, a scalar prefix of the tile totals,
 - the in-block compaction is a one-hot permutation matmul applied to the
   block's four byte planes (integers 0..255 are exact in bf16, so the
-  permutation is bit-exact on int32/f32 data),
+  permutation is bit-exact on int32/f32 data); a stable compaction's
+  one-hot is a staircase, one source lane tile lands in at most 128
+  consecutive lanes, so it is built and multiplied as a two-tile window a
+  source tile (``_staircase``, ``_apply_staircase``),
 - per-bin accumulation = dot of bf16 value rows with bin-equality
   one-hots (3-term hi/mid/lo value split keeps f32 fidelity),
 exactly the trade SURVEY §7 prescribes (scatter -> one-hot matmul).
@@ -114,16 +131,18 @@ _LANE = 128  # DMA lane-alignment quantum
 _RING = 3  # read-buffer ring depth per stream end (max occupancy 2 + 1 inflight)
 _HIST_GROUP_WORDS = 8  # bin-word rows a rolled histogram group holds (one sublane tile)
 _PERM_GROUP_ROWS = 128  # channel rows a rolled permutation group holds
+_TILES = BLK // _LANE  # lane tiles of a block: what the in-block compaction walks
 # one-hot tile budget of the kernels whose VMEM the partition stream buffers
 # crowd (split_stream, level_stream): the historical 1 MiB
 _SPLIT_TILE_BYTES = 1024 * 1024
 _UPDATE_TILE_BYTES = 2 * 1024 * 1024  # tune_fchunk's default, the other kernels'
 # Mosaic's scoped VMEM default fits the kernels' scratch up to a few hundred
 # columns.  Past _VMEM_DEFAULT_FITS of scratch the limit is asked for: the
-# scratch plus room for the values Mosaic spills there (the (BLK, BLK)
-# permutation one-hots and iota, a one-hot tile, a row group's byte planes)
+# scratch plus room for the values Mosaic spills there (a one-hot tile, a row
+# group's byte planes and their dots; compiled for the v5e at 2,000 and 3,900
+# columns, PR 37: the kernels need between 2 and 4 MiB of it)
 _VMEM_DEFAULT_FITS = 6 * 1024 * 1024
-_VMEM_SPILL_ROOM = 40 * 1024 * 1024
+_VMEM_SPILL_ROOM = 16 * 1024 * 1024
 
 
 def num_words(num_features: int, bits: int = 8) -> int:
@@ -422,7 +441,7 @@ def _vmem_params(*scratch_bytes):
     """Compiler parameters for a kernel whose VMEM scratch comes to
     ``sum(scratch_bytes)``: none while the scoped default holds it (every
     shape up to a few hundred columns), else the limit it needs (the v5e
-    has 128 MiB; at 2,000 columns level_stream asks for ~81 MiB)."""
+    has 128 MiB; at 2,000 columns level_stream asks for ~58 MiB)."""
     need = int(sum(scratch_bytes))
     if need <= _VMEM_DEFAULT_FITS:
         return None
@@ -1038,7 +1057,7 @@ def _stream_drain(stage, wsem, nstarts):
 
 def _run_segment(
     p_any, hist_ref, scalars,
-    buf, carL, carR, stageL, stageR, tri_ref,
+    buf, carL, carR, stageL, stageR, tri_ref, oh_ref, pacc,
     rsemF, rsemB, csemL, csemR, wsemL, wsemR,
     *, c, bits, nf, nb, rows,
 ):
@@ -1061,8 +1080,9 @@ def _run_segment(
     groups of channel rows: the left/right decision reads the one word row
     that holds the split column, the histograms walk the bin words group
     by group (``_hist_accumulate``), and the permutation the decision
-    implies is applied to ``_PERM_GROUP_ROWS`` rows at a time
-    (``_for_row_groups``), each group merged into the carries and the
+    implies (``_staircase``: its one-hots, once a block) is applied to
+    ``_PERM_GROUP_ROWS`` rows at a time (``_apply_staircase`` under
+    ``_for_row_groups``), each group merged into the carries and the
     write stages before the next is loaded.  ``buf`` is the two read
     rings in one array (front slots 0.._RING-1, back slots _RING..) so
     the hand block is one dynamic slot of it."""
@@ -1079,8 +1099,6 @@ def _run_segment(
     head = start - base
     E = head + cnt
     nblk = (E + BLK - 1) // BLK
-
-    ii = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 0)
 
     # preload carries: carL holds the head block (lanes < head preserved
     # as pre-filled carry), carR the tail block (lanes >= E-(nblk-1)*BLK
@@ -1226,25 +1244,8 @@ def _run_segment(
         _hist_accumulate(hand, vals, hist_ref, nf=nf, nb=nb, bits=bits,
                          max_tile_bytes=_SPLIT_TILE_BYTES)
 
-        # ---- in-block compaction via permutation matmuls: where each
-        # lane goes, once for the block
-        lr = jnp.concatenate(
-            [glm.astype(jnp.bfloat16), grm.astype(jnp.bfloat16)], axis=0
-        )  # (2, BLK)
-        cum2 = jax.lax.dot_general(
-            lr, tri_ref[:, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.int32)
-        cumL = cum2[0:1]
-        cumR = cum2[1:2]
-        cntl = jnp.max(cumL)
-        cntr = jnp.max(cumR)
-        tgtL = cl + cumL - 1
-        tgtL = tgtL - jnp.where(tgtL >= BLK, BLK, 0)
-        ohL = (gl & (ii == tgtL)).astype(jnp.bfloat16)
-        tgtR = BLK - cr - cumR
-        tgtR = tgtR + jnp.where(tgtR < 0, BLK, 0)
-        ohR = (gr & (ii == tgtR)).astype(jnp.bfloat16)
+        # ---- in-block compaction: where each lane goes, once for the block
+        cntl, cntr, win = _staircase(glm, grm, cl, cr, tri_ref, oh_ref)
 
         # ---- left flush (forward, into front-vacated space)
         tL = cl + cntl
@@ -1294,16 +1295,7 @@ def _run_segment(
         slotR = jax.lax.rem(fr, 2)
 
         def permute(rws):
-            planes = _planes(hand[rws, :])
-            nrow = planes.shape[0] // 4
-            permL = _unplanes(
-                jax.lax.dot_general(planes, ohL, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32), nrow
-            )
-            permR = _unplanes(
-                jax.lax.dot_general(planes, ohR, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32), nrow
-            )
+            permL, permR = _apply_staircase(hand[rws, :], oh_ref, pacc, win)
             mergedL = jnp.where(lane < cl, carL[rws, :], permL)
             mergedR = jnp.where(lane >= BLK - cr, carR[rws, :], permR)
 
@@ -1389,8 +1381,20 @@ def _run_segment(
     return fl * BLK + cl - head
 
 
+def _compaction_scratch(c: int) -> list:
+    """VMEM of the in-block compaction (``_staircase``, ``_apply_staircase``)."""
+    return [
+        pltpu.VMEM((_LANE, _LANE), jnp.bfloat16),  # tri
+        pltpu.VMEM((2 * _TILES, 2 * _LANE, _LANE), jnp.bfloat16),  # oh: a block's one-hots
+        # pacc: the rows _for_row_groups hands over at a time, permuted
+        pltpu.VMEM((2, min(c, _PERM_GROUP_ROWS), BLK + _LANE), jnp.int32),
+    ]
+
+
 def _partition_bytes(c: int) -> int:
-    return _tile_bytes(c, 2 * _RING + 6) + BLK * BLK * 2
+    """VMEM bytes of ``_partition_scratch(c)``."""
+    return sum(int(np.prod(v.shape)) * jnp.dtype(v.dtype).itemsize
+               for v in _partition_scratch(c))
 
 
 def level_stream_vmem_bytes(num_cols: int, num_bins: int, num_score: int = 1,
@@ -1409,22 +1413,102 @@ def _partition_scratch(c: int) -> list:
         pltpu.VMEM((c, BLK), jnp.int32),  # carR
         pltpu.VMEM((2, c, BLK), jnp.int32),  # stageL
         pltpu.VMEM((2, c, BLK), jnp.int32),  # stageR
-        pltpu.VMEM((BLK, BLK), jnp.bfloat16),  # tri
-    ]
+    ] + _compaction_scratch(c)
+
+
+def perm_tiles() -> int:
+    """128 x 128 one-hot tiles the MXU is handed for one block's compaction:
+    the triangle once (all ``2 * _TILES`` lane tiles' flags stream through it
+    stacked on sublanes) and a two-tile window for each source tile and side.
+    The dense form this replaced took ``_TILES**2`` for the count and
+    ``2 * _TILES**2`` for the permutation."""
+    return 1 + 2 * _TILES * 2
 
 
 def _build_tri(tri_ref):
-    """Triangular cumsum operand, built once per kernel (cheaper than an
-    HBM-resident constant: reading a 2 MB tri per pass costs more than
-    one (BLK, BLK) compare)."""
-    ii = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 1)
+    """Triangular operand of the in-tile running count, built once per
+    kernel: 16 K cells (cheaper than reading it from HBM every launch)."""
+    ii = jax.lax.broadcasted_iota(jnp.int32, (_LANE, _LANE), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (_LANE, _LANE), 1)
     tri_ref[:, :] = (ii <= jj).astype(jnp.bfloat16)
+
+
+def _staircase(glm, grm, cl, cr, tri_ref, oh_ref):
+    """Where a block's lanes go, as the one-hots that take them there.
+
+    ``glm`` / ``grm`` are the (1, BLK) 0/1 flags of the lanes that go left /
+    right, ``cl`` / ``cr`` the fills of the two carries.  The k-th left lane
+    of the block lands on lane ``cl + k`` and the k-th right lane on lane
+    ``BLK - cr - 1 - k``, both mod BLK: a stable compaction, so the targets
+    of ONE source lane tile are at most ``_LANE`` consecutive lanes, which
+    lie in two consecutive target tiles.  For each source tile ``s`` and
+    side, ``oh_ref[side * _TILES + s]`` gets the (2 * _LANE, _LANE) one-hot
+    [window lane, source lane] of that two-tile window.
+
+    The running count is in-tile: the 2 * _TILES flag tiles stacked on
+    sublanes against one (_LANE, _LANE) triangle; the tile totals leave the
+    vector unit as scalars and their prefix is scalar arithmetic, which the
+    window offsets have to be anyway (they address ``pacc``).
+
+    Returns (left count, right count, the ``2 * _TILES`` window offsets in
+    lanes, ``oh_ref``'s order; multiples of _LANE below BLK)."""
+    flags = jnp.concatenate(
+        [m[:, s * _LANE:(s + 1) * _LANE] for m in (glm, grm) for s in range(_TILES)],
+        axis=0)  # (2 * _TILES, _LANE)
+    incl = jax.lax.dot_general(
+        flags.astype(jnp.bfloat16), tri_ref[:, :], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+    iota_w = jax.lax.broadcasted_iota(jnp.int32, (2 * _LANE, _LANE), 0)
+    # first target lane of the tile's window, kept non-negative: lefts count
+    # up from cl, rights down from BLK - cr - 1 (so a tile's LAST right lane
+    # is its window's first)
+    base = [cl, 2 * BLK - cr - _LANE]
+    win = []
+    for side in range(2):
+        b = base[side]
+        for s in range(_TILES):
+            k = side * _TILES + s
+            inc = incl[k:k + 1, :]
+            lane0 = b & (_LANE - 1)
+            rel = inc + (lane0 - 1) if side == 0 else (lane0 + _LANE) - inc
+            rel = jnp.where(flags[k:k + 1, :] > 0, rel, -1)
+            oh_ref[k] = (iota_w == rel).astype(jnp.bfloat16)
+            win.append(b & (BLK - _LANE))  # the tile b lies in, mod BLK
+            tot = incl[k, _LANE - 1]
+            b = b + tot if side == 0 else b - tot
+        base[side] = b
+    return base[0] - cl, (2 * BLK - cr - _LANE) - base[1], win
+
+
+def _apply_staircase(rows_i32, oh_ref, pacc, win):
+    """The block's compaction applied to a group of channel rows: (rows, BLK)
+    int32 -> the same rows with the left lanes compacted (``permL``) and with
+    the right lanes compacted (``permR``), zero where no lane lands.  Each
+    source lane tile's four byte planes go through its two-tile one-hot
+    (integers 0..255 are exact in bf16) into ``pacc`` at the window's
+    offset; a window that starts in the last tile spills into a ninth, which
+    is folded back onto the first.  Every target lane receives from exactly
+    one source lane, so OR-ing the pieces is exact."""
+    planes = _planes(rows_i32)
+    nrow = rows_i32.shape[0]
+    pacc[:, 0:nrow, :] = jnp.zeros((2, nrow, BLK + _LANE), jnp.int32)
+    for s in range(_TILES):
+        src = planes[:, s * _LANE:(s + 1) * _LANE]
+        for side in range(2):
+            k = side * _TILES + s
+            piece = _unplanes(
+                jax.lax.dot_general(src, oh_ref[k], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32), nrow)
+            lanes = pl.ds(pl.multiple_of(win[k], _LANE), 2 * _LANE)
+            pacc[side, 0:nrow, lanes] |= piece
+    pacc[:, 0:nrow, 0:_LANE] |= pacc[:, 0:nrow, BLK:BLK + _LANE]
+    return pacc[0, 0:nrow, 0:BLK], pacc[1, 0:nrow, 0:BLK]
 
 
 def _split_kernel(
     sref, p_in, p_any, hist_ref, nl_ref,
-    buf, carL, carR, stageL, stageR, tri_ref,
+    buf, carL, carR, stageL, stageR, tri_ref, oh_ref, pacc,
     rsemF, rsemB, csemL, csemR, wsemL, wsemR,
     *, c, bits, nf, nb, rows,
 ):
@@ -1435,7 +1519,7 @@ def _split_kernel(
     scalars = tuple(sref[k] for k in range(11))
     nl = _run_segment(
         p_any, hist_ref, scalars, buf, carL, carR, stageL, stageR,
-        tri_ref, rsemF, rsemB, csemL, csemR, wsemL, wsemR,
+        tri_ref, oh_ref, pacc, rsemF, rsemB, csemL, csemR, wsemL, wsemR,
         c=c, bits=bits, nf=nf, nb=nb, rows=rows,
     )
     nl_ref[0] = nl
@@ -1443,7 +1527,7 @@ def _split_kernel(
 
 def _level_kernel(
     sref, p_in, p_any, hist_out, nl_ref,
-    buf, carL, carR, stageL, stageR, tri_ref, hacc,
+    buf, carL, carR, stageL, stageR, tri_ref, oh_ref, pacc, hacc,
     rsemF, rsemB, csemL, csemR, wsemL, wsemR, hsem,
     *, c, bits, nf, nb, rows,
 ):
@@ -1473,7 +1557,7 @@ def _level_kernel(
         scalars = tuple(sref[1 + s, k] for k in range(11))
         nl = _run_segment(
             p_any, hacc.at[slot], scalars, buf, carL, carR,
-            stageL, stageR, tri_ref, rsemF, rsemB, csemL, csemR,
+            stageL, stageR, tri_ref, oh_ref, pacc, rsemF, rsemB, csemL, csemR,
             wsemL, wsemR,
             c=c, bits=bits, nf=nf, nb=nb, rows=rows,
         )

@@ -187,6 +187,214 @@ class TestLevelStreamKernel:
         np.testing.assert_array_equal(np.asarray(pl_), Pn)
 
 
+def _dense_compaction(block, gl, gr, cl, cr):
+    """The formula the staircase replaced: the (BLK, BLK) one-hots
+    ``ii == cl + cumsum(gl) - 1`` and ``ii == BLK - cr - cumsum(gr)`` (mod
+    BLK), applied as the scatter they are.  (permL, permR, cntl, cntr)."""
+    B = pk.BLK
+    permL, permR = np.zeros_like(block), np.zeros_like(block)
+    tgtL = (cl + np.cumsum(gl) - 1) % B
+    tgtR = (B - cr - np.cumsum(gr)) % B
+    permL[:, tgtL[gl]] = block[:, gl]
+    permR[:, tgtR[gr]] = block[:, gr]
+    return permL, permR, int(gl.sum()), int(gr.sum())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _compaction_alone(block, masks, fills, interpret):
+    """``pk._staircase`` and ``pk._apply_staircase`` on ONE (C, BLK) block, as
+    ``_run_segment`` calls them: masks (2, BLK) f32 0/1 (left, right), fills
+    (2,) int32 (cl, cr) -> permL, permR (C, BLK), counts (2,)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    c = block.shape[0]
+
+    def kernel(fills_ref, blk_ref, m_ref, outL, outR, cnt_ref, tri, oh, pacc):
+        pk._build_tri(tri)
+        cntl, cntr, win = pk._staircase(m_ref[0:1, :], m_ref[1:2, :], fills_ref[0],
+                                        fills_ref[1], tri, oh)
+        cnt_ref[0] = cntl
+        cnt_ref[1] = cntr
+
+        def permute(rws):
+            outL[rws, :], outR[rws, :] = pk._apply_staircase(blk_ref[rws, :], oh, pacc, win)
+
+        pk._for_row_groups(c, permute)
+
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,), in_specs=[vmem, vmem],
+            out_specs=[vmem, vmem, pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=pk._compaction_scratch(c)),
+        out_shape=(jax.ShapeDtypeStruct(block.shape, jnp.int32),
+                   jax.ShapeDtypeStruct(block.shape, jnp.int32),
+                   jax.ShapeDtypeStruct((2,), jnp.int32)),
+        interpret=interpret,
+    )(fills, block, masks)
+
+
+def _dense_segment(P, start, cnt, goes_left):
+    """``pk._run_segment``'s two-ended protocol replayed on the host with the
+    dense compaction: the matrix split_stream must leave, BIT FOR BIT (which
+    block is in hand, and so the order of rows within a side, is the
+    protocol's; ``partition_ref`` knows only the row sets).  A read lands
+    when it is waited for, a write when it is started: the protocol lets no
+    write touch a block between those two (TestTwoEndProtocol).
+    ``goes_left`` maps a (C, BLK) block to its (BLK,) bool flags."""
+    B, RING = pk.BLK, pk._RING
+    P = np.array(P)
+    base = start // B * B
+    head = start - base
+    E = head + cnt
+    nblk = -(-E // B)
+    lane = np.arange(B)
+
+    def blk(k):
+        return P[:, base + k * B: base + (k + 1) * B]
+
+    carL, carR = blk(0).copy(), blk(max(nblk - 1, 0)).copy()
+    bufF, bufB = {}, {}
+    if_ = ib = cf = cb = kf = kb = fl = fr = 0
+    cl, cr = head, nblk * B - E
+
+    def waitF():
+        nonlocal cf
+        bufF[cf % RING] = blk(cf).copy()
+        cf += 1
+
+    def waitB():
+        nonlocal cb
+        bufB[cb % RING] = blk(nblk - 1 - cb).copy()
+        cb += 1
+
+    for _ in range(nblk):
+        budget = if_ + ib < nblk
+        if cf - fl == 0 and (if_ > cf or budget):
+            if_ += if_ == cf
+            waitF()
+        budget = if_ + ib < nblk
+        if cb - fr == 0 and (ib > cb or budget):
+            ib += ib == cb
+            waitB()
+        budget = if_ + ib < nblk
+        if cf - kf == 0 and cb - kb == 0:
+            if if_ > cf or budget:
+                if_ += if_ == cf
+                waitF()
+            else:
+                ib += ib == cb
+                waitB()
+        useF = cf - kf > 0
+        hand = bufF[kf % RING] if useF else bufB[kb % RING]
+        jh = kf if useF else nblk - 1 - kb
+        kf, kb = kf + useF, kb + (not useF)
+        pos = lane + jh * B
+        valid = (pos >= head) & (pos < E)
+        gl = goes_left(hand) & valid
+        gr = valid & ~gl
+        permL, permR, cntl, cntr = _dense_compaction(hand, gl, gr, cl, cr)
+        tL, tR = cl + cntl, cr + cntr
+        flushL, flushR = tL >= B, tR >= B
+        rtgt = nblk - 1 - fr
+        if flushL and ib > cb and fl == nblk - 1 - cb:
+            waitB()
+        if flushL and if_ > cf and fl == cf:
+            waitF()
+        if flushR and ib > cb and rtgt == nblk - 1 - cb:
+            waitB()
+        if flushR and if_ > cf and rtgt == cf:
+            waitF()
+        mergedL = np.where(lane < cl, carL, permL)
+        mergedR = np.where(lane >= B - cr, carR, permR)
+        if flushL:
+            blk(fl)[:] = mergedL
+        if flushR:
+            blk(rtgt)[:] = mergedR
+        carL = permL if flushL else mergedL
+        carR = permR if flushR else mergedR
+        cl, fl = (tL - B, fl + 1) if flushL else (tL, fl)
+        cr, fr = (tR - B, fr + 1) if flushR else (tR, fr)
+        budget = if_ + ib < nblk
+        if_ += budget and useF and if_ - kf < RING
+        budget = if_ + ib < nblk
+        ib += budget and (not useF) and ib - kb < RING
+    assert cl + cr in (0, B)
+    if cl + cr:
+        blk(fl)[:] = np.where(lane < cl, carL, carR)
+    return P, fl * B + cl - head
+
+
+class TestStaircaseCompaction:
+    """The in-block compaction (PR 37): per source lane tile a two-tile
+    window of the one-hot, where there was a (BLK, BLK) one-hot a side."""
+
+    @staticmethod
+    def _block(c, seed):
+        rng = np.random.default_rng(seed)
+        return rng.integers(-2**31, 2**31, size=(c, pk.BLK), dtype=np.int64).astype(np.int32)
+
+    # every fill of {0, 1, 127, 128, 129, 1023} on either side; 960 and 1023
+    # start a window in tile 7 that wraps into tile 0
+    @pytest.mark.parametrize("c", [16, 136], ids=["C16", "C136-row-groups"])
+    @pytest.mark.parametrize("lefts", [0, 1, 512, 1023, 1024],
+                             ids=["share0", "share1of1024", "half", "share1023of1024", "share1"])
+    @pytest.mark.parametrize("cl,cr", [(0, 1023), (1, 129), (127, 128), (128, 127), (129, 1),
+                                       (1023, 0), (960, 960)])
+    def test_alone_against_the_dense_one_hots(self, c, lefts, cl, cr):
+        rng = np.random.default_rng(1000 * cl + cr + lefts)
+        block = self._block(c, lefts + c)
+        gl = np.zeros(pk.BLK, bool)
+        gl[rng.choice(pk.BLK, size=lefts, replace=False)] = True
+        # the half-and-half case also has lanes that go nowhere (a head or a
+        # tail outside the segment); the extreme shares are exact
+        valid = rng.random(pk.BLK) < 0.9 if lefts == 512 else np.ones(pk.BLK, bool)
+        gl &= valid
+        gr = valid & ~gl
+        got = _compaction_alone(jnp.asarray(block), jnp.asarray(np.stack([gl, gr]), jnp.float32),
+                                jnp.asarray([cl, cr], jnp.int32), interpret=INTERP)
+        wantL, wantR, cntl, cntr = _dense_compaction(block, gl, gr, cl, cr)
+        assert np.asarray(got[2]).tolist() == [cntl, cntr]
+        np.testing.assert_array_equal(np.asarray(got[0]), wantL)
+        np.testing.assert_array_equal(np.asarray(got[1]), wantR)
+
+    # (head, lanes past the tail): the fills the first block starts with
+    @pytest.mark.parametrize("thr,cat", [(40, 1), (0, 0), (15, 0), (31, 0)],
+                             ids=["none-left", "1-in-32-left", "half-left", "all-left"])
+    @pytest.mark.parametrize("head,tail", [(1, 1023), (127, 129), (129, 127), (1023, 1), (0, 0)])
+    def test_segment_bit_for_bit(self, head, tail, thr, cat):
+        """split_stream on a segment whose head and tail are not
+        block-aligned: the matrix and ``nl`` of the dense kernel, replayed on
+        the host, and ``partition_ref``'s row sets."""
+        P, lay, *_ = _make_packed(n=7000)
+        start = 1024 + head
+        cnt = 5 * 1024 - head - tail
+        feat, per = 3, 32 // lay.bits
+
+        def goes_left(block):
+            b = (block[feat // per] >> ((feat % per) * lay.bits)) & 255
+            return (b == thr) if cat else (b <= thr)
+
+        want, nl_want = _dense_segment(P, start, cnt, goes_left)
+        _check_split_stream(P, lay, start, cnt, feat, thr, 0, 0, cat)
+        got, nl, _ = pk.split_stream(
+            jnp.array(P), start, cnt, feat // per, (feat % per) * lay.bits, 0, 0, thr, cat,
+            num_features=lay.F, num_bins=32, bits=lay.bits, rows=lay.rows, interpret=INTERP,
+            planes=True)
+        assert int(nl) == nl_want
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+    def test_the_tiles_it_multiplies(self):
+        """One triangle and a two-tile window a source tile and side, where
+        the dense form took 64 + 128."""
+        assert pk.perm_tiles() == 33 and pk._TILES == 8
+        shapes = [tuple(v.shape) for v in pk._compaction_scratch(512)]
+        assert shapes == [(128, 128), (16, 256, 128), (2, 128, pk.BLK + 128)]
+        assert not any(sum(d >= pk.BLK for d in sh) > 1 for sh in shapes)  # nothing (BLK, BLK)
+
+
 class TestTwoEndProtocol:
     """Host-side block-level simulation of split_stream's two-ended
     read/write protocol (demand reads, force-consume, hand-side prefetch,

@@ -347,13 +347,20 @@ def _booster(X, y, learner="serial"):
 
 
 @pytest.mark.parametrize("learner", ["serial", "data"])
-def test_tracing_off_costs_nothing(tracing_off, counted, learner):
+def test_tracing_off_costs_nothing(tracing_off, counted, learner, monkeypatch):
     """No record, no TraceAnnotation, no block_until_ready from the new spans,
     no re-lowering: the untraced path issues the calls it issued before.  The
     data-parallel trainer's `shard_pack` span and all-reduce counters too:
-    construction is inside what is counted."""
+    construction is inside what is counted.  Nor is a shape count made for a
+    span nobody writes (`perm_tiles`, PR 37, stands for `stream_counts`)."""
+    from unittest import mock
+
+    from lightgbm_tpu.boosting import ptrainer as ptrainer_mod
+
     if learner == "data" and len(jax.devices()) < 4:
         pytest.skip("needs a multi-device mesh")
+    tiles = mock.Mock(wraps=ptrainer_mod.perm_tiles)
+    monkeypatch.setattr(ptrainer_mod, "perm_tiles", tiles)
     X, y, _ = _toy()
     work = tracer.work_ops
     bst = _booster(X, y, learner)
@@ -363,6 +370,7 @@ def test_tracing_off_costs_nothing(tracing_off, counted, learner):
         bst.boosting.train_iters_partitioned(2, is_eval=False)
     assert tracer.work_ops == work
     assert counted == {"annotation": 0, "block": 0, "phase_map": 0}
+    assert not tiles.called
 
 
 @pytest.fixture
@@ -415,6 +423,8 @@ def test_trees_from_records_carries_the_stream_counts(traced_chunks):
     n, f, b = pt.num_rows, pt.params.num_features, pt.params.num_bins
     for t in (r for r in recs if r["ev"] == "span" and r["name"] == "trees_from_records"):
         assert t["channels"] == pt.layout.C == 16 and t["col_groups"] == 1
+        # PR 37: the tiles a block's compaction multiplies, of the dense form's 192
+        assert t["perm_tiles"] == 33
         assert t["hist_cells"] == hist_lanes(f, b) and t["hist_cells"] % 128 == 0
         assert t["hist_cells"] >= f * b
         # two trees a chunk: every tree's first level streams every row once,
