@@ -19,6 +19,7 @@ from .io.dataset import BinnedDataset
 from .io.sparse import is_sparse, map_dense_blocks
 from .metric import create_metric
 from .objective import create_objective
+from .obs import tracer
 from .utils.log import Log
 
 
@@ -147,6 +148,16 @@ class Dataset:
         """
         if self._constructed is not None:
             return self._constructed
+        # a real construction only: once a Dataset (obs/trace.py's rule)
+        with tracer.stage("dataset_construct") as stage:
+            ds, source = self._construct(extra_params)
+            stage.attrs.update(rows=ds.num_data, features=ds.num_features, source=source)
+        return ds
+
+    def _construct(self, extra_params: Optional[Dict[str, Any]]):
+        """``(the binned dataset, where it came from)``: ``binary`` (the
+        program's own dataset cache), ``file`` (text, parsed or streamed),
+        ``sparse`` (scipy CSR/CSC) or ``matrix``."""
         merged = dict(extra_params) if extra_params else {}
         merged.update(self.params)
         cfg = Config.from_params(
@@ -165,7 +176,7 @@ class Dataset:
                 if self.init_score is not None:
                     ds.metadata.set_init_score(self.init_score)
                 self._constructed = ds
-                return ds
+                return ds, "binary"
             from .data.ingest import should_stream, stream_dataset
 
             if should_stream(self.data_path, cfg):
@@ -190,7 +201,7 @@ class Dataset:
                     ds.metadata.set_init_score(self.init_score)
                 self.label_idx = ds.label_idx
                 self._constructed = ds
-                return ds
+                return ds, "file"
             from .io.parser import load_text_file
 
             feats, label, weights, group, names, label_idx = load_text_file(
@@ -234,7 +245,9 @@ class Dataset:
         ref = self.reference.construct() if self.reference is not None else None
         if self.reference is not None:
             self._remap_categorical_to_reference(self.reference)
-        make = BinnedDataset.from_sparse if is_sparse(self.data) else BinnedDataset.from_raw
+        sparse = is_sparse(self.data)
+        source = "file" if self.data_path is not None else "sparse" if sparse else "matrix"
+        make = BinnedDataset.from_sparse if sparse else BinnedDataset.from_raw
         self._constructed = make(
             self.data,
             cfg,
@@ -249,7 +262,7 @@ class Dataset:
         self._constructed.label_idx = self.label_idx
         if self.free_raw_data:
             self.data = None
-        return self._constructed
+        return self._constructed, source
 
     # ------------------------------------------------------------------
     def _remap_categorical_to_reference(self, ref: "Dataset") -> None:
@@ -400,23 +413,25 @@ class Booster:
 
         self.pandas_categorical = []
         if train_set is not None:
-            self.config = Config.from_params(self.params)
-            self.pandas_categorical = getattr(train_set, "pandas_categorical", [])
-            # dataset-relevant train params reach construction unless the
-            # Dataset set them explicitly (Dataset._update_params: the
-            # dataset's own params win, booster params fill the gaps) —
-            # passed per-construction, never written into train_set.params
-            binned = train_set.construct(extra_params=self.params)
-            self.train_dataset = train_set
-            self.objective = create_objective(self.config)
-            self.boosting = create_boosting(self.config.boosting_type)
-            # training metrics only when asked (is_provide_training_metric
-            # gate, gbdt.cpp ResetTrainingData); the python engine path
-            # evaluates "training" as a valid set instead
-            training_metrics = (
-                self._make_metrics(binned) if self.config.is_training_metric else []
-            )
-            self.boosting.init(self.config, binned, self.objective, training_metrics)
+            # once a Booster, where lgb.train and lgb.Booster(...) both pass
+            with tracer.stage("booster_init"):
+                self.config = Config.from_params(self.params)
+                self.pandas_categorical = getattr(train_set, "pandas_categorical", [])
+                # dataset-relevant train params reach construction unless the
+                # Dataset set them explicitly (Dataset._update_params: the
+                # dataset's own params win, booster params fill the gaps) —
+                # passed per-construction, never written into train_set.params
+                binned = train_set.construct(extra_params=self.params)
+                self.train_dataset = train_set
+                self.objective = create_objective(self.config)
+                self.boosting = create_boosting(self.config.boosting_type)
+                # training metrics only when asked (is_provide_training_metric
+                # gate, gbdt.cpp ResetTrainingData); the python engine path
+                # evaluates "training" as a valid set instead
+                training_metrics = (
+                    self._make_metrics(binned) if self.config.is_training_metric else []
+                )
+                self.boosting.init(self.config, binned, self.objective, training_metrics)
             self._num_datasets = 1
         elif model_file is not None or model_str is not None:
             if model_file is not None:

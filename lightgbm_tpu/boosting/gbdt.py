@@ -19,6 +19,7 @@ from __future__ import annotations
 import functools
 from typing import List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -153,7 +154,10 @@ class GBDT:
                     local_s = max(int.from_bytes(b, "little")
                                   for b in blobs)
                 md.pad_group_size = local_s
-            objective.init(train_set.metadata, self.num_data)
+            # label statistics on the host and the objective's row vectors
+            # to the device: seconds at 21M rows (once a Booster)
+            with tracer.stage("objective_init", rows=self.num_data):
+                objective.init(train_set.metadata, self.num_data)
 
         # persistent compile cache, keyed on the now-known backend
         from .. import enable_compile_cache
@@ -235,7 +239,11 @@ class GBDT:
         self._bins = None
         self._bins_lazy = not ooc_on and not train_set.has_dense_bins
         if not ooc_on and not self._bins_lazy:
-            self._bins = jnp.asarray(train_set.binned)
+            # ends in one wait, sink on or off: the stage would otherwise
+            # read the dispatch, and the copy would land in whichever span
+            # next touches the device (once a Booster, never a chunk)
+            with tracer.stage("bins_upload", bytes=int(train_set.binned.nbytes)):
+                self._bins = jax.block_until_ready(jnp.asarray(train_set.binned))
         self.num_bins = int(train_set.max_num_bin)
         self.meta = FeatureMeta.from_dataset(train_set)
         self.hyper = SplitHyper.from_config(config)
@@ -347,10 +355,11 @@ class GBDT:
                 # keep the mask grower's collective formulations
                 if (learner_type == "data" and self.supports_partitioned
                         and self.supports_partitioned_data):
-                    from .ptrainer import (
-                        ShardedPartitionedTrainer,
-                        eligible as _pt_eligible,
-                    )
+                    with tracer.stage("trainer_import"):
+                        from .ptrainer import (
+                            ShardedPartitionedTrainer,
+                            eligible as _pt_eligible,
+                        )
 
                     if _pt_eligible(config, train_set, objective,
                                     self.num_tree_per_iteration):
@@ -390,7 +399,10 @@ class GBDT:
         # earlier host-driven FastGrower is gone: it paid a host round
         # trip per split; pgrow supersedes it.)
         if self.learner is None and self.ptrainer is None and self.supports_partitioned:
-            from .ptrainer import PartitionedTrainer, eligible as _pt_eligible
+            # a process's first import of the fused trainer loads Pallas: a
+            # second of the first Booster's construction, nothing after
+            with tracer.stage("trainer_import"):
+                from .ptrainer import PartitionedTrainer, eligible as _pt_eligible
 
             if _pt_eligible(config, train_set, objective, self.num_tree_per_iteration):
                 self.ptrainer = PartitionedTrainer(
@@ -608,8 +620,6 @@ class GBDT:
     def _train_one_iter_impl(self, gradients=None, hessians=None,
                              is_eval: bool = True) -> bool:
         """The actual iteration body (see :meth:`train_one_iter`)."""
-        from ..utils.profiling import timetag
-
         if self.ptrainer is not None and gradients is None:
             return self.train_iters_partitioned(1, is_eval=is_eval)
 
@@ -640,7 +650,7 @@ class GBDT:
         bytes_before = comm.ledger_total() if comm is not None else 0
 
         with tracer.iteration(self.iter) as irec:
-            with timetag.phase("boosting"):
+            with tracer.span("boosting"):
                 if gradients is None or hessians is None:
                     grad, hess = self._get_gradients()
                 else:
@@ -650,7 +660,7 @@ class GBDT:
                         self.num_tree_per_iteration, -1))
                 fence((grad, hess))
 
-            with timetag.phase("bagging"):
+            with tracer.span("bagging"):
                 grad, hess = self._adjust_gradients(grad, hess)
                 self._bagging(self.iter)
                 fence(self.select)
@@ -666,7 +676,7 @@ class GBDT:
                                         "quantizes_internally", False))
             for k in range(self.num_tree_per_iteration):
                 feature_mask = self._feature_mask()
-                with timetag.phase("tree"):
+                with tracer.span("tree"):
                     gk, hk, qscale = grad[k], hess[k], None
                     if quantize:
                         gk, hk, qscale = self._quantize_class(gk, hk, k)
@@ -713,7 +723,7 @@ class GBDT:
                             "tree.monotone_clip",
                             float(sum(1 for f in rf
                                       if mono_t[int(f)] != 0)))
-                    with timetag.phase("train_score"):
+                    with tracer.span("train_score"):
                         # score update via the grower's partition (one gather)
                         lv = np.zeros(self.grow_params.num_leaves, np.float32)
                         lv[: tree.num_leaves] = tree.leaf_value[: tree.num_leaves]
@@ -727,7 +737,7 @@ class GBDT:
                                 add_leaf_outputs(self.scores[k], gr.leaf_id, leaf_vals)
                             )
                         fence(self.scores)
-                    with timetag.phase("valid_score"):
+                    with tracer.span("valid_score"):
                         self._add_tree_to_valid_scores(tree, k)
                         fence(self.valid_scores)
                 else:
@@ -770,8 +780,6 @@ class GBDT:
         """Run ``num_iters`` boosting iterations through the fused
         partitioned trainer (one device program, no per-iteration host
         round-trips).  Returns True when training should stop."""
-        from ..utils.profiling import timetag
-
         if num_iters <= 0:
             return False
         self._boost_from_average()
@@ -782,7 +790,7 @@ class GBDT:
         import time as _time
 
         t_chunk0 = _time.perf_counter()
-        with timetag.phase("tree"):
+        with tracer.span("tree"):
             recs, scores_orig, n_done = pt.train_chunk(
                 num_iters, self.shrinkage_rate, self.iter
             )
@@ -799,7 +807,7 @@ class GBDT:
                     leaves=int(np.sum(ns + (ns > 0))), trees=K,
                     amortized=True,
                 )
-        with timetag.phase("train_score"):
+        with tracer.span("train_score"):
             self.scores = scores_orig[None, :] if K == 1 else scores_orig
             fence(self.scores)
         chunk_trees = [[] for _ in range(K)]
@@ -825,7 +833,7 @@ class GBDT:
         # valid scores advance ONCE per chunk per class: a single stacked
         # predict_binned over all of the chunk's trees (vs one dispatch
         # per tree; per-dispatch cost not measured on this machine)
-        with timetag.phase("valid_score"):
+        with tracer.span("valid_score"):
             for k in range(K):
                 if chunk_trees[k]:
                     self._add_trees_to_valid_scores(chunk_trees[k], k)

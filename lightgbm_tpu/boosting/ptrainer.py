@@ -201,10 +201,16 @@ class PartitionedTrainer:
             if bits == 4 and max_col_bin > 16:
                 bits = 8  # cannot pack >16 bins in 4 bits
         self.layout = PLayout(matrix.shape[1], num_score=self.K, with_weight=True, bits=bits)
-        if bins_dev is None:
-            bins_dev = jnp.asarray(np.asarray(matrix))
-        self.p = pack_matrix_device(bins_dev, self.layout, label=md.label,
-                                    weight=md.weights if self.has_weights else None)
+        # the matrix's upload where GBDT.init's `bins_upload` has not made it
+        # (the bundled matrix), and the packing; ends in one wait, sink on or
+        # off, for a buffer the first chunk needs whole (once a Booster)
+        with tracer.stage("pack_matrix", rows=n, channels=self.layout.C) as stage:
+            if bins_dev is None:
+                bins_dev = jnp.asarray(np.asarray(matrix))
+            self.p = jax.block_until_ready(pack_matrix_device(
+                bins_dev, self.layout, label=md.label,
+                weight=md.weights if self.has_weights else None))
+            stage.attrs["bytes"] = int(self.p.nbytes)
         self.num_rows = n
         self.meta = meta
         self.hyper = hyper
@@ -791,7 +797,7 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
         # v5e, PR 33: two `copy s32[1,512,101024]`, +397 MB of temporaries).
         # The kernels address rows by `num_rows`, never by the width.
         width = -(-(nl + BLK) // 128) * 128
-        with tracer.span("shard_pack", rows=n, shards=d_local):
+        with tracer.stage("shard_pack", rows=n, shards=d_local):
             local = np.zeros((d_local, self.layout.C, width), np.int32)
             for k in range(d_local):
                 lo, hi = k * nl, min((k + 1) * nl, n)
@@ -818,6 +824,9 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                 self.p = _jax.make_array_from_single_device_arrays(gshape, sharding, bufs)
             else:
                 self.p = _jax.device_put(jnp.asarray(local), sharding)
+            # one wait, sink on or off: the stage reads the upload, not its
+            # dispatch (once a Booster)
+            jax.block_until_ready(self.p)
 
         self.meta = meta
         self.hyper = hyper

@@ -80,8 +80,7 @@ def train(
     if categorical_feature != "auto":
         train_set.categorical_feature = categorical_feature
 
-    with tracer.span("booster_init"):
-        booster = Booster(params=params, train_set=train_set)
+    booster = Booster(params=params, train_set=train_set)  # the `booster_init` stage
     tracer.event(
         "train_begin", num_boost_round=num_boost_round,
         objective=str(params.get("objective", "")),

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..config import Config
+from ..obs import tracer
 from ..utils.log import Log
 from ..utils.random import Random
 from .binning import CATEGORICAL, NUMERICAL, BinMapper
@@ -242,14 +243,16 @@ class BinnedDataset:
             ds.used_feature_map = reference.used_feature_map
         else:
             cat_set = set(int(c) for c in categorical_features) if categorical_features else set()
-            mappers = _find_bin_mappers_distributed(data, config, cat_set, sample_indices)
+            with tracer.stage("find_bins", features=num_features):
+                mappers = _find_bin_mappers_distributed(data, config, cat_set, sample_indices)
             used = [i for i, m in enumerate(mappers) if not m.is_trivial]
             if not used:
                 Log.fatal("Cannot construct Dataset: all features are trivial (constant)")
             ds.bin_mappers = [mappers[i] for i in used]
             ds.used_feature_map = np.asarray(used, dtype=np.int32)
 
-        ds.binned = _bin_matrix(data, ds.bin_mappers, ds.used_feature_map)
+        with tracer.stage("bin_rows", rows=n, features=len(ds.bin_mappers)):
+            ds.binned = _bin_matrix(data, ds.bin_mappers, ds.used_feature_map)
         return ds
 
     @classmethod
@@ -299,10 +302,14 @@ class BinnedDataset:
             return
         from .bundle import build_bundled_matrix, find_bundles
 
-        info = find_bundles(self.binned, self.bin_mappers, config)
+        # the sparse path's stage names (io/sparse.py): the same two steps
+        with tracer.stage("find_bundles", columns=self.num_features) as stage:
+            info = find_bundles(self.binned, self.bin_mappers, config)
+            stage.attrs["bundles"] = 0 if info is None else info.num_cols
         if info is not None:
             self.bundle = info
-            self.bundled = build_bundled_matrix(self.binned, self.bin_mappers, info)
+            with tracer.stage("build_bundled", rows=self.num_data, bundles=info.num_cols):
+                self.bundled = build_bundled_matrix(self.binned, self.bin_mappers, info)
 
     def create_valid(self, data, **kwargs) -> "BinnedDataset":
         """Validation dataset aligned with this dataset's bin mappers
@@ -437,6 +444,11 @@ class BinnedDataset:
 
     @classmethod
     def load_binary(cls, path: str) -> "BinnedDataset":
+        with tracer.stage("load_binary", bytes=os.path.getsize(path)):
+            return cls._load_binary(path)
+
+    @classmethod
+    def _load_binary(cls, path: str) -> "BinnedDataset":
         from ..data.cache import (
             CACHE_FORMAT_VERSION,
             open_cache_reader,

@@ -189,14 +189,14 @@ def ingest(csr, config: Config, *, categorical: set = frozenset(), sample_indice
     ``binned`` is None: nothing of ``(N, F)`` is built."""
     from .dataset import _find_bin_mappers_distributed
 
-    span = tracer.span("sparse_ingest", rows=csr.shape[0], nnz=int(csr.nnz),
-                       features=csr.shape[1])
+    span = tracer.stage("sparse_ingest", rows=csr.shape[0], nnz=int(csr.nnz),
+                        features=csr.shape[1])
     info, conflicts = None, 0
     with span:
         if reference is not None:
             mappers, used = reference.bin_mappers, np.asarray(reference.used_feature_map)
         else:
-            with tracer.span("csr_bin"):
+            with tracer.stage("csr_bin"):
                 found = _find_bin_mappers_distributed(
                     csr, config, set(categorical), sample_indices, find=find_bin_mappers)
             used = np.asarray([i for i, m in enumerate(found) if not m.is_trivial], np.int32)
@@ -204,11 +204,12 @@ def ingest(csr, config: Config, *, categorical: set = frozenset(), sample_indice
                 Log.fatal("Cannot construct Dataset: all features are trivial (constant)")
             mappers = [found[i] for i in used]
             if bundle and max(m.num_bin for m in mappers) <= 256:
-                with tracer.span("find_bundles"):
+                with tracer.stage("find_bundles", columns=len(mappers)) as found_stage:
                     info = find_bundles(csr, mappers, used, config)
-        with tracer.span("build_bundled"):
+                    found_stage.attrs["bundles"] = 0 if info is None else info.num_cols
+        with tracer.stage("build_bundled"):
             matrix, conflicts = build_columns(csr, mappers, used, info)
-        if tracer.enabled and info is not None:
+        if info is not None:
             span.attrs.update(bundle_cols=info.num_cols, max_col_bin=int(info.max_col_bin))
     if conflicts:
         Log.warning("EFB: %d of %d entries were overwritten by a later feature of "

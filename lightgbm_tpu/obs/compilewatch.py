@@ -21,12 +21,16 @@ provides two layers:
 
 Both layers are cheap enough to stay on unconditionally: the monitoring
 listener fires only on compiles, and a ``JitWatch`` call adds two cache
--size reads per invocation (the fused trainer invokes its chunk program
-once per 64 iterations).
+-size reads and two clock reads per invocation (the fused trainer invokes
+its chunk program once per 64 iterations).  A call on which the jit cache
+grew is kept as a ``program_build`` stage (obs/trace.py): what a program's
+first call cost, sink on or off; less its ``backend_s`` that is what the
+persistent cache does NOT skip (tracing to a jaxpr, lowering it to MLIR).
 """
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict
 
 from ..utils.log import Log
@@ -202,8 +206,10 @@ class JitWatch:
         # whole re-warm as retraces
         if before < self._last_cache_size:
             self._sigs.clear()
-        csecs0 = _counts["backend_compile_secs"]
+        counts0 = dict(_counts)
+        t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
+        dur = time.perf_counter() - t0
         after = self._last_cache_size = self._fn._cache_size()
         if after > before:
             self.compiles += 1
@@ -211,6 +217,15 @@ class JitWatch:
             self._compiled_spec = _abstract(args, kwargs)
             from .trace import tracer
 
+            # what this program's first call cost, up to the call's return
+            # (trace, lower, compile or cache load, dispatch; not the
+            # device's run); ``backend_s`` is the back-end compile's share,
+            # a cache load where ``cache_hit``
+            grew = {k: _counts[k] - counts0[k] for k in _counts}
+            tracer.record_stage(
+                "program_build", t0, dur, program=self.name,
+                backend_s=grew["backend_compile_secs"],
+                cache_hit=grew["cache_hits"] > 0 and grew["cache_misses"] == 0)
             if sig in self._sigs:
                 self.retraces += 1
                 Log.warning(
@@ -225,9 +240,7 @@ class JitWatch:
             else:
                 self._sigs.add(sig)
                 tracer.event("jax_trace", fn=self.name, cache_size=after)
-                self._record_cost(
-                    args, kwargs,
-                    _counts["backend_compile_secs"] - csecs0, sig)
+                self._record_cost(args, kwargs, grew["backend_compile_secs"], sig)
         return out
 
     @property

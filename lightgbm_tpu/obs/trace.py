@@ -27,11 +27,20 @@ Record schema (all records carry ``ev`` and ``ts`` = time.time()):
 Spans opened while an iteration record is open additionally accumulate
 into that iteration's ``phases`` map — that is how the per-phase
 histogram/split/partition breakdown lands on each ``iter`` record.
+
+A **stage** (``tracer.stage``) is a span that is also KEPT, in
+``tracer.stages``, whether or not a sink is configured: set-up happens
+before anybody switches a sink on, and it is measured from inside all the
+same.  With the sink off a stage costs two clock reads and one dict.  The
+rule that keeps it off the hot path: a stage is opened once a process, a
+``Dataset``, a ``Booster`` or a compiled program, never once an iteration
+or a chunk (docs/OBSERVABILITY.md lists the vocabulary).
 """
 
 from __future__ import annotations
 
 import atexit
+import collections
 import contextlib
 import json
 import os
@@ -56,6 +65,9 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 # the program's spans on a profiler trace: "lgbm:<span name>"
 ANNOTATION_PREFIX = "lgbm:"
+# tracer.stages is bounded: a process that builds boosters for days keeps
+# the newest STAGES_MAX stages
+STAGES_MAX = 512
 
 
 def _max_bytes_from_env() -> int:
@@ -86,51 +98,47 @@ _FLIGHT = None
 
 
 class _Span:
-    """An enabled span.  Besides its JSONL record it is a
-    ``jax.profiler.TraceAnnotation("lgbm:<name>")``: under a profiler
-    session the span lands on the device trace's own clock, so a reader of
-    the trace needs no wall-clock bridge to lay the program's host spans
-    over the device's idle gaps.  (Without a session the annotation is a
-    flag check.)"""
+    """An enabled span, or a stage.  Besides its JSONL record an enabled
+    span is a ``jax.profiler.TraceAnnotation("lgbm:<name>")``: under a
+    profiler session the span lands on the device trace's own clock, so a
+    reader of the trace needs no wall-clock bridge to lay the program's host
+    spans over the device's idle gaps.  (Without a session the annotation is
+    a flag check.)  A stage (``keep``) is opened with the sink off too, and
+    then is two clock reads and an entry in ``tracer.stages``: it closes by
+    the state it opened in (no annotation to exit where none was entered)
+    and its record goes where the sink is by then, because ``GBDT.init``
+    re-reads the environment inside ``booster_init``."""
 
-    __slots__ = ("_tr", "name", "attrs", "_t0", "_ann")
+    __slots__ = ("_tr", "name", "attrs", "_t0", "_ann", "_keep")
 
-    def __init__(self, tr: "Tracer", name: str, attrs: Dict[str, Any]):
+    def __init__(self, tr: "Tracer", name: str, attrs: Dict[str, Any], keep: bool = False):
         self._tr = tr
         self.name = name
         self.attrs = attrs
+        self._keep = keep
 
     def __enter__(self):
-        import jax
+        self._ann = None
+        if self._tr.enabled:
+            import jax
 
-        self._ann = jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + self.name)
-        self._ann.__enter__()
+            self._ann = jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + self.name)
+            self._ann.__enter__()
         self._tr._stack.append(self.name)
+        if self._keep:
+            self._tr._stage_stack().append(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
-        self._ann.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = self._tr
-        stack = tr._stack
-        if stack and stack[-1] is self.name:
-            stack.pop()
-        rec = {
-            "ev": "span",
-            "name": self.name,
-            "dur_s": round(dur, 9),
-            "depth": len(stack),
-            "parent": stack[-1] if stack else None,
-        }
-        if self.attrs:
-            rec.update(self.attrs)
-        tr._emit(rec)
-        agg = tr._agg.setdefault(self.name, [0.0, 0])
-        agg[0] += dur
-        agg[1] += 1
-        if tr._iter_phases is not None:
-            tr._iter_phases[self.name] = tr._iter_phases.get(self.name, 0.0) + dur
+        for stack in (tr._stack, tr._stage_stack()) if self._keep else (tr._stack,):
+            if stack and stack[-1] is self.name:
+                stack.pop()
+        tr._close_span(self.name, self._t0, dur, self.attrs, self._keep)
         return False
 
 
@@ -166,6 +174,21 @@ class Tracer:
         # test pins "near-zero when off" on this staying 0 — a counter
         # of work done, not a wall-clock estimate.
         self.work_ops = 0
+        # the newest stages kept, oldest first (``stage``): survive
+        # ``refresh_from_env()`` and ``close()``, which a traced window calls.
+        # A stage's ``depth`` and ``parent`` there count STAGES only, of its
+        # own thread: ``_stack`` holds ordinary spans too, while the sink is
+        # on, and a kept entry reads the same with the sink on or off
+        self.stages = collections.deque(maxlen=STAGES_MAX)
+        self._stage_tls = threading.local()
+
+    def _stage_stack(self) -> list:
+        """Names of the stages open in this thread, outermost first."""
+        try:
+            return self._stage_tls.stack
+        except AttributeError:
+            stack = self._stage_tls.stack = []
+            return stack
 
     # -- lifecycle -----------------------------------------------------
     def refresh_from_env(self) -> None:
@@ -290,6 +313,52 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, attrs)
+
+    def stage(self, name: str, **attrs):
+        """A span that is kept: an ordinary span in every respect while the
+        sink is on, and on or off one entry of ``self.stages`` when it
+        closes: ``name``, ``t0`` (raw ``time.perf_counter()`` at its start),
+        ``ts`` (wall clock at its end, as every record has), ``dur_s``,
+        ``depth`` and ``parent`` (among the stages open in this thread: an
+        ordinary span around it does not count, so the entry reads the same
+        with the sink on) and the attributes (``.attrs`` of what this
+        returns may be added to until it closes).  Off, that is all it does:
+        no JSON, no ``work_ops``, no annotation, no flight-recorder entry.
+        Once a ``Dataset``, a ``Booster`` or a compiled program, never once
+        an iteration or a chunk."""
+        return _Span(self, name, attrs, keep=True)
+
+    def record_stage(self, name: str, t0: float, dur_s: float, **attrs) -> None:
+        """A stage that is known to be one only when it is over (a call
+        that turned out to build a program, obs/compilewatch.py): ``t0`` and
+        ``dur_s`` are the caller's ``perf_counter`` readings.  No annotation."""
+        self._close_span(name, t0, dur_s, attrs, True)
+
+    def _close_span(self, name: str, t0: float, dur: float, attrs: Dict[str, Any],
+                    keep: bool) -> None:
+        if keep:
+            outer = self._stage_stack()
+            self.stages.append({
+                "name": name, "t0": t0, "ts": round(time.time(), 6), "dur_s": dur,
+                "depth": len(outer), "parent": outer[-1] if outer else None, **attrs})
+        if not self.enabled:
+            return
+        stack = self._stack
+        rec = {
+            "ev": "span",
+            "name": name,
+            "dur_s": round(dur, 9),
+            "depth": len(stack),
+            "parent": stack[-1] if stack else None,
+        }
+        if attrs:
+            rec.update(attrs)
+        self._emit(rec)
+        agg = self._agg.setdefault(name, [0.0, 0])
+        agg[0] += dur
+        agg[1] += 1
+        if self._iter_phases is not None:
+            self._iter_phases[name] = self._iter_phases.get(name, 0.0) + dur
 
     def counter(self, name: str, value: float = 1.0, **attrs) -> None:
         if not self.enabled:

@@ -1,85 +1,26 @@
-"""Tracing/profiling — counterpart of the reference's compile-time TIMETAG
-phase timers (serial_tree_learner.cpp:10-37, gbdt.cpp:22-63) plus the
-per-iteration wall-clock log (application.cpp:233-236).
-
-``PhaseTimers`` is a thin adapter over the structured tracer
-(obs/trace.py): every phase accumulates into the TIMETAG-style totals
-dumped at exit and, when ``LIGHTGBM_TPU_TRACE`` is set, lands as a
-structured span in the JSONL trace (feeding the per-iteration ``phases``
-breakdown).  An enabled span is also a
+"""Device-profiler captures.  The reference's compile-time TIMETAG phase
+timers (serial_tree_learner.cpp:10-37, gbdt.cpp:22-63) have no counterpart
+here: the phases of an iteration are spans of the structured tracer
+(obs/trace.py; ``boosting``, ``bagging``, ``tree``, ``train_score``,
+``valid_score`` in boosting/gbdt.py), and an enabled span is also a
 ``jax.profiler.TraceAnnotation("lgbm:<name>")``, which is what puts a host
-phase on a profiler trace's clock.  (A ``jax.named_scope`` here never
-did: a scope names operations while a program is TRACED, and these
-phases wrap calls to programs that are already compiled.  The scopes that
-do reach the compiled programs sit inside them, obs/phases.py.)  Enable
-the legacy aggregate dump with LIGHTGBM_TPU_TIMETAG=1 or
-``timetag.enable()``.
+phase on a profiler trace's clock.  (A ``jax.named_scope`` there never
+did: a scope names operations while a program is TRACED, and these phases
+wrap calls to programs that are already compiled.  The scopes that do
+reach the compiled programs sit inside them, obs/phases.py.)
 """
 
 from __future__ import annotations
 
-import atexit
 import contextlib
 import os
 import time
-from collections import defaultdict
-from typing import Dict, Iterator
+from typing import Iterator
 
 import jax
 
 from ..obs.trace import tracer
 from .log import Log
-
-
-class PhaseTimers:
-    """Accumulating named phase timers (the TIMETAG duration maps),
-    bridged onto the structured tracer."""
-
-    def __init__(self):
-        self.enabled = bool(int(os.environ.get("LIGHTGBM_TPU_TIMETAG", "0")))
-        self.totals: Dict[str, float] = defaultdict(float)
-        self.counts: Dict[str, int] = defaultdict(int)
-        self._dump_registered = False
-
-    def enable(self) -> None:
-        self.enabled = True
-        if not self._dump_registered:
-            atexit.register(self.dump)
-            self._dump_registered = True
-
-    @contextlib.contextmanager
-    def phase(self, name: str, **attrs) -> Iterator[None]:
-        """Time a phase: a structured tracer span (and with it an
-        ``lgbm:<name>`` profiler annotation) when the JSONL trace is
-        enabled, a TIMETAG total when that is."""
-        if not self.enabled and not tracer.enabled:
-            yield
-            return
-        start = time.perf_counter()
-        with tracer.span(name, **attrs):
-            yield
-        if self.enabled:
-            self.totals[name] += time.perf_counter() - start
-            self.counts[name] += 1
-
-    def dump(self) -> None:
-        """TIMETAG destructor-style dump (serial_tree_learner.cpp:12-24)."""
-        if not self.totals:
-            return
-        for name in sorted(self.totals):
-            Log.info(
-                "%s costs: %f (n=%d)", name, self.totals[name], self.counts[name]
-            )
-
-    def reset(self) -> None:
-        self.totals.clear()
-        self.counts.clear()
-
-
-timetag = PhaseTimers()
-if timetag.enabled:
-    atexit.register(timetag.dump)
-    timetag._dump_registered = True
 
 
 @contextlib.contextmanager

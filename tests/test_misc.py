@@ -92,21 +92,6 @@ def test_prediction_early_stop(small_booster):
     np.testing.assert_allclose(none_pred, full, rtol=1e-5)
 
 
-def test_phase_timers():
-    from lightgbm_tpu.utils.profiling import PhaseTimers
-
-    t = PhaseTimers()
-    t.enable()
-    with t.phase("hist"):
-        pass
-    with t.phase("hist"):
-        pass
-    assert t.counts["hist"] == 2
-    assert t.totals["hist"] >= 0.0
-    t.reset()
-    assert not t.totals
-
-
 # ----------------------------------------------------------------------
 # parser (ADVICE r1 asked for direct tests over all formats + side files)
 # ----------------------------------------------------------------------

@@ -329,6 +329,217 @@ class TestDisabledOverheadEndToEnd:
             "flight ring allocated with tracing off")
 
 
+@pytest.fixture
+def fresh_stages(monkeypatch):
+    """The process-global tracer with an empty stage list and the sink off,
+    whatever earlier tests of this worker kept (the list is bounded)."""
+    import collections
+
+    from lightgbm_tpu.obs import tracer
+    from lightgbm_tpu.obs.trace import STAGES_MAX
+
+    monkeypatch.delenv("LIGHTGBM_TPU_TRACE", raising=False)
+    tracer.close()
+    tracer.path = None
+    monkeypatch.setattr(tracer, "stages", collections.deque(maxlen=STAGES_MAX))
+    yield tracer
+    tracer.close()
+    tracer.path = None
+
+
+class TestStages:
+    """``tracer.stage``: a span that is kept with the sink off."""
+
+    def test_off_a_stage_is_two_clock_reads_and_one_kept_entry(self, monkeypatch):
+        import jax
+
+        made = []
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            lambda *a, **k: made.append(a) or pytest.fail("annotation while off"))
+        tr = Tracer()
+        t_before, wall_before = time.perf_counter(), time.time()
+        with tr.stage("outer", rows=5) as outer:
+            with tr.stage("inner"):
+                pass
+            with tr.span("plain"):  # off: the shared no-op, not on the stack
+                with tr.stage("inner"):
+                    pass
+            outer.attrs["found"] = 2
+        assert tr.work_ops == 0 and not made and tr.snapshot()["spans"] == {}
+        assert [s["name"] for s in tr.stages] == ["inner", "inner", "outer"]
+        inner, _, kept = tr.stages
+        assert (inner["parent"], inner["depth"]) == ("outer", 1)
+        assert (kept["parent"], kept["depth"], kept["rows"], kept["found"]) == (None, 0, 5, 2)
+        assert set(kept) == {"name", "t0", "ts", "dur_s", "depth", "parent", "rows", "found"}
+        # t0 on perf_counter's clock (the benchmark's spans'), ts on the wall's
+        assert t_before <= kept["t0"] <= inner["t0"] <= time.perf_counter()
+        assert wall_before <= inner["ts"] <= kept["ts"] <= time.time() + 1e-3
+        assert inner["dur_s"] + tr.stages[1]["dur_s"] <= kept["dur_s"]
+
+    def test_on_a_stage_is_an_ordinary_span_and_is_kept_too(self, fresh_tracer):
+        tr = fresh_tracer
+        with tr.span("plain"):
+            with tr.stage("find_bundles", columns=3):
+                pass
+        tr.record_stage("program_build", time.perf_counter() - 0.25, 0.25, program="p")
+        snap = tr.snapshot()["spans"]
+        assert snap["find_bundles"]["count"] == 1 and snap["program_build"]["total_s"] == 0.25
+        tr.close()
+        spans = {r["name"]: r for r in _read(tr.path) if r["ev"] == "span"}
+        assert spans["find_bundles"]["parent"] == "plain" and spans["find_bundles"]["columns"] == 3
+        assert spans["program_build"]["dur_s"] == 0.25 and spans["program_build"]["program"] == "p"
+        assert [s["name"] for s in tr.stages] == ["find_bundles", "program_build"]
+        # a kept entry's depth and parent count stages only: the ordinary span
+        # around it is there with the sink on alone, and a reader of
+        # ``tracer.stages`` gets the same tree either way
+        assert [(s["depth"], s["parent"]) for s in tr.stages] == [(0, None), (0, None)]
+        summary = report.summarize(report.load_trace(tr.path))
+        assert summary["spans"]["find_bundles"]["count"] == 1
+
+    def test_a_stage_closes_by_the_state_it_opened_in(self, tmp_path, monkeypatch):
+        """``GBDT.init`` re-reads the environment INSIDE ``booster_init``: no
+        annotation to exit where none was entered, and the record goes where
+        the sink is by then; and the other way round."""
+        import jax
+
+        entered = []
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                entered.remove(self.name)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+        tr = Tracer()
+        path = str(tmp_path / "late.jsonl")
+        with tr.stage("booster_init"):
+            tr.configure(path)
+            with tr.stage("bins_upload"):
+                assert entered == ["lgbm:bins_upload"]
+        assert entered == []
+        with tr.stage("opened_on"):
+            tr.close()
+        assert entered == []  # the annotation it entered was exited
+        names = [r["name"] for r in _read(path) if r["ev"] == "span"]
+        assert names == ["bins_upload", "booster_init"]
+        assert [s["name"] for s in tr.stages] == ["bins_upload", "booster_init", "opened_on"]
+
+    def test_the_list_is_bounded_and_survives_refresh_and_close(self, tmp_path, monkeypatch):
+        from lightgbm_tpu.obs.trace import STAGES_MAX
+
+        tr = Tracer()
+        for i in range(STAGES_MAX + 7):
+            with tr.stage("s", i=i):
+                pass
+        assert len(tr.stages) == STAGES_MAX == 512
+        # the newest are kept: a process that retrains reads its last Booster
+        assert (tr.stages[0]["i"], tr.stages[-1]["i"]) == (7, STAGES_MAX + 6)
+        monkeypatch.setenv("LIGHTGBM_TPU_TRACE", str(tmp_path / "t.jsonl"))
+        tr.refresh_from_env()
+        assert tr.enabled and len(tr.stages) == STAGES_MAX
+        tr.close()
+        monkeypatch.delenv("LIGHTGBM_TPU_TRACE")
+        tr.refresh_from_env()
+        assert not tr.enabled and len(tr.stages) == STAGES_MAX
+
+    def test_a_stage_nests_among_the_stages_of_its_own_thread(self):
+        """A Booster built in a second thread while this one is inside a
+        stage is nobody's child, and leaves nothing on this thread's stack."""
+        import threading
+
+        tr = Tracer()
+
+        def other():
+            with tr.stage("booster_init"):
+                with tr.stage("bins_upload"):
+                    pass
+
+        with tr.stage("dataset_construct"):
+            t = threading.Thread(target=other)
+            t.start()
+            t.join()
+            with tr.stage("load_binary"):
+                pass
+        with tr.stage("after"):
+            pass
+        got = {s["name"]: (s["depth"], s["parent"]) for s in tr.stages}
+        assert got == {"bins_upload": (1, "booster_init"), "booster_init": (0, None),
+                       "load_binary": (1, "dataset_construct"),
+                       "dataset_construct": (0, None), "after": (0, None)}
+
+    def test_program_build_is_kept_when_the_jit_cache_grew(self, fresh_stages):
+        import jax
+        import jax.numpy as jnp
+
+        w = JitWatch(jax.jit(lambda x: jnp.cumsum(x) - 2), name="test.program_build")
+        w(jnp.ones((5,)))
+        (built,) = fresh_stages.stages
+        assert built["name"] == "program_build" and built["program"] == "test.program_build"
+        assert set(built) == {"name", "t0", "ts", "dur_s", "depth", "parent", "program",
+                              "backend_s", "cache_hit"}
+        assert isinstance(built["cache_hit"], bool)
+        # the call's own clock: the back-end compile is inside it, and what is
+        # left (trace, lowering, dispatch) is what no compile cache removes
+        assert 0 < built["backend_s"] < built["dur_s"]
+        for _ in range(3):
+            w(jnp.ones((5,)))  # jit cache hits: no stage
+        assert len(fresh_stages.stages) == 1
+        w(jnp.ones((6,)))
+        assert [s["program"] for s in fresh_stages.stages] == ["test.program_build"] * 2
+        assert fresh_stages.work_ops == fresh_stages.work_ops and not fresh_stages.enabled
+
+    def test_program_build_is_top_level_under_an_ordinary_span(self, fresh_stages,
+                                                               global_trace):
+        """Sink on, the trainer's ``chunk_program`` span is around the call
+        that builds the program; the kept entry still reads depth 0, as it
+        does with the sink off, while the JSONL span nests as spans do."""
+        import jax
+        import jax.numpy as jnp
+
+        tr = fresh_stages
+        tr.refresh_from_env()
+        w = JitWatch(jax.jit(lambda x: jnp.sin(x) + 5), name="test.under_a_span")
+        with tr.span("chunk_program"):
+            w(jnp.ones((3,)))
+        tr.close()
+        (built,) = tr.stages
+        assert (built["name"], built["depth"], built["parent"]) == ("program_build", 0, None)
+        by = {r["name"]: r for r in _read(global_trace) if r["ev"] == "span"}
+        assert by["program_build"]["parent"] == "chunk_program"
+
+    def test_sink_on_the_stages_arrive_as_spans_and_report_aggregates_them(
+            self, fresh_stages, global_trace, monkeypatch):
+        monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
+        X, y = _toy(600)
+        params = {"objective": "binary", "num_leaves": 7, "verbose": -1}
+        lgb.train(params, lgb.Dataset(X, label=y, params=params), num_boost_round=2,
+                  verbose_eval=False)
+        fresh_stages.close()
+        recs = _read(global_trace)
+        spans = [r for r in recs if r["ev"] == "span"]
+        by = {s["name"]: s for s in spans}
+        assert {"dataset_construct", "find_bins", "bin_rows", "booster_init", "bins_upload",
+                "find_bundles", "pack_matrix", "program_build"} <= set(by)
+        assert by["dataset_construct"]["parent"] == "booster_init"
+        assert by["find_bins"]["parent"] == by["bin_rows"]["parent"] == "dataset_construct"
+        assert by["bins_upload"]["parent"] == by["pack_matrix"]["parent"] == "booster_init"
+        assert by["program_build"]["parent"] == "chunk_program"
+        # engine.train opens no booster_init of its own: one Booster, one span
+        assert sum(s["name"] == "booster_init" for s in spans) == 1
+        summary = report.summarize(recs)
+        assert summary["spans"]["booster_init"]["count"] == 1
+        assert summary["spans"]["program_build"]["total_s"] > 0
+        # the kept tree is the sink-off tree: `program_build` under no stage
+        kept = {s["name"]: s for s in fresh_stages.stages}
+        assert kept["program_build"]["depth"] == 0 and kept["booster_init"]["depth"] == 0
+        assert kept["dataset_construct"]["parent"] == "booster_init"
+
+
 class TestFlightRecorder:
     def test_ring_bounded_and_dump_contents(self, tmp_path, monkeypatch):
         from lightgbm_tpu.obs import flight
@@ -623,7 +834,7 @@ class TestNameRegistryLint:
     docs/OBSERVABILITY.md name registry."""
 
     TRACER_PAT = re.compile(
-        r'tracer\.(?:span|counter|gauge|event)\(\s*[\'"]([A-Za-z0-9_.]+)[\'"]')
+        r'tracer\.(?:span|stage|record_stage|counter|gauge|event)\(\s*[\'"]([A-Za-z0-9_.]+)[\'"]')
     METRIC_PAT = re.compile(
         r'(?:registry|reg)\.(?:labeled_)?(?:counter|gauge|histogram)\(\s*\n?\s*'
         r'[\'"]([A-Za-z0-9_:]+)[\'"]')

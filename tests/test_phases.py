@@ -346,13 +346,27 @@ def _booster(X, y, learner="serial"):
     return bst
 
 
+@pytest.fixture
+def fresh_stages(monkeypatch, tracing_off):
+    """`tracer.stages` empty, whatever earlier tests of this worker kept."""
+    monkeypatch.setattr(tracer, "stages", [])
+    return tracer.stages
+
+
+# the stages that end in work handed to the device, each with ONE wait
+DEVICE_STAGES = {"bins_upload", "pack_matrix", "shard_pack"}
+
+
 @pytest.mark.parametrize("learner", ["serial", "data"])
-def test_tracing_off_costs_nothing(tracing_off, counted, learner, monkeypatch):
-    """No record, no TraceAnnotation, no block_until_ready from the new spans,
-    no re-lowering: the untraced path issues the calls it issued before.  The
-    data-parallel trainer's `shard_pack` span and all-reduce counters too:
+def test_tracing_off_costs_nothing(fresh_stages, counted, learner, monkeypatch):
+    """No record, no TraceAnnotation, no re-lowering, and no block_until_ready
+    but the one that ends each stage that hands work to the device, in
+    construction: the untraced path issues the calls it issued before.  The
+    data-parallel trainer's `shard_pack` stage and all-reduce counters too:
     construction is inside what is counted.  Nor is a shape count made for a
-    span nobody writes (`perm_tiles`, PR 37, stands for `stream_counts`)."""
+    span nobody writes (`perm_tiles`, PR 37, stands for `stream_counts`).  A
+    stage is opened once a Dataset, a Booster or a program: the first chunk
+    adds its program's `program_build`, the chunks after it add no stage."""
     from unittest import mock
 
     from lightgbm_tpu.boosting import ptrainer as ptrainer_mod
@@ -366,11 +380,97 @@ def test_tracing_off_costs_nothing(tracing_off, counted, learner, monkeypatch):
     bst = _booster(X, y, learner)
     assert type(bst.boosting.ptrainer).__name__ == (
         "ShardedPartitionedTrainer" if learner == "data" else "PartitionedTrainer")
+    built = [s["name"] for s in fresh_stages]
+    waits = [n for n in built if n in DEVICE_STAGES]
+    assert waits == ["bins_upload", "shard_pack" if learner == "data" else "pack_matrix"]
+    assert counted == {"annotation": 0, "block": len(waits), "phase_map": 0}
+    assert len(built) < 32 and "program_build" not in built
+    bst.boosting.train_iters_partitioned(2, is_eval=False)
+    first = fresh_stages[len(built):]
+    assert [s["name"] for s in first] == ["program_build"]
+    assert first[0]["program"].startswith("ptrainer.") and first[0]["depth"] == 0
+    assert 0 < first[0]["backend_s"] < first[0]["dur_s"]
     for _ in range(2):
         bst.boosting.train_iters_partitioned(2, is_eval=False)
+    assert len(fresh_stages) == len(built) + 1  # the second and third chunk: no stage
     assert tracer.work_ops == work
-    assert counted == {"annotation": 0, "block": 0, "phase_map": 0}
+    assert counted == {"annotation": 0, "block": len(waits), "phase_map": 0}
     assert not tiles.called
+
+
+def _tree_of(stages):
+    """{parent name: [children, oldest first]} of the kept stages."""
+    kids = {}
+    for s in stages:
+        kids.setdefault(s["parent"], []).append(s)
+    return kids
+
+
+@pytest.mark.parametrize("source", ["matrix", "binary", "sparse"])
+def test_a_booster_leaves_its_setup_as_nested_stages(fresh_stages, counted, source, tmp_path):
+    """With the sink off: `dataset_construct` and `booster_init` with the
+    children of that way in, nested, their seconds inside their parent's."""
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1}
+    X, y, _ = _toy()
+    if source == "sparse":
+        table = _one_hot_csr()
+    elif source == "binary":
+        path = str(tmp_path / "table.bin")
+        lgb.Dataset(X, label=y, params=params).construct().save_binary(path)
+        del fresh_stages[:]
+        table = path
+    else:
+        table = X
+    work = tracer.work_ops
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(table, label=y, params=params))
+    assert bst.boosting.ptrainer is not None
+    assert tracer.work_ops == work and counted["annotation"] == 0
+    kids = _tree_of(fresh_stages)
+    (booster,) = kids[None]
+    assert booster["name"] == "booster_init" and booster["depth"] == 0
+    under_booster = [s["name"] for s in kids["booster_init"]]
+    (ds,) = [s for s in kids["booster_init"] if s["name"] == "dataset_construct"]
+    assert (ds["source"], ds["rows"], ds["depth"]) == (source, 300, 1)
+    under_ds = [s["name"] for s in kids["dataset_construct"]]
+    if source == "sparse":  # the bundles are made at ingest and uploaded by the trainer
+        assert under_ds == ["sparse_ingest"]
+        assert [s["name"] for s in kids["sparse_ingest"]] == ["csr_bin", "find_bundles",
+                                                              "build_bundled"]
+        (found,) = [s for s in kids["sparse_ingest"] if s["name"] == "find_bundles"]
+        assert found["columns"] == 16 and found["bundles"] == 3 and found["depth"] == 3
+        assert under_booster == ["dataset_construct", "objective_init", "trainer_import",
+                                 "pack_matrix"]
+    else:
+        assert under_ds == (["load_binary"] if source == "binary" else ["find_bins", "bin_rows"])
+        if source == "binary":
+            assert kids["dataset_construct"][0]["bytes"] == os.path.getsize(table)
+        assert under_booster == ["dataset_construct", "objective_init", "bins_upload",
+                                 "trainer_import", "find_bundles", "pack_matrix"]
+        upload, found = kids["booster_init"][2], kids["booster_init"][4]
+        assert upload["bytes"] == 300 * 4 and found["columns"] == 4 and found["bundles"] == 0
+    pack = kids["booster_init"][-1]
+    assert pack["rows"] == 300 and pack["channels"] == 16 and pack["bytes"] == bst.boosting.ptrainer.p.nbytes
+    for parent, children in kids.items():
+        if parent is not None:
+            (p,) = [s for s in fresh_stages if s["name"] == parent]
+            assert sum(c["dur_s"] for c in children) <= p["dur_s"]
+            assert all(c["depth"] == p["depth"] + 1 and p["t0"] <= c["t0"] for c in children)
+    assert counted["block"] == sum(s["name"] in DEVICE_STAGES for s in fresh_stages)
+    # an already constructed Dataset opens nothing
+    n = len(fresh_stages)
+    assert bst.train_dataset.construct() is bst.train_dataset.construct()
+    assert len(fresh_stages) == n
+
+
+def test_shard_pack_is_a_stage_with_its_shards(fresh_stages):
+    if len(jax.devices()) < 4:
+        pytest.skip("needs a multi-device mesh")
+    X, y, _ = _toy()
+    bst = _booster(X, y, "data")
+    (pack,) = [s for s in fresh_stages if s["name"] == "shard_pack"]
+    assert pack["shards"] == bst.boosting.ptrainer.d == len(jax.devices())
+    assert (pack["rows"], pack["parent"], pack["depth"]) == (300, "booster_init", 1)
+    assert "pack_matrix" not in [s["name"] for s in fresh_stages]
 
 
 @pytest.fixture
@@ -404,8 +504,10 @@ def test_nested_spans_are_each_right_alone(traced_chunks):
         assert wait["dur_s"] + d2h["dur_s"] <= fetch["dur_s"]
         assert wait["parent"] == d2h["parent"] == "records_fetch"
         assert wait["depth"] == d2h["depth"] == fetch["depth"] + 1
-    # the fence ran (tracing is on) and every span was an annotation too
-    assert calls["block"] >= 2 and calls["annotation"] >= len(spans)
+    # the fence ran (tracing is on) and every span was an annotation too, but
+    # `program_build`: a call is known to have built a program once it is over
+    assert [s["parent"] for s in by["program_build"]] == ["chunk_program"]
+    assert calls["block"] >= 2 and calls["annotation"] >= len(spans) - 1
     trees = by["trees_from_records"]
     assert [t["trees"] for t in trees] == [2, 2]
     models = bst.boosting.models
