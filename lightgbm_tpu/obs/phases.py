@@ -41,7 +41,7 @@ CHUNK_EPILOGUE = "chunk_epilogue"    # settle the last delta, scores to original
 # (replay).  One word cannot be enclosed by three, so `phase_of` keys such an
 # instruction "<that phase>/bundle_expand" (`nested`), and ENCLOSING hands each
 # key to the phase whose readers should still count it.
-BUNDLE_EXPAND = "bundle_expand"      # (G, BH, 3) bundle histograms -> (F, B, 3) per feature
+BUNDLE_EXPAND = "bundle_expand"      # a bundle histogram's (G, BH) planes -> (F, B) per feature
 
 PHASES = (CANON_REORDER, SAMPLE, UPDATE_ROOT_HIST, LEVEL_PHASE, SPLIT_SCAN, REPLAY,
           REPLAY_TAIL, LEAF_DELTA, SCORE_ADD, CHUNK_EPILOGUE, BUNDLE_EXPAND)

@@ -25,8 +25,11 @@ Design notes (v2, measured on v5e):
   recs) updated with one scatter each: per-op dispatch inside a TPU
   while_loop body costs ~1-2 us, so the old ~25 small updates were a
   measured ~150 us/split tax.
-- Left/right split search runs as ONE vmapped call over the stacked
-  (2, F, B, 3) children histograms.
+- Left/right split search runs as ONE vmapped call over the children's
+  g, h and count planes, three arrays (2, F, B): the cells as the kernels
+  emit them, one lane a cell.  No (..., B, 3) histogram is built on this
+  path (PR 40): with its 3 on the lanes such an array takes 43 times its
+  payload.
 - The carry contract (PR 27): the packed matrix goes loop carry ->
   aliased kernel -> loop carry and through NO ``lax.cond``, here and in
   the chunk programs that inline this grower (boosting/ptrainer.py).
@@ -68,18 +71,18 @@ from .histogram_pallas import hist_segments
 from .pkernels import (
     BLK,
     PLayout,
-    _hist_from_rows,
     hist_dyn,
     hist_lanes,
-    hists_from_planes,
     level_stream,
+    plane_cells,
+    sibling_planes,
     split_stream,
 )
 from .split import (
     NEG_INF,
     FeatureMeta,
     SplitHyper,
-    best_split_per_feature,
+    best_split_planes,
     finalize_split,
     leaf_output,
 )
@@ -146,10 +149,16 @@ def level_slots(num_leaves: int) -> int:
 
 
 # Kernel rows one batch of the level's split search reads (``scan_batch``).
-# Measured at 2,000 columns on the v5e (my chip run, PR 34, call A; ms of
-# `split_scan` an iteration at 4 / 8 / 16 / 32 slots a batch: 72.7 / 81.2 /
-# 108.0 / 159.8, and 953.3 at all 256): a slot costs less in a short batch, and
-# a level's last batch visits fewer slots that hold nothing.
+# Swept twice at 2,000 columns on the v5e, at 4 / 8 / 16 / 32 slots a batch.
+# PR 34, when a slot's temporaries were (..., B, 3) arrays, 43 times their
+# payload (my chip run, call A; ms of `split_scan` an iteration): 72.7 / 81.2 /
+# 108.0 / 159.8, and 953.3 at all 256.  PR 40, on planes, one lane a cell (my
+# chip run: ``level_split_scan`` alone on random kernel rows, ms over a full
+# tree's nine levels of 1, 2, 4 ... 128, 128 active slots): 35.5 / 63.5 / 52.2 /
+# 58.7, where the parent's search read 107.3 / 104.1 / 140.6 / 186.9; 2 slots
+# 37.0, 1 slot 45.9.  Four still wins: a slot costs 0.082 ms there and 0.11-0.15
+# in a longer batch, and a level's last batch visits fewer slots that hold
+# nothing.  In the cell: 72.7 -> 21.7 ms an iteration.
 SCAN_BATCH_BYTES = 32 << 20
 
 
@@ -180,10 +189,9 @@ def level_split_scan(find2, hists, sums2, dok2, n_act, batch: int,
     smax = hists.shape[0]
 
     def scan(hists, sums2, dok2):
-        hist_l = jax.vmap(lambda h: _hist_from_rows(h, num_cols, num_bins, row0=0))(hists)
-        hist_r = jax.vmap(lambda h: _hist_from_rows(h, num_cols, num_bins, row0=7))(hists)
-        hist2 = jnp.stack([hist_l, hist_r], axis=1)  # (slots, 2, G, BH, 3)
-        return jax.vmap(find2)(hist2, sums2, dok2)  # fields (slots, 2)
+        planes2 = tuple(plane_cells(x, num_cols, num_bins)  # (slots, 2, G, BH) each
+                        for x in sibling_planes(hists))
+        return jax.vmap(find2)(planes2, sums2, dok2)  # fields (slots, 2)
 
     if batch >= smax:
         return scan(hists, sums2, dok2), jnp.int32(smax)
@@ -201,6 +209,13 @@ def level_split_scan(find2, hists, sums2, dok2, n_act, batch: int,
     tables0 = jax.tree.map(
         lambda a: jnp.zeros((smax,) + a.shape[1:], a.dtype), one_batch)
     return jax.lax.fori_loop(0, trips, step, tables0), trips * batch
+
+
+def child_cells(planes, num_cols: int, num_bins: int):
+    """``child_planes``, (6, lanes) -> what ``find2`` takes: both children's
+    g, h and count planes, three arrays (2, G, BH), the bins on the minor
+    axis."""
+    return tuple(plane_cells(planes[k::3], num_cols, num_bins) for k in range(3))
 
 
 def levelgrow_env_params() -> dict:
@@ -228,17 +243,16 @@ class BundleMeta(NamedTuple):
     defmask: jnp.ndarray  # (F, B) bool
 
 
-def _expand_bundle_hist(hist_g, sums, bmeta: BundleMeta, f: int, b: int):
-    """(G, BH, 3) bundle histogram -> (F, B, 3) per-feature histograms.
-    Under the ``bundle_expand`` scope wherever it is called from (the
-    root's search, a level's, a tail split's): at 700 features in 10
-    bundle columns it is a gather of 44,100 cells a histogram."""
+def _expand_bundle_plane(plane, total, bmeta: BundleMeta, f: int, b: int):
+    """One (G, BH) plane of a bundle histogram -> the (F, B) plane of the
+    per-feature histograms.  Under the ``bundle_expand`` scope wherever it is
+    called from (the root's search, a level's, a tail split's): at 700
+    features in 10 bundle columns it is a gather of 44,100 cells a plane."""
     with jax.named_scope(BUNDLE_EXPAND):
-        flat = jnp.concatenate([hist_g.reshape(-1, 3), jnp.zeros((1, 3))], axis=0)
-        hf = flat[bmeta.idx.reshape(-1)].reshape(f, b, 3)
-        nd_sums = jnp.sum(hf, axis=1)  # (F, 3): non-default mass
-        dfl = sums[None, :] - nd_sums
-        return jnp.where(bmeta.defmask[:, :, None], dfl[:, None, :], hf)
+        flat = jnp.concatenate([plane.reshape(-1), jnp.zeros((1,))])
+        hf = flat[bmeta.idx.reshape(-1)].reshape(f, b)
+        dfl = total - jnp.sum(hf, axis=1)  # (F,): leaf total less non-default mass
+        return jnp.where(bmeta.defmask, dfl[:, None], hf)
 
 
 class PTreeResult(NamedTuple):
@@ -316,22 +330,22 @@ def sibling_split_search(params: PGrowParams, meta: FeatureMeta, hyper: SplitHyp
     """``find2`` of ``params``: the best split of two sibling leaves at once."""
     F, B = params.num_features, params.num_bins
 
-    def find2(hist2, sums2, depth_ok):
-        """Best split for sibling leaves at once: hist2 (2, G/F, B, 3),
-        sums2 (2, 3) -> per-leaf scalars stacked on axis 0."""
-        if bmeta is not None:
-            hist2 = jax.vmap(
-                lambda hh, ss: _expand_bundle_hist(hh, ss, bmeta, F, B)
-            )(hist2, sums2)
+    def find2(planes2, sums2, depth_ok):
+        """Best split for sibling leaves at once: planes2 the children's g,
+        h and count planes (``child_cells``), three arrays (2, G, BH), sums2
+        (2, 3) -> per-leaf scalars stacked on axis 0."""
 
-        def one(hist, s):
-            gain_f, thr_f, dbz_f, left_f = best_split_per_feature(
-                hist, s[0], s[1], s[2], meta, hyper, feature_mask,
+        def one(planes, s):
+            if bmeta is not None:
+                planes = [_expand_bundle_plane(x, s[k], bmeta, F, B)
+                          for k, x in enumerate(planes)]
+            gain_f, thr_f, dbz_f, left_f = best_split_planes(
+                *planes, s[0], s[1], s[2], meta, hyper, feature_mask,
                 params.use_missing, has_categorical=params.has_categorical,
             )
             return finalize_split(gain_f, thr_f, dbz_f, left_f, s[0], s[1], s[2], hyper)
 
-        res = jax.vmap(one)(hist2, sums2)
+        res = jax.vmap(one)(planes2, sums2)
         return res._replace(gain=jnp.where(depth_ok, res.gain, NEG_INF))
 
     return find2
@@ -406,7 +420,9 @@ def grow_tree_partitioned(
                 root_hist = jax.lax.psum(root_hist, params.axis_name)
         # (callers passing root_hist in data-parallel mode psum it themselves)
         root_sums = jnp.sum(root_hist[0], axis=0)  # (3,): totals via feature 0
-        rr = find2(jnp.stack([root_hist, root_hist]),
+        # once a tree, from the (G, BH, 3) histogram the chunk program hands
+        # over (and all-reduces): the one search that is given that form
+        rr = find2(tuple(jnp.stack([root_hist[..., k]] * 2) for k in range(3)),
                    jnp.stack([root_sums, root_sums]), jnp.array(True))
 
         root_val = leaf_output(root_sums[0], root_sums[1], hyper.lambda_l1, hyper.lambda_l2)
@@ -614,17 +630,16 @@ def grow_tree_partitioned(
         off_hi = mrow[4].astype(jnp.int32)
         bias = mrow[5].astype(jnp.int32)
         with jax.named_scope(REPLAY_TAIL):
-            # hists: (left, right), each (G, BH, 3); under ``axis_name`` the
-            # six ``child_planes`` in one array, for the all-reduce below
-            p, nl, *hists = split_stream(
+            # planes: both children's ``child_planes``, (6, lanes)
+            p, nl, planes = split_stream(
                 st.p, jnp.where(has_pre, 0, start), jnp.where(has_pre, 0, cnt),
                 colidx // per, (colidx % per) * params.bits, zb, dbz, thr, cat,
                 off_lo=off_lo, off_hi=off_hi, bias=bias,
                 num_features=G, num_bins=BH, bits=params.bits, rows=rows,
-                interpret=interpret, planes=bool(params.axis_name),
+                interpret=interpret,
             )
 
-        def take_pre(nl, *hists):
+        def take_pre(nl, planes):
             clo = jnp.clip(childlo, 0, CANDMAX - 1)
             chi = jnp.clip(childlo + 1, 0, CANDMAX - 1)
             seg2 = jnp.stack([c_seg[clo], c_seg[chi]])
@@ -633,25 +648,22 @@ def grow_tree_partitioned(
             ps2 = jnp.stack([clo, chi])
             return seg2, bs2, leaf2, ps2
 
-        def take_classic(nl, *hists):
+        def take_classic(nl, planes):
             with jax.named_scope(REPLAY_TAIL):
                 if params.axis_name:
                     # global children histograms; the split decision below is
                     # then bit-identical on every device (local segments
                     # diverge, the tree does not).  Inside the branch: a
                     # precomputed split needs no collective, and has_pre
-                    # is replicated.  Reduced as PLANES, one lane a cell,
-                    # and laid out as (2, G, BH, 3) after: that array has
-                    # its 3 on the lanes (`{3,0,2,1:T(2,128)}`), 129 MB for
-                    # 3 MB at 2,000 columns, and an all-reduce of it takes
-                    # 2.65 ms where this one takes 0.12.  The seed moves
-                    # how many tail splits a tree takes, so what one costs
-                    # is what an iteration differs by from seed to seed (my
-                    # chip runs, PR 33).  The same 2 x G x BH x 3 sums.
-                    hist2 = hists_from_planes(
-                        jax.lax.psum(hists[0], params.axis_name), G, BH)
-                else:
-                    hist2 = jnp.stack(hists)
+                    # is replicated.  Reduced as the kernel's PLANES, one
+                    # lane a cell (0.12 ms at 2,000 columns, where the
+                    # all-reduce of a (2, G, BH, 3) array, its 3 on the
+                    # lanes, took 2.65; my chip runs, PR 33): the one thing
+                    # the serial and the sharded tail differ by.  The seed
+                    # moves how many tail splits a tree takes, so what one
+                    # costs is what an iteration differs by from seed to seed.
+                    planes = jax.lax.psum(planes, params.axis_name)
+                planes2 = child_cells(planes, G, BH)
 
             right = totals - left
             sums2 = jnp.stack([left, right])  # (2, 3)
@@ -662,7 +674,7 @@ def grow_tree_partitioned(
                 if params.max_depth <= 0
                 else child_depth < params.max_depth
             )
-            res2 = find2(hist2, sums2, depth_ok)
+            res2 = find2(planes2, sums2, depth_ok)
 
             seg2 = jnp.stack(
                 [jnp.stack([start, nl]), jnp.stack([start + nl, cnt - nl])]
@@ -683,7 +695,7 @@ def grow_tree_partitioned(
             return seg2, bs2, leaf2, ps2
 
         seg2, bs2, leaf2, ps2 = jax.lax.cond(
-            has_pre, take_pre, take_classic, nl, *hists
+            has_pre, take_pre, take_classic, nl, planes
         )
         # child outputs are recomputed HERE, at one shared (2,)-shaped
         # site outside the cond, from the children's g/h sums.  The

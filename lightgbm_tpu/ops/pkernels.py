@@ -453,13 +453,22 @@ def _tile_bytes(c: int, n: int = 1) -> int:
 
 
 def _planes_from_rows(out, row0=0):
-    """(Σ 3-term g, Σ 3-term h, cnt) kernel rows of ``hist_lanes`` lanes
-    -> the g, h and count planes, one lane a cell."""
-    return (
-        out[row0 + 0] + (out[row0 + 1] + out[row0 + 2]),
-        out[row0 + 3] + (out[row0 + 4] + out[row0 + 5]),
-        out[row0 + 6],
-    )
+    """(Σ 3-term g, Σ 3-term h, cnt) kernel rows of ``hist_lanes`` lanes,
+    (..., rows, lanes) -> the g, h and count planes, (..., lanes) each, one
+    lane a cell."""
+    def row(i):
+        return out[..., row0 + i, :]
+
+    return (row(0) + (row(1) + row(2)), row(3) + (row(4) + row(5)), row(6))
+
+
+def sibling_planes(out):
+    """``split_stream``'s kernel rows, or every slot's of ``level_stream``,
+    (..., 16, lanes) -> both children's g, h and count planes, three arrays
+    (..., 2, lanes), left child then right: the left's seven rows and the
+    right's seven are one axis folded in two, so no plane is stacked."""
+    rows = out[..., :14, :]
+    return _planes_from_rows(rows.reshape(rows.shape[:-2] + (2, 7, rows.shape[-1])))
 
 
 def _hist_from_rows(out, num_features, num_bins, row0=0):
@@ -471,6 +480,17 @@ def _hist_cells(g, h, cnt, num_features, num_bins):
     pitch = bin_pitch(num_bins)
     hist = jnp.stack([g, h, cnt], axis=1)[: num_features * pitch]
     return hist.reshape(num_features, pitch, 3)[:, :num_bins]
+
+
+def plane_cells(planes, num_features, num_bins):
+    """Planes of ``hist_lanes`` lanes, (..., lanes) -> (..., F, B): every
+    column's ``bin_pitch`` lanes less their padding, the bins still on the
+    minor axis.  What the fused engine's split search reads
+    (ops/split.py ``best_split_planes``); ``_hist_cells`` is the (F, B, 3)
+    form of the same cells, for the root and for ``hist_dyn``'s callers."""
+    pitch = bin_pitch(num_bins)
+    cells = planes[..., : num_features * pitch]
+    return cells.reshape(planes.shape[:-1] + (num_features, pitch))[..., :num_bins]
 
 
 # ======================================================================
@@ -1062,7 +1082,7 @@ def _run_segment(
     *, c, bits, nf, nb, rows,
 ):
     """One pass over one parent segment: stable-unordered in-place
-    partition by the split predicate + (F, B, 3) histograms of BOTH
+    partition by the split predicate + the histogram rows of BOTH
     children accumulated into ``hist_ref`` (caller zeroes it and builds
     ``tri_ref`` once).  Returns the left-child row count.
 
@@ -1637,18 +1657,19 @@ def level_stream(p, seg_tab, n_active, *, num_features, num_bins, bits=8,
     return p, nl, hist
 
 
-@functools.partial(jax.jit, static_argnames=("num_features", "num_bins", "bits", "rows", "interpret", "planes"),
+@functools.partial(jax.jit, static_argnames=("num_features", "num_bins", "bits", "rows", "interpret"),
                    donate_argnums=(0,))
 def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
                  off_lo=0, off_hi=256, bias=0, *, num_features, num_bins,
-                 bits=8, rows=None, interpret=False, planes=False):
+                 bits=8, rows=None, interpret=False):
     """Partition the leaf segment [start, start+cnt) of ``p`` in place by
     the split predicate AND return both children's histograms from the
     same pass.
 
     Lefts land at [start, start+nl), rights at [start+nl, start+cnt)
-    (order within each child unspecified).  Returns (p', nl, left_hist
-    (F, B, 3), right_hist), or (p', nl, ``child_planes``) under ``planes``."""
+    (order within each child unspecified).  Returns (p', nl,
+    ``child_planes``): both children's histograms as six planes of
+    ``hist_lanes`` lanes."""
     if rows is None:
         wpad = -(-num_words(num_features, bits) // 8) * 8
         rows = (wpad, wpad + 1, wpad + 2)
@@ -1692,26 +1713,19 @@ def split_stream(p, start, cnt, word, shift, zero_bin, dbz, thr, is_cat,
         interpret=interpret,
         name="split_stream",
     )(sv, p)
-    if planes:
-        return p, nl[0], child_planes(hist)
-    left = _hist_from_rows(hist, num_features, num_bins, row0=0)
-    right = _hist_from_rows(hist, num_features, num_bins, row0=7)
-    return p, nl[0], left, right
+    return p, nl[0], child_planes(hist)
 
 
 def child_planes(out):
-    """``split_stream``'s kernel rows -> (6, hist_lanes): the left child's
-    g, h and count planes, then the right's.  What the data-parallel tail
-    all-reduces: the planes are dense, where a ``(F, B, 3)`` histogram has
-    its 3 on the lanes and 125 of every 128 lanes padding."""
+    """``split_stream``'s kernel rows (a slot's of ``level_stream``) ->
+    (6, hist_lanes): the left child's g, h and count planes, then the
+    right's, one lane a cell.  What the split search reads (through
+    ``plane_cells``) and what the data-parallel tail all-reduces: the
+    planes are dense, where a ``(F, B, 3)`` histogram has its 3 on the
+    lanes and 125 of every 128 lanes padding.  (Six rows stacked, not
+    ``sibling_planes`` reshaped: the compiler moves a reshape through the
+    all-reduce, whose operand the compile tests pin as ``f32[6, lanes]``.)"""
     return jnp.stack(_planes_from_rows(out, 0) + _planes_from_rows(out, 7))
-
-
-def hists_from_planes(planes, num_features, num_bins):
-    """``child_planes`` (summed over the shards) -> (2, F, B, 3): what
-    ``split_stream`` returns without ``planes``, left child then right."""
-    return jnp.stack([_hist_cells(*planes[0:3], num_features, num_bins),
-                      _hist_cells(*planes[3:6], num_features, num_bins)])
 
 
 # ======================================================================

@@ -1,4 +1,4 @@
-"""Vectorized best-split search over a (F, B, 3) histogram tensor.
+"""Vectorized best-split search over a histogram's three (F, B) planes.
 
 Counterpart of FeatureHistogram::FindBestThreshold*
 (src/treelearner/feature_histogram.hpp:71-198, 253-387).  The reference
@@ -29,6 +29,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 # host constant: a jnp scalar here would initialize the XLA backend at
@@ -122,14 +123,31 @@ def leaf_split_gain_given_output(sum_g, sum_h, l1, l2, output):
     return -(2.0 * sg_l1 * output + (sum_h + l2) * output * output)
 
 
+def _pick(x, idx):
+    """``x[f, idx[f]]`` of an (F, n) plane, (F,): SELECTED, by comparing the
+    minor axis with the index and OR-ing the one hit's bits out of zeros
+    (-0.0 stays -0.0), so that it fuses into whatever reads ``x`` in
+    whatever layout that has.  A ``take_along_axis`` here is a gather: under
+    the grower's ``vmap`` over slots and siblings the TPU compiler answers it
+    by copying the whole plane with the BATCH on the lanes (`f32[4,2,1,2000,63]
+    {0,1,4,3,2:T(2,128)}`, 129 MB for 4 MB, a plane; compiled here for the
+    v5e, PR 40)."""
+    hit = jnp.arange(x.shape[1])[None, :] == idx[:, None]
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    out = jax.lax.reduce(jnp.where(hit, bits, 0), jnp.int32(0), jax.lax.bitwise_or, (1,))
+    return jax.lax.bitcast_convert_type(out, x.dtype)
+
+
 def _argmax_prefer_high(x):
     """argmax returning the HIGHEST index among ties (right-to-left scan)."""
     n = x.shape[-1]
     return n - 1 - jnp.argmax(x[..., ::-1], axis=-1)
 
 
-def best_split_per_feature(
-    hist: jnp.ndarray,
+def best_split_planes(
+    g: jnp.ndarray,
+    h: jnp.ndarray,
+    c: jnp.ndarray,
     sum_g: jnp.ndarray,
     sum_h: jnp.ndarray,
     num_data: jnp.ndarray,
@@ -147,7 +165,12 @@ def best_split_per_feature(
     FindBestThresholds — exposed separately so the parallel learners can
     vote / reduce over features before the global argmax.
 
-    hist : (F, B, 3) f32 histogram of (sum_g, sum_h, cnt) per bin.
+    g, h, c : (F, B) f32 planes of a histogram, sum_g, sum_h and cnt per
+        bin, the bins on the minor axis: the layout the streaming kernels
+        emit (ops/pkernels.py ``child_planes``), one lane a cell.  Every
+        prefix sum, take and argmax below runs along that axis; the three
+        quantities never share one (an ``(F, B, 3)`` array lies in HBM with
+        its 3 on the lanes, 43 times its payload at 63 bins).
     sum_g/sum_h/num_data : leaf totals (LeafSplits snapshot) — used for the
         complement side exactly like the reference (right = total - left).
     feature_mask : (F,) f32 0/1 — feature_fraction sampling mask.
@@ -164,7 +187,8 @@ def best_split_per_feature(
         gains (their strategy direction is forced to 0; outputs are
         still bound-clipped by the grower).
     """
-    f, b, _ = hist.shape
+    planes = (g, h, c)
+    b = g.shape[1]
     l1, l2 = hyper.lambda_l1, hyper.lambda_l2
     min_cnt = hyper.min_data_in_leaf
     min_hess = hyper.min_sum_hessian_in_leaf
@@ -178,23 +202,23 @@ def best_split_per_feature(
             sum_g, sum_h, l1, l2, parent_out)
     min_gain_shift = gain_shift + hyper.min_gain_to_split
 
-    cum = jnp.cumsum(hist, axis=1)  # (F, B, 3)
     db = meta.default_bin  # (F,)
     nb = meta.num_bins  # (F,)
-    hist_db = jnp.take_along_axis(hist, db[:, None, None], axis=1)[:, 0, :]  # (F, 3)
+    hist_db = [_pick(x, db) for x in planes]  # (F,) each
 
     thr = jnp.arange(b - 1)  # candidate thresholds t: left = bins <= t
-    db_gt_t = (db[:, None] > thr[None, :]).astype(hist.dtype)  # (F, B-1)
+    db_gt_t = (db[:, None] > thr[None, :]).astype(g.dtype)  # (F, B-1)
     db_le_t = 1.0 - db_gt_t
 
-    base = cum[:, : b - 1, :]  # natural left sums, (F, B-1, 3)
+    # natural left sums, (F, B-1) each
+    base = [jnp.cumsum(x, axis=1)[:, : b - 1] for x in planes]
     # zero-left: default bin's mass always on the left
-    left_zl = base + db_gt_t[:, :, None] * hist_db[:, None, :]
+    left_zl = [x + db_gt_t * d[:, None] for x, d in zip(base, hist_db)]
     # zero-right: default bin's mass always on the right
-    left_zr = base - db_le_t[:, :, None] * hist_db[:, None, :]
+    left_zr = [x - db_le_t * d[:, None] for x, d in zip(base, hist_db)]
 
     def eval_placement(left, extra_valid):
-        lg, lh, lc = left[..., 0], left[..., 1], left[..., 2]
+        lg, lh, lc = left
         rg, rh, rc = sum_g - lg, sum_h - lh, num_data - lc
         valid = (
             extra_valid
@@ -209,8 +233,8 @@ def best_split_per_feature(
         else:
             lout = jnp.clip(leaf_output(lg, lh, l1, l2), leaf_lo, leaf_hi)
             rout = jnp.clip(leaf_output(rg, rh, l1, l2), leaf_lo, leaf_hi)
-            c = monotone[:, None]  # (F, 1) broadcast over thresholds
-            bad = ((c > 0) & (lout > rout)) | ((c < 0) & (lout < rout))
+            mono = monotone[:, None]  # (F, 1) broadcast over thresholds
+            bad = ((mono > 0) & (lout > rout)) | ((mono < 0) & (lout < rout))
             gain = (leaf_split_gain_given_output(lg, lh, l1, l2, lout)
                     + leaf_split_gain_given_output(rg, rh, l1, l2, rout))
             gain = jnp.where(bad, NEG_INF, gain)
@@ -237,23 +261,29 @@ def best_split_per_feature(
             [gain_zl[:, ::-1], gain_nat[:, ::-1], gain_zr], axis=1
         )  # (F, 3*(B-1))
         idx = jnp.argmax(flat_gain, axis=1)
-        best_gain_f = jnp.take_along_axis(flat_gain, idx[:, None], axis=1)[:, 0]
+        best_gain_f = _pick(flat_gain, idx)
         pl = idx // (b - 1)
         off = idx % (b - 1)
         best_thr_f = jnp.where(pl == 2, off, b - 2 - off).astype(jnp.int32)
         best_dbz_f = jnp.where(
             pl == 0, 0, jnp.where(pl == 1, db, nb - 1)
         ).astype(jnp.int32)
-        left_all = jnp.concatenate([left_zl, base, left_zr], axis=1)  # (F, 3(B-1), 3)
-        lidx = pl * (b - 1) + best_thr_f
-        best_left_f = jnp.take_along_axis(left_all, lidx[:, None, None], axis=1)[:, 0, :]
+        # the winner's left sums: its natural ones, and the default bin's
+        # mass moved as its placement moves it (the cell of ``left_zl`` or
+        # ``left_zr`` at the threshold, from the same two operands)
+        zl_add = (db > best_thr_f).astype(g.dtype)
+        zr_sub = 1.0 - zl_add
+        best_left_f = jnp.stack([
+            jnp.where(pl == 0, nat + zl_add * d,
+                      jnp.where(pl == 1, nat, nat - zr_sub * d))
+            for nat, d in zip((_pick(x, best_thr_f) for x in base), hist_db)], axis=1)
     else:
         gain_nat = eval_placement(base, always)
         t_idx = _argmax_prefer_high(gain_nat)
-        best_gain_f = jnp.take_along_axis(gain_nat, t_idx[:, None], axis=1)[:, 0]
+        best_gain_f = _pick(gain_nat, t_idx)
         best_thr_f = t_idx.astype(jnp.int32)
         best_dbz_f = db.astype(jnp.int32)
-        best_left_f = jnp.take_along_axis(base, t_idx[:, None, None], axis=1)[:, 0, :]
+        best_left_f = jnp.stack([_pick(x, t_idx) for x in base], axis=1)
 
     if not has_categorical:
         best_gain_f = jnp.where(feature_mask > 0, best_gain_f, NEG_INF)
@@ -264,20 +294,19 @@ def best_split_per_feature(
 
     # categorical one-vs-rest (FindBestThresholdCategorical, hpp:100-198):
     # left = exactly bin t, decision type "is"; zeros keep their natural bin
-    cg, ch, cc = hist[..., 0], hist[..., 1], hist[..., 2]  # (F, B)
-    og, oh, oc = sum_g - cg, sum_h - ch, num_data - cc
+    og, oh, oc = sum_g - g, sum_h - h, num_data - c
     cat_valid = (
-        (cc >= min_cnt)
+        (c >= min_cnt)
         & (oc >= min_cnt)
-        & (ch >= min_hess)
+        & (h >= min_hess)
         & (oh >= min_hess)
         & (jnp.arange(b)[None, :] <= nb[:, None] - 1)
     )
-    cat_gain = leaf_split_gain(cg, ch, l1, l2) + leaf_split_gain(og, oh, l1, l2)
+    cat_gain = leaf_split_gain(g, h, l1, l2) + leaf_split_gain(og, oh, l1, l2)
     cat_gain = jnp.where(cat_valid & (cat_gain > min_gain_shift), cat_gain, NEG_INF)
     cat_t = _argmax_prefer_high(cat_gain)  # right-to-left scan
-    cat_best = jnp.take_along_axis(cat_gain, cat_t[:, None], axis=1)[:, 0]
-    cat_left = jnp.take_along_axis(hist, cat_t[:, None, None], axis=1)[:, 0, :]
+    cat_best = _pick(cat_gain, cat_t)
+    cat_left = jnp.stack([_pick(x, cat_t) for x in planes], axis=1)
 
     is_cat = meta.is_categorical
     best_gain_f = jnp.where(is_cat, cat_best, best_gain_f)
@@ -291,6 +320,31 @@ def best_split_per_feature(
         jnp.isfinite(best_gain_f), best_gain_f - min_gain_shift, NEG_INF
     )
     return best_gain_f, best_thr_f, best_dbz_f, best_left_f
+
+
+def best_split_per_feature(
+    hist: jnp.ndarray,
+    sum_g: jnp.ndarray,
+    sum_h: jnp.ndarray,
+    num_data: jnp.ndarray,
+    meta: FeatureMeta,
+    hyper: SplitHyper,
+    feature_mask: jnp.ndarray,
+    use_missing: bool = True,
+    has_categorical: bool = True,
+    monotone: jnp.ndarray = None,
+    leaf_lo: jnp.ndarray = None,
+    leaf_hi: jnp.ndarray = None,
+):
+    """``best_split_planes`` on an (F, B, 3) f32 histogram of (sum_g,
+    sum_h, cnt) per bin: the entry of every caller that holds one (the mask
+    grower, the out-of-core and the host-parallel learners).  The same
+    arithmetic in the same order, whichever entry a histogram comes in by."""
+    return best_split_planes(
+        hist[..., 0], hist[..., 1], hist[..., 2], sum_g, sum_h, num_data,
+        meta, hyper, feature_mask, use_missing, has_categorical,
+        monotone=monotone, leaf_lo=leaf_lo, leaf_hi=leaf_hi,
+    )
 
 
 def finalize_split(gain_f, thr_f, dbz_f, left_f, sum_g, sum_h, num_data,

@@ -55,6 +55,12 @@ class TestHistKernel:
         assert err < (2e-3 if INTERP else 1e-5)
 
 
+def _child_hists(planes, f, b):
+    """``split_stream``'s six planes laid out as (left, right), each (F, B, 3):
+    the form ``hist_ref`` and ``hist_dyn`` give."""
+    return (pk._hist_cells(*planes[0:3], f, b), pk._hist_cells(*planes[3:6], f, b))
+
+
 def _check_split_stream(P, lay, start, cnt, feat, thr, zb, dbz, cat, bits=8,
                         nbins=32):
     """split_stream vs the stable numpy reference: same left/right row
@@ -67,12 +73,14 @@ def _check_split_stream(P, lay, start, cnt, feat, thr, zb, dbz, cat, bits=8,
     # wrapper carries donate_argnums), so P must not be read afterwards —
     # pass a copy so callers can reuse P across checks
     Pref, nlref = pk.partition_ref(P, start, cnt, feat, zb, dbz, thr, bool(cat), lay)
-    P2, nl, lh, rh = pk.split_stream(
+    P2, nl, planes = pk.split_stream(
         jnp.array(P), start, cnt, feat // per, (feat % per) * bits, zb, dbz,
         thr, cat,
         num_features=lay.F, num_bins=nbins, bits=bits, rows=lay.rows,
         interpret=INTERP,
     )
+    assert planes.shape == (6, pk.hist_lanes(lay.F, nbins))
+    lh, rh = _child_hists(planes, lay.F, nbins)
     assert int(nl) == nlref
     P2n, Prefn = np.asarray(P2), np.asarray(Pref)
     # outside the segment: bit-identical
@@ -161,11 +169,12 @@ class TestLevelStreamKernel:
 
         ps = P
         for i, (s, c, f, t, zb, dbz, cat) in enumerate(segs):
-            ps, nls, lh, rh = pk.split_stream(
+            ps, nls, planes = pk.split_stream(
                 ps, s, c, f // per, (f % per) * lay.bits, zb, dbz, t, cat,
                 num_features=F, num_bins=B, bits=lay.bits, rows=lay.rows,
                 interpret=INTERP,
             )
+            lh, rh = _child_hists(planes, F, B)
             assert int(nls) == int(nl[i]), f"seg {i} left count"
             ll = np.asarray(pk._hist_from_rows(jnp.asarray(hists[i]), F, B, row0=0))
             rr = np.asarray(pk._hist_from_rows(jnp.asarray(hists[i]), F, B, row0=7))
@@ -381,8 +390,7 @@ class TestStaircaseCompaction:
         _check_split_stream(P, lay, start, cnt, feat, thr, 0, 0, cat)
         got, nl, _ = pk.split_stream(
             jnp.array(P), start, cnt, feat // per, (feat % per) * lay.bits, 0, 0, thr, cat,
-            num_features=lay.F, num_bins=32, bits=lay.bits, rows=lay.rows, interpret=INTERP,
-            planes=True)
+            num_features=lay.F, num_bins=32, bits=lay.bits, rows=lay.rows, interpret=INTERP)
         assert int(nl) == nl_want
         np.testing.assert_array_equal(np.asarray(got), want)
 
@@ -1196,14 +1204,14 @@ class TestEmptySegmentLaunch:
                 np.abs(rng.standard_normal(n)).astype(np.float32).view(np.int32)))
         before = np.asarray(P, np.int32)
         per = 32 // bits
-        P2, nl, lh, rh = pk.split_stream(
+        P2, nl, planes = pk.split_stream(
             jnp.array(P), 0, 0, 5 // per, (5 % per) * bits, 0, 0, 7, 0,
             num_features=f, num_bins=nbins, bits=bits, rows=lay.rows,
             interpret=INTERP)
         assert int(nl) == 0
         np.testing.assert_array_equal(np.asarray(P2, np.int32), before)
-        assert np.asarray(lh).shape == (f, nbins, 3)
-        assert not np.asarray(lh).any() and not np.asarray(rh).any()
+        assert np.asarray(planes).shape == (6, pk.hist_lanes(f, nbins))
+        assert not np.asarray(planes).any()
 
 
 class TestChunkStops:
