@@ -43,8 +43,9 @@ layout is float summation order: a run is bit-deterministic, a
 checkpoint carries the layout (``export_perm``), and two runs that
 partition differently (LEVELGROW=1 against =0) agree byte for byte on
 the first tree and to an ulp after it.  The original-order score
-vectors are rebuilt ONCE per chunk (one scatter per class through the
-rowid channel) for metrics/eval.
+vectors are rebuilt ONCE per chunk through the rowid channel
+(``in_row_order``: a scatter a class below 4M rows, else one key-value
+sort of every class's score plane) for metrics/eval.
 
 Why every channel write goes through a Pallas kernel: an XLA-level
 write to the packed matrix copies all of it; only
@@ -107,6 +108,7 @@ from ..ops.pgrow import (
     BundleMeta,
     PGrowParams,
     grow_tree_partitioned,
+    in_row_order,
     level_slots,
     levelgrow_env_params,
     segment_values,
@@ -299,13 +301,10 @@ class PartitionedTrainer:
 
     def scores_original_order(self):
         """(N,) for K == 1, else (K, N)."""
-        lay = self.layout
-        rowid = self.p[lay.ROWID, : self.num_rows]
-        outs = []
-        for k in range(self.K):
-            sc = _i2f(self.p[lay.SCORE + k, : self.num_rows])
-            outs.append(jnp.zeros((self.num_rows,), jnp.float32).at[rowid].set(sc))
-        return outs[0] if self.K == 1 else jnp.stack(outs)
+        lay, n = self.layout, self.num_rows
+        out = in_row_order(
+            self.p[lay.ROWID, :n], [_i2f(self.p[lay.SCORE + k, :n]) for k in range(self.K)])
+        return out[0] if self.K == 1 else out
 
     def rollback_last(self) -> bool:
         """Undo the most recent tree's score contribution (the segment
@@ -607,13 +606,11 @@ class PartitionedTrainer:
                     # checkpoint exports scores + perm, never g/h).
                     p = score_add(p, lay, last_delta, 0, num_rows=n,
                                   interpret=interpret)
-                # original-order scores for eval (K scatters per chunk)
-                rowid = p[lay.ROWID, :n]
-                outs = []
-                for k in range(K):
-                    sc = _i2f(p[lay.SCORE + k, :n])
-                    outs.append(jnp.zeros((n,), jnp.float32).at[rowid].set(sc))
-                scores_orig = outs[0] if K == 1 else jnp.stack(outs)
+                # original-order scores for eval, once a chunk
+                scores_orig = in_row_order(
+                    p[lay.ROWID, :n], [_i2f(p[lay.SCORE + k, :n]) for k in range(K)])
+                if K == 1:
+                    scores_orig = scores_orig[0]
             return p, recs, scores_orig, last_kept
 
         return prog
@@ -1021,14 +1018,8 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
 
             def shard_body(pg):
                 p = pg[0]
-                rowid = p[lay.ROWID, :nl]
-                outs = [
-                    jnp.zeros((nl,), jnp.float32).at[rowid].set(
-                        _i2f(p[lay.SCORE + k, :nl])
-                    )
-                    for k in range(K)
-                ]
-                return jnp.stack(outs)  # (K, nl)
+                return in_row_order(
+                    p[lay.ROWID, :nl], [_i2f(p[lay.SCORE + k, :nl]) for k in range(K)])
 
             self._scores_prog = jax.jit(
                 self._shard_map(shard_body, (P("data"),), P(None, "data"))
@@ -1333,13 +1324,8 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                     # score-only chunk-end settle (see the serial trainer)
                     p = score_add(p, lay, last_delta, 0, num_rows=nl,
                                   interpret=interpret)
-                rowid = p[lay.ROWID, :nl]
-                scores_local = jnp.stack([
-                    jnp.zeros((nl,), jnp.float32).at[rowid].set(
-                        _i2f(p[lay.SCORE + k, :nl])
-                    )
-                    for k in range(K)
-                ])  # (K, nl)
+                scores_local = in_row_order(
+                    p[lay.ROWID, :nl], [_i2f(p[lay.SCORE + k, :nl]) for k in range(K)])
             return p[None], recs, scores_local, last_kept
 
         mapped = self._shard_map(

@@ -874,13 +874,43 @@ def split_audit_rows(gr):
         }
 
 
+# Rows from which ``in_row_order`` sorts.  The v5e's compiler lowers
+# ``zeros(n).at[rowid].set(x)`` to a direct scatter up to ~4.08M rows, and
+# from ~4.10M on to a key-value sort plus a scatter fusion through the sorted
+# ids (125 ms a chunk at 21M rows, where the sort takes 51.7).  On the v5e's
+# host a direct scatter compiles in under 2 s and a sort in 8-19 s, so below
+# this the sort would add ~10 s to every cold compile to save 2 ms (400,000
+# rows) to 10 ms (2,270,296) a chunk; above it the sort is compiled either
+# way and only the scatter fusion goes.
+SORT_MIN_ROWS = 4_000_000
+
+
+def in_row_order(rowid, planes):
+    """The K (n,) planes, each in the packed matrix's row order, put back in
+    original row order as one (K, n) array through the (n,) ROWID channel:
+    ``zeros(n).at[rowid].set(plane)`` a plane below ``SORT_MIN_ROWS``, else
+    ONE key-value sort of the K planes laid end to end, plane k keyed by
+    ``rowid + k * n``.  The channel is a permutation of 0..n-1
+    (``pack_matrix`` writes ``arange(n)``, a shard's padding rows included,
+    and trees only permute it), so the two give the same bytes.  Unstable,
+    since the keys are distinct: a stable sort adds an iota key.  Two
+    operands whatever K is: a sort carrying K + 1 operands takes 27 s to
+    compile at K = 3 and over 6 minutes at K = 16, XLA merges K sorts on one
+    key into that one, and a (K, n) sort along the rows ran 432 ms against
+    178 at K = 3 and 21M rows (v5e).  K * n < 2**31 (K <= 16)."""
+    n, K = rowid.shape[0], len(planes)
+    if n < SORT_MIN_ROWS:
+        return jnp.stack([jnp.zeros(p.shape, p.dtype).at[rowid].set(p) for p in planes])
+    keys = jnp.concatenate([rowid + k * n for k in range(K)])
+    return jax.lax.sort((keys, jnp.concatenate(planes)), num_keys=1, is_stable=False)[1].reshape(K, n)
+
+
 def leaf_id_from_segments(tree: PTreeResult, p: jnp.ndarray, layout: PLayout, num_rows: int) -> jnp.ndarray:
     """(N,) int32 leaf index in ORIGINAL row order (via the rowid
     channel) — the GrowResult.leaf_id contract for driver code that needs
-    it (one O(N) scatter; avoided on the fast path)."""
+    it (one O(N) scatter or sort; avoided on the fast path)."""
     L = tree.starts.shape[0]
     leaf_at_pos = segment_values(
         tree, num_rows, jnp.arange(L, dtype=jnp.float32)
     ).astype(jnp.int32)
-    rowid = p[layout.ROWID, :num_rows]
-    return jnp.zeros((num_rows,), jnp.int32).at[rowid].set(leaf_at_pos)
+    return in_row_order(p[layout.ROWID, :num_rows], [leaf_at_pos])[0]

@@ -219,8 +219,10 @@ def test_no_program_reorders_the_matrix(request, cell):
     nothing in them gathers, scatters, sorts, transposes or copies an array of
     the matrix's size (the parent's: `fusion s32[21000000,16]`, 469 ms a tree,
     and at 512 channel rows a transposing copy around a gather of 2 KB rows).
-    A row vector of `n` elements still moves in the epilogue (its score
-    scatter); `leaf_delta`'s went in PR 32 (the test after this one)."""
+    Row vectors of `n` elements still move: the epilogue puts the score
+    plane back in row order through ROWID, by a sort or a scatter as the row
+    count asks (the epilogue's own tests, below); `leaf_delta` moves none
+    (the test after this one)."""
     if cell == "higgs_21m_x_28":
         text, rows, channels = request.getfixturevalue("compiled_text"), ROWS, 16
     else:
@@ -296,6 +298,93 @@ def test_leaf_delta_looks_values_up_by_compare(request, cell):
     assert [i[:3] for i in launched
             if any({leaves, rows} <= set(d) for d in _dims(i.shape))] == []
     assert temps <= parent_temps
+
+
+def _inside(comps, insts):
+    """``insts`` and what their fusions call, at any depth (XLA nests the
+    scatter's kCustom fusion inside a loop fusion)."""
+    out, todo = [], list(insts)
+    while todo:
+        i = todo.pop()
+        out.append(i)
+        if i.calls:
+            todo.extend(comps[i.calls])
+    return out
+
+
+def _epilogue_moves(text, rows):
+    """(the scatters of ``rows`` or more elements, the shapes of the sorts)
+    under the `chunk_epilogue` scope, fusion bodies included."""
+    ops = parse_hlo_phases(text)["ops"]
+    comps = _by_computation(text)
+    mine = [i for body in comps.values() for i in body if ops.get(i.name) == "chunk_epilogue"]
+    inside = _inside(comps, mine)
+    scatters = [i[:3] for i in inside
+                if i.opcode == "scatter" and max(map(np.prod, _dims(i.shape))) >= rows]
+    return scatters, [_LAYOUT.sub("", i.shape) for i in inside if i.opcode == "sort"]
+
+
+def test_the_epilogue_sorts_and_scatters_nothing(compiled_text):
+    """From ``pgrow.SORT_MIN_ROWS`` rows on the epilogue puts the score
+    channel back in row order with ONE key-value sort on ROWID
+    (``pgrow.in_row_order``): under `chunk_epilogue` no scatter of `n` or
+    more elements, and exactly one sort, over ``(s32[n], f32[n])``.  The
+    scatter ``zeros(n).at[rowid].set(score)`` compiles at 21M rows to an
+    index select, this same sort and a kCustom scatter fusion through the
+    sorted ids (0.0515 and 0.1249 s a chunk on a v5e).  The
+    sort is unstable because the ids are distinct: a stable one carries an
+    ``s32[n]`` iota as a third operand."""
+    assert _epilogue_moves(compiled_text, ROWS) == ([], [f"(s32[{ROWS}], f32[{ROWS}])"])
+
+
+def test_below_the_sort_size_the_epilogue_scatters(epsilon_compiled):
+    """At Epsilon's 400,000 rows, under ``SORT_MIN_ROWS``, the compiler makes
+    the scatter a direct one, and the epilogue keeps it: one scatter of `n`,
+    no sort (a sort would add ~8 s to a cold compile on the v5e's host, for
+    2 ms a chunk)."""
+    from lightgbm_tpu.ops.pgrow import SORT_MIN_ROWS
+
+    assert EPS_ROWS < SORT_MIN_ROWS
+    scatters, sorts = _epilogue_moves(epsilon_compiled[0], EPS_ROWS)
+    assert [op for _, _, op in scatters] == ["scatter"] and sorts == []
+
+
+def test_the_sharded_epilogue_sorts_at_its_size(sharded_compiled):
+    """The same on one chip of the four-chip program, at a chip's rows: the
+    sort at 21M rows a chip (a shard's padding rows keep their own ids, so
+    the key is a permutation), the direct scatter at 100,000."""
+    from lightgbm_tpu.ops.pgrow import SORT_MIN_ROWS
+
+    text, rows = sharded_compiled[0], sharded_compiled[3]
+    scatters, sorts = _epilogue_moves(text, rows)
+    if rows >= SORT_MIN_ROWS:
+        assert (scatters, sorts) == ([], [f"(s32[{rows}], f32[{rows}])"])
+    else:
+        assert [op for _, _, op in scatters] == ["scatter"] and sorts == []
+
+
+def test_the_epilogue_check_sees_a_scatter(one_chip):
+    """The scatter into zeros, compiled alone at 21M rows under the same scope: the
+    check finds its scatter and its sort, and the helper's one sort."""
+    from lightgbm_tpu.ops.pgrow import in_row_order
+    from lightgbm_tpu.obs.phases import CHUNK_EPILOGUE
+
+    def scattered(rowid, score):
+        with jax.named_scope(CHUNK_EPILOGUE):
+            return jnp.zeros((ROWS,), jnp.float32).at[rowid].set(score)
+
+    def sorted_(rowid, score):
+        with jax.named_scope(CHUNK_EPILOGUE):
+            return in_row_order(rowid, [score])
+
+    args = (jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((ROWS,), jnp.float32, sharding=one_chip))
+    with _compiling_for_a_described_chip():
+        texts = [jax.jit(f).lower(*args).compile().as_text() for f in (scattered, sorted_)]
+    scatters, sorts = _epilogue_moves(texts[0], ROWS)
+    assert [op for _, _, op in scatters] == ["scatter"]
+    assert sorts == [f"(s32[{ROWS}], f32[{ROWS}])"]
+    assert _epilogue_moves(texts[1], ROWS) == ([], [f"(s32[{ROWS}], f32[{ROWS}])"])
 
 
 @pytest.mark.parametrize("kernel,phase", [
