@@ -24,12 +24,14 @@ jitted program.  Per iteration:
   K > 1 (multiclass): ALL K gradient planes + K root histograms come
     from ONE streaming pass over the same score snapshot
     (update_multi_and_hists — GBDT::Boosting computes every class's
-    gradients once per iteration, gbdt.cpp:692-700); each class's tree
-    then reads its own g/h channel pair, and its leaf deltas land on its
-    score row IMMEDIATELY after the tree via the score_add streamer,
-    while the delta's partition layout is still current.  (Deltas must
-    never stay pending across another class's tree: each tree physically
-    re-permutes the rows.)
+    gradients once per iteration, gbdt.cpp:692-700); the K trees then
+    come from ONE traced grower in a fori_loop over the classes
+    (class_trees), which reads the class-0 g/h pair, and after each tree
+    one score_add band pass lands its leaf deltas on its class's score
+    row (IMMEDIATELY, while the delta's partition layout is still
+    current) and swaps the next class's planes into that pair.  (Deltas
+    must never stay pending across another class's tree: each tree
+    physically re-permutes the rows.)
 
 Scores, labels, weights and the row id travel as bitcast channels of
 the packed matrix, so nothing is ever gathered back to original row
@@ -99,6 +101,7 @@ from ..obs import JitWatch, fence, tracer
 from ..obs.phases import (
     CHUNK_EPILOGUE,
     LEAF_DELTA,
+    MULTI_UPDATE,
     RANK_GRAD,
     SAMPLE,
     SCORE_ADD,
@@ -123,6 +126,7 @@ from ..ops.pkernels import (
     score_add,
     update_and_root_hist,
     update_multi_and_hists,
+    update_value_rows,
 )
 from ..ops.split import (
     FeatureMeta,
@@ -168,6 +172,42 @@ def rowid_uniform(key, rowid):
 
 def _is_goss(config) -> bool:
     return str(config.boosting_type).lower() == "goss"
+
+
+def class_trees(p, hists, fmask, lr, slots, *, lay, num_rows, meta, hyper, params, bmeta,
+                interpret):
+    """The K trees of one multiclass iteration (the per-class loop,
+    gbdt.cpp:445-480) as ONE traced grower in a ``fori_loop`` over the
+    classes, the serial and the sharded chunk programs alike.  Tree k reads
+    the class-0 pair of g and h rows, into which the band pass after tree
+    k - 1 swapped class k's (``score_add(class_step=k - 1)``, which also
+    lands tree k - 1's delta on its score row; after the last tree the
+    pairs rotate back, so an iteration leaves each class's planes where
+    ``update_multi_and_hists`` wrote them, from one score snapshot).
+    ``hists``: the K root histograms; ``slots``: the
+    iteration's record rows (num_splits, raw, levels, tail), each with a
+    leading K axis that tree k writes at ``k``.  The matrix goes loop carry
+    -> aliased kernel -> loop carry, through no conditional.  Returns (p,
+    slots, whether any tree split)."""
+    hists = jnp.stack(hists)
+
+    def one_class(k, carry):
+        p, slots, any_split = carry
+        tree, p = grow_tree_partitioned(
+            p, fmask, meta, hyper, params, bmeta=bmeta, interpret=interpret,
+            root_hist=hists[k], rows=lay.rows,
+        )
+        with jax.named_scope(LEAF_DELTA):
+            keep = (tree.num_splits > 0).astype(jnp.float32)
+            lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
+            dk = segment_values(tree, num_rows, keep * lval)
+        with jax.named_scope(SCORE_ADD):
+            p = score_add(p, lay, dk, num_rows=num_rows, interpret=interpret, class_step=k)
+        got = (tree.num_splits, tree.recs_raw, tree.level_counts, tree.tail_counts)
+        slots = tuple(s.at[k].set(v) for s, v in zip(slots, got))
+        return p, slots, any_split | (tree.num_splits > 0)
+
+    return jax.lax.fori_loop(0, lay.num_score, one_class, (p, slots, jnp.array(False)))
 
 
 class PartitionedTrainer:
@@ -520,39 +560,23 @@ class PartitionedTrainer:
                     lv_t = lv_t.at[0].set(tree.level_counts)
                     tl_t = tl_t.at[0].set(tree.tail_counts)
                 else:
-                    # K trees per iteration (per-class loop,
-                    # gbdt.cpp:445-480): ALL K gradient planes + K root
-                    # histograms from the same score snapshot in ONE
-                    # pass; each tree's delta lands on its score row
-                    # IMMEDIATELY after the tree (while its partition
-                    # layout is still current), which the precomputed
-                    # gradient planes make snapshot-safe.
-                    with jax.named_scope(UPDATE_ROOT_HIST):
+                    # K trees per iteration from ALL K gradient planes + K
+                    # root histograms of the same score snapshot, one pass;
+                    # the trees from one looped grower (class_trees), each
+                    # tree's delta on its score row IMMEDIATELY after it
+                    # (while its partition layout is still current), which
+                    # the precomputed planes make snapshot-safe.  No delta
+                    # stays pending.
+                    with jax.named_scope(UPDATE_ROOT_HIST), jax.named_scope(MULTI_UPDATE):
                         p, hists = update_multi_and_hists(
                             p, lay, grad_all_fn, sel=sel, num_rows=n,
                             num_features=G, num_bins=BH, bits=params.bits,
                             interpret=interpret,
                         )
-                    any_split = jnp.array(False)
-                    for k in range(K):
-                        tree, p = grow_tree_partitioned(
-                            p, fmask, meta, hyper, params, bmeta=bmeta,
-                            interpret=interpret, root_hist=hists[k],
-                            rows=lay.class_rows(k),
-                        )
-                        with jax.named_scope(LEAF_DELTA):
-                            keep = (tree.num_splits > 0).astype(jnp.float32)
-                            lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
-                            dk = segment_values(tree, n, keep * lval)
-                        with jax.named_scope(SCORE_ADD):
-                            p = score_add(p, lay, dk, k, num_rows=n,
-                                          interpret=interpret)
-                        any_split = any_split | (tree.num_splits > 0)
-                        ns_t = ns_t.at[k].set(tree.num_splits)
-                        raw_t = raw_t.at[k].set(tree.recs_raw)
-                        lv_t = lv_t.at[k].set(tree.level_counts)
-                        tl_t = tl_t.at[k].set(tree.tail_counts)
-                    delta = delta  # unused for K > 1 (scores always settled)
+                    p, (ns_t, raw_t, lv_t, tl_t), any_split = class_trees(
+                        p, hists, fmask, lr, (ns_t, raw_t, lv_t, tl_t), lay=lay, num_rows=n,
+                        meta=meta, hyper=hyper, params=params, bmeta=bmeta,
+                        interpret=interpret)
 
                 # ONE packed record buffer: per-op dispatch inside the
                 # loop costs ~1-2 us, so ten separate stores would be a
@@ -707,12 +731,16 @@ class PartitionedTrainer:
         ``rank_buckets``, and over the iterations ``rank_docs``,
         ``rank_pairs`` (sum of n^2 a query, the reference's pair loop) and
         ``rank_pair_cells`` (the cells its length buckets evaluated, padding
-        included).  Called only when tracing is on."""
+        included).  And the gradient pass's shape: ``classes`` (K, the trees
+        an iteration) and ``update_value_rows`` (the value rows its
+        histogram dots carry a row, padded: 8 at K = 1, 48 at K = 7).
+        Called only when tracing is on."""
         cols = self.params.num_cols or self.params.num_features
         bins = self.params.num_bins_hist or self.params.num_bins
         out = {"hist_cells": hist_lanes(cols, bins), "channels": self.layout.C,
                "col_groups": col_groups(cols, self.params.bits).count,
                "perm_tiles": perm_tiles(),
+               "classes": self.K, "update_value_rows": update_value_rows(self.K),
                "bundle_cols": self.params.num_cols,
                "shards": 1, "allreduce_calls": 0, "allreduce_bytes": 0}
         out.update(zip(("levels", "level_rows", "level_segments", "scan_slots"),
@@ -1256,36 +1284,21 @@ class ShardedPartitionedTrainer(PartitionedTrainer):
                     lv_t = lv_t.at[0].set(tree.level_counts)
                     tl_t = tl_t.at[0].set(tree.tail_counts)
                 else:
-                    # K trees per iteration from one gradient pass; each
-                    # class's delta lands on its score row immediately
-                    # after its tree (mirrors the serial K > 1 branch,
-                    # with per-level hist psums inside the grower)
+                    # K trees per iteration from one gradient pass (the
+                    # serial K > 1 branch, the same class_trees, with
+                    # per-level hist psums inside the grower)
                     with jax.named_scope(UPDATE_ROOT_HIST):
-                        p, hists = update_multi_and_hists(
-                            p, lay, grad_all_fn, sel=sel, num_rows=nl,
-                            num_features=G, num_bins=BH, bits=params.bits,
-                            interpret=interpret,
-                        )
+                        with jax.named_scope(MULTI_UPDATE):
+                            p, hists = update_multi_and_hists(
+                                p, lay, grad_all_fn, sel=sel, num_rows=nl,
+                                num_features=G, num_bins=BH, bits=params.bits,
+                                interpret=interpret,
+                            )
                         hists = jax.lax.psum(hists, "data")
-                    any_split = jnp.array(False)
-                    for k in range(K):
-                        tree, p = grow_tree_partitioned(
-                            p, fmask, meta, hyper, params, bmeta=bmeta,
-                            interpret=interpret, root_hist=hists[k],
-                            rows=lay.class_rows(k),
-                        )
-                        with jax.named_scope(LEAF_DELTA):
-                            keep = (tree.num_splits > 0).astype(jnp.float32)
-                            lval = jnp.clip(lr * tree.leaf_value, -100.0, 100.0)
-                            dk = segment_values(tree, nl, keep * lval)
-                        with jax.named_scope(SCORE_ADD):
-                            p = score_add(p, lay, dk, k, num_rows=nl,
-                                          interpret=interpret)
-                        any_split = any_split | (tree.num_splits > 0)
-                        ns_t = ns_t.at[k].set(tree.num_splits)
-                        raw_t = raw_t.at[k].set(tree.recs_raw)
-                        lv_t = lv_t.at[k].set(tree.level_counts)
-                        tl_t = tl_t.at[k].set(tree.tail_counts)
+                    p, (ns_t, raw_t, lv_t, tl_t), any_split = class_trees(
+                        p, hists, fmask, lr, (ns_t, raw_t, lv_t, tl_t), lay=lay, num_rows=nl,
+                        meta=meta, hyper=hyper, params=params, bmeta=bmeta,
+                        interpret=interpret)
 
                 recs = {
                     "num_splits": recs["num_splits"].at[t].set(ns_t),
@@ -1509,9 +1522,14 @@ def eligible(config, train_set, objective, num_tree_per_iteration: int) -> bool:
     # rides at any width that VMEM holds: at 2,000 columns x 63 bins
     # level_stream holds 12 (512, BLK) blocks, two (16, 128,000)
     # histograms and its compaction's 2 MB, 44 MB of the v5e's 128 MiB.
-    # The ceiling that is left is that budget.  Multiclass keeps the old 512 columns: its update
-    # kernel holds 6K+1 histogram rows of ALL columns at once (53 MB at
-    # K = 16 and 2,000 columns, twice with its output block).
+    # The ceiling that is left is that budget.  Multiclass keeps the old
+    # 512 columns: its gradient pass (update_multi_and_hists) holds 6K+1
+    # histogram rows of ALL columns at once (53 MB at K = 16 and 2,000
+    # columns, twice with its output block).  The K trees of an iteration
+    # are one traced grower in a loop over the classes
+    # (class_trees), so the chunk program's size and its trace no longer
+    # grow with K; what grows with K is that pass alone, and this ceiling
+    # is its own, not the grower's.
     bundle = getattr(train_set, "bundle", None)
     cols = bundle.num_cols if bundle is not None else train_set.num_features
     col_bins = int(bundle.max_col_bin) if bundle is not None else int(train_set.max_num_bin)

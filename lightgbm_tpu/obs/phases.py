@@ -45,9 +45,14 @@ BUNDLE_EXPAND = "bundle_expand"      # a bundle histogram's (G, BH) planes -> (F
 # Opened only by a program that trains a query-grouped objective (lambdarank),
 # before update_root_hist: the bucketed pair step of objective/rank.py.
 RANK_GRAD = "rank_grad"              # query-grouped gradients from the score channel
+# Opened only by a program that trains K > 1 trees an iteration (multiclass),
+# inside update_root_hist: the one pass that writes all K gradient planes and
+# takes the K root histograms (pkernels.update_multi_and_hists).
+MULTI_UPDATE = "multi_update"        # all K classes' g, h and root histograms
 
 PHASES = (CANON_REORDER, SAMPLE, UPDATE_ROOT_HIST, LEVEL_PHASE, SPLIT_SCAN, REPLAY,
-          REPLAY_TAIL, LEAF_DELTA, SCORE_ADD, CHUNK_EPILOGUE, BUNDLE_EXPAND, RANK_GRAD)
+          REPLAY_TAIL, LEAF_DELTA, SCORE_ADD, CHUNK_EPILOGUE, BUNDLE_EXPAND, RANK_GRAD,
+          MULTI_UPDATE)
 
 
 def nested(outer: str) -> str:
@@ -59,7 +64,7 @@ def nested(outer: str) -> str:
 # `level_phase` (whose total stays whole) and `split_scan` reads the search alone.
 ENCLOSING = {REPLAY_TAIL: REPLAY, SPLIT_SCAN: LEVEL_PHASE,
              nested(SPLIT_SCAN): LEVEL_PHASE, nested(REPLAY): REPLAY,
-             nested(UPDATE_ROOT_HIST): UPDATE_ROOT_HIST}
+             nested(UPDATE_ROOT_HIST): UPDATE_ROOT_HIST, MULTI_UPDATE: UPDATE_ROOT_HIST}
 
 _COMPUTATION = re.compile(r"^(ENTRY )?%(\S+) \(.*\) -> .*\{$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(")

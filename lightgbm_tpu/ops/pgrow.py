@@ -392,8 +392,9 @@ def grow_tree_partitioned(
     G = params.num_cols or F
     BH = params.num_bins_hist or B
     if rows is None:
-        # default single-class channel rows; multiclass callers pass
-        # PLayout.class_rows(k) so tree k reads its own g/h pair
+        # the single-class layout's rows; a K > 1 layout's caller passes
+        # its PLayout.rows (class 0's pair, where each class's planes are
+        # swapped in turn, and its select row)
         rows = PLayout(G, bits=params.bits).rows
     per = 32 // params.bits
     mtab = _meta_table(meta, bmeta, F, params.bits)

@@ -193,13 +193,10 @@ class PLayout:
     def h_row(self, k: int) -> int:
         return self.WPAD + 2 * k + 1
 
-    def class_rows(self, k: int):
-        """(g, h, sel) row triple for class k — static kernel param."""
-        return (self.g_row(k), self.h_row(k), self.SEL)
-
     @property
     def rows(self):
-        """(g, h, sel) row indices for class 0."""
+        """(g, h, sel) row indices for class 0: what the grower reads, every
+        class's tree in turn under K > 1 (``ptrainer.class_trees``)."""
         return (self.G, self.H, self.SEL)
 
 
@@ -870,6 +867,14 @@ def _upd_multi_kernel(sref, aux_any, p_any_in, p_any, o_ref, acc_ref, buf_ref, a
     o_ref[:, :] = acc_ref[:, :]
 
 
+def update_value_rows(num_score: int) -> int:
+    """Value rows, padded to the sublane tile, that the gradient pass's
+    histogram dots carry a block: 8 for one class (``update_and_root_hist``:
+    3-term g, 3-term h, the count, one spare), 6K + 1 padded for K classes
+    (48 at K = 7)."""
+    return 8 if num_score == 1 else -(-(6 * num_score + 1) // 8) * 8
+
+
 def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
                            *, num_rows, num_features, num_bins, bits=8,
                            interpret: bool = False):
@@ -880,8 +885,7 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
     ntot = p.shape[1]
     c = p.shape[0]
     fb = hist_lanes(num_features, num_bins)
-    nv = 6 * K + 1
-    nvpad = -(-nv // 8) * 8
+    nvpad = update_value_rows(K)
 
     def fit(v):
         v = jnp.asarray(v, jnp.float32)
@@ -940,14 +944,17 @@ def update_multi_and_hists(p, layout: PLayout, grad_all_fn, sel=None,
 # score_add: in-place score-row segment update (multiclass per-tree,
 # chunk-end settle)
 # ======================================================================
-def _score_band_kernel(aux_any, p_in, p_any, buf, abuf, rsem, asem, wsem, *,
-                       band0, bandn, nblk, score_off):
+def _score_band_kernel(*refs, band0, bandn, nblk, update, dynamic):
     """Band-streaming score update: score += delta touching ONLY the
     8-aligned mutable band (``update_channels``' ring pattern).  The old
     kernel streamed every matrix row — including the packed bin words —
     just to rewrite them unchanged; reading the band alone halves (or
     better) the traffic of every score-only pass and leaves the bin/rowid
-    rows genuinely untouched ("read once per round")."""
+    rows genuinely untouched ("read once per round").  ``update(band,
+    delta, k_ref)`` makes a block's new band; ``dynamic``: the first ref
+    is the scalar-prefetched class index it reads."""
+    k_ref, refs = (refs[0], refs[1:]) if dynamic else (None, refs)
+    aux_any, p_in, p_any, buf, abuf, rsem, asem, wsem = refs
     R, K = _URING, _UAHEAD
 
     def rd(j):
@@ -976,10 +983,7 @@ def _score_band_kernel(aux_any, p_in, p_any, buf, abuf, rsem, asem, wsem, *,
         sl = jax.lax.rem(j, R)
         rd(j).wait()
         rda(j).wait()
-        blk = buf[sl]
-        sc = pltpu.bitcast(blk[score_off : score_off + 1, :], jnp.float32)
-        sc = sc + abuf[sl][0:1, :]
-        buf[sl] = _setrow(blk, score_off, pltpu.bitcast(sc, jnp.int32))
+        buf[sl] = update(buf[sl], abuf[sl][0:1, :], k_ref)
         wr(j).start()
 
         @pl.when(j + K < nblk)
@@ -998,16 +1002,56 @@ def _score_band_kernel(aux_any, p_in, p_any, buf, abuf, rsem, asem, wsem, *,
         wr(nblk - 1 - k).wait()
 
 
+def _add_score_row(band, delta, _, *, off):
+    """score row ``off`` (static, band-relative) += delta."""
+    sc = pltpu.bitcast(band[off : off + 1, :], jnp.float32) + delta
+    return _setrow(band, off, pltpu.bitcast(sc, jnp.int32))
+
+
+def _class_step_band(band, delta, k_ref, *, lay: PLayout):
+    """score row k += delta, k the scalar-prefetched class, and the K (g, h)
+    row pairs (the band's first 2K rows) permuted so that the class-0 pair
+    holds class k + 1's planes: after k < K - 1 the pair of class 0 and of
+    class k + 1 swap; after the last class they rotate back, so that an
+    iteration leaves every class's planes in its own pair, as the
+    gradient pass wrote them.  Each row is a select on the class, never a
+    static offset, so one kernel serves every k; the other rows pass
+    through."""
+    K, band0 = lay.num_score, lay.WPAD
+    kv = jnp.full((2, band.shape[1]), k_ref[0], jnp.int32)
+    last = kv == K - 1
+    old = [band[lay.g_row(c) - band0 : lay.g_row(c) - band0 + 2] for c in range(K)]
+    nxt = old[0]
+    for c in range(1, K):
+        nxt = jnp.where(kv + 1 == c, old[c], nxt)
+    new = [jnp.where(last, old[1], nxt)]
+    for c in range(1, K):
+        new.append(jnp.where(last, old[(c + 1) % K], jnp.where(kv + 1 == c, old[0], old[c])))
+    s0 = lay.SCORE - band0
+    for c in range(K):
+        sc = pltpu.bitcast(band[s0 + c : s0 + c + 1], jnp.float32)
+        new.append(pltpu.bitcast(jnp.where(kv[:1] == c, sc + delta, sc), jnp.int32))
+    return jnp.concatenate(new[:K] + [band[2 * K : s0]] + new[K:] + [band[s0 + K:]], axis=0)
+
+
 @functools.partial(jax.jit, static_argnames=("layout", "k", "num_rows", "interpret"),
                    donate_argnums=(0,))
 def score_add(p, layout: PLayout, delta, k: int = 0, *, num_rows,
-              interpret: bool = False):
-    """score channel k += delta (N,) in place — the per-tree score update
-    of the multiclass fused loop (applied IMMEDIATELY after each tree,
-    while the delta's row layout is still current) and the chunk-end
-    pending-delta settle.  Streams only the mutable band, not the full
-    matrix; donated at the jit level so standalone calls never pay a
-    defensive whole-matrix copy."""
+              interpret: bool = False, class_step=None):
+    """score channel k += delta (N,) in place — the chunk-end
+    pending-delta settle and the host's score syncs.  Streams only the
+    mutable band, not the full matrix; donated at the jit level so
+    standalone calls never pay a defensive whole-matrix copy.
+
+    ``class_step`` (an int32 scalar, traced; ``k`` is then unused): the
+    band pass after each class tree of a multiclass iteration — score row
+    ``class_step`` += delta (applied IMMEDIATELY after its tree, while the
+    delta's row layout is still current), and class ``class_step + 1``'s g
+    and h rows moved into the class-0 pair, where the one grower of the
+    class loop reads them (``ptrainer.class_trees``), by a swap with that
+    pair; after the last class the pairs rotate back to their own classes
+    (``_class_step_band``).  All K planes come from one score snapshot, so
+    a class's pair may move once its tree is grown."""
     ntot = p.shape[1]
     v = jnp.asarray(delta, jnp.float32)
     pad = ntot - v.shape[0]
@@ -1016,14 +1060,19 @@ def score_add(p, layout: PLayout, delta, k: int = 0, *, num_rows,
     aux = jnp.concatenate([v[None, :], jnp.zeros((7, ntot), jnp.float32)], axis=0)
     nblk = (int(num_rows) + BLK - 1) // BLK
     band0, bandn = layout.WPAD, layout.BAND
-    kern = functools.partial(
-        _score_band_kernel, band0=band0, bandn=bandn, nblk=nblk,
-        score_off=layout.SCORE + k - band0,
-    )
+    dynamic = class_step is not None
+    if dynamic:
+        update = functools.partial(_class_step_band, lay=layout)
+        args = (jnp.reshape(jnp.asarray(class_step, jnp.int32), (1,)), aux, p)
+    else:
+        update = functools.partial(_add_score_row, off=layout.SCORE + k - band0)
+        args = (aux, p)
+    kern = functools.partial(_score_band_kernel, band0=band0, bandn=bandn, nblk=nblk,
+                             update=update, dynamic=dynamic)
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0,
+            num_scalar_prefetch=int(dynamic),
             grid=(1,),
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),  # aux
@@ -1039,10 +1088,10 @@ def score_add(p, layout: PLayout, delta, k: int = 0, *, num_rows,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(p.shape, jnp.int32),
-        input_output_aliases={1: 0},
+        input_output_aliases={len(args) - 1: 0},
         interpret=interpret,
         name="score_add",
-    )(aux, p)
+    )(*args)
 
 
 # ======================================================================

@@ -3,10 +3,12 @@ spans): the names the chunk programs give themselves, the join from a compiled
 module's text to them, and what all of it costs with tracing off (nothing)."""
 
 import ast
+import contextlib
 import json
 import os
 import pathlib
 import re
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -102,7 +104,15 @@ def test_chunk_program_carries_every_phase_word(lowered, phase):
     `bundle_expand` is a scope of a program that streams EFB bundles alone,
     keyed with the phase it sits in: the root's search, a level's, the tail's.
     `rank_grad` is a scope of a program that trains a query-grouped objective
-    alone."""
+    alone, `multi_update` of one that trains K > 1 trees an iteration, inside
+    `update_root_hist`."""
+    if phase == "multi_update":
+        assert not any("multi_update" in n for n in _loc_names(lowered["binary"]))
+        names = _loc_names(lowered["multiclass"])
+        assert phase in {phase_of(n) for n in names}
+        assert any("/update_root_hist/multi_update/" in n for n in names)
+        assert ENCLOSING[phase] == "update_root_hist"
+        return
     if phase == "rank_grad":
         assert not any("rank_grad" in n for n in _loc_names(lowered["binary"]))
         assert phase in {phase_of(n) for n in _loc_names(lowered["ranked"])}
@@ -233,7 +243,7 @@ def test_parser_on_text_without_a_module():
 
 
 def test_vocabulary_is_flat_and_enclosing_is_inside_it():
-    assert len(set(PHASES)) == len(PHASES) == 12
+    assert len(set(PHASES)) == len(PHASES) == 13
     # an inner phase is a word, or `bundle_expand` keyed with the word around it
     assert all(outer in PHASES and all(w in PHASES for w in inner.split("/"))
                and inner.split("/")[1:] in ([], ["bundle_expand"])
@@ -323,8 +333,8 @@ def tracing_off(monkeypatch):
     tracer.path = None
 
 
-@pytest.fixture
-def counted(monkeypatch):
+@contextlib.contextmanager
+def _counting():
     """Counts of the calls the instrumentation may only make while tracing."""
     calls = {"annotation": 0, "block": 0, "phase_map": 0}
     real_ann, real_block, real_map = (jax.profiler.TraceAnnotation, jax.block_until_ready,
@@ -342,10 +352,16 @@ def counted(monkeypatch):
         calls["phase_map"] += 1
         return real_map(self)
 
-    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
-    monkeypatch.setattr(jax, "block_until_ready", block)
-    monkeypatch.setattr(JitWatch, "phase_map", phase_map)
-    return calls
+    with mock.patch.object(jax.profiler, "TraceAnnotation", annotation), \
+            mock.patch.object(jax, "block_until_ready", block), \
+            mock.patch.object(JitWatch, "phase_map", phase_map):
+        yield calls
+
+
+@pytest.fixture
+def counted():
+    with _counting() as calls:
+        yield calls
 
 
 def _booster(X, y, learner="serial"):
@@ -482,21 +498,22 @@ def test_shard_pack_is_a_stage_with_its_shards(fresh_stages):
     assert "pack_matrix" not in [s["name"] for s in fresh_stages]
 
 
-@pytest.fixture
-def traced_chunks(tmp_path, monkeypatch, counted):
-    """Two fused chunks of two iterations with the JSONL sink on."""
-    monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
-    X, y, _ = _toy()
-    bst = _booster(X, y)
-    path = str(tmp_path / "trace.jsonl")
-    tracer.configure(path)
-    try:
-        for _ in range(2):
-            bst.boosting.train_iters_partitioned(2, is_eval=False)
-        n_maps = tracer.write_program_maps(modules={"jit_prog"})
-    finally:
-        tracer.close()
-        tracer.path = None
+@pytest.fixture(scope="module")
+def traced_chunks(tmp_path_factory):
+    """Two fused chunks of two iterations with the JSONL sink on, made once a
+    module (the tests that take them only read them)."""
+    path = str(tmp_path_factory.mktemp("traced_chunks") / "trace.jsonl")
+    with mock.patch.dict(os.environ, LIGHTGBM_TPU_PGROW="force"), _counting() as counted:
+        X, y, _ = _toy()
+        bst = _booster(X, y)
+        tracer.configure(path)
+        try:
+            for _ in range(2):
+                bst.boosting.train_iters_partitioned(2, is_eval=False)
+            n_maps = tracer.write_program_maps(modules={"jit_prog"})
+        finally:
+            tracer.close()
+            tracer.path = None
     with open(path) as f:
         recs = [json.loads(line) for line in f]
     return bst, recs, n_maps, dict(counted)
@@ -547,10 +564,42 @@ def test_trees_from_records_carries_the_stream_counts(traced_chunks):
         assert t["levels"] <= t["level_segments"] <= 2 * (pt.params.num_leaves - 1) * t["levels"]
 
 
+_TRACED_TREES = {}
+
+
+def _traced_trees_span(tmp_path, objective, rows, loop):
+    """(the `trees_from_records` span of one chunk of two iterations, the
+    trainer's K) for this case, trained once a worker: the tests that read
+    a case's span share it."""
+    from lightgbm_tpu.ops import pgrow
+
+    key = (objective, rows, loop)
+    if key not in _TRACED_TREES:
+        X, yb, ym = _toy(rows)
+        params = {"objective": objective, "num_leaves": 15, "min_data_in_leaf": 2, "verbose": -1}
+        if objective == "multiclass":
+            params["num_class"] = 3
+        with mock.patch.dict(os.environ, LIGHTGBM_TPU_PGROW="force"), \
+                mock.patch.object(pgrow, "SCAN_BATCH_BYTES", 1 if loop else pgrow.SCAN_BATCH_BYTES):
+            bst = lgb.Booster(params=params, train_set=lgb.Dataset(
+                X, label=ym if objective == "multiclass" else yb, params=params))
+            path = str(tmp_path / "trace.jsonl")
+            tracer.configure(path)
+            try:
+                bst.boosting.train_iters_partitioned(2, is_eval=False)
+            finally:
+                tracer.close()
+                tracer.path = None
+        with open(path) as f:
+            (t,) = [r for r in map(json.loads, f)
+                    if r["ev"] == "span" and r["name"] == "trees_from_records"]
+        _TRACED_TREES[key] = (t, bst.boosting.ptrainer.K)
+    return _TRACED_TREES[key]
+
+
 @pytest.mark.parametrize("objective,rows,loop", [
     ("binary", 300, False), ("binary", 310, True), ("multiclass", 320, False)])
-def test_scan_slots_counts_what_the_split_search_visited(tmp_path, monkeypatch, objective, rows,
-                                                         loop):
+def test_scan_slots_counts_what_the_split_search_visited(tmp_path, objective, rows, loop):
     """`scan_slots` (PR 34), beside `level_segments` on the same span: all
     `level_slots` of every level where the search has no loop (any shape up to
     32 columns), whole batches of `scan_batch` slots up to the level's active
@@ -560,29 +609,11 @@ def test_scan_slots_counts_what_the_split_search_visited(tmp_path, monkeypatch, 
     traced."""
     from lightgbm_tpu.ops import pgrow
 
-    monkeypatch.setenv("LIGHTGBM_TPU_PGROW", "force")
-    if loop:
-        monkeypatch.setattr(pgrow, "SCAN_BATCH_BYTES", 1)
-    X, yb, ym = _toy(rows)
-    params = {"objective": objective, "num_leaves": 15, "min_data_in_leaf": 2, "verbose": -1}
-    if objective == "multiclass":
-        params["num_class"] = 3
-    bst = lgb.Booster(params=params, train_set=lgb.Dataset(
-        X, label=ym if objective == "multiclass" else yb, params=params))
-    pt = bst.boosting.ptrainer
-    path = str(tmp_path / "trace.jsonl")
-    tracer.configure(path)
-    try:
-        bst.boosting.train_iters_partitioned(2, is_eval=False)
-    finally:
-        tracer.close()
-        tracer.path = None
-    with open(path) as f:
-        (t,) = [r for r in map(json.loads, f)
-                if r["ev"] == "span" and r["name"] == "trees_from_records"]
+    t, K = _traced_trees_span(tmp_path, objective, rows, loop)
     smax = pgrow.level_slots(15)
-    sb = pgrow.scan_batch(15, t["hist_cells"])
-    assert t["trees"] == 2 * pt.K and t["levels"] >= 3 * t["trees"]
+    with mock.patch.object(pgrow, "SCAN_BATCH_BYTES", 1 if loop else pgrow.SCAN_BATCH_BYTES):
+        sb = pgrow.scan_batch(15, t["hist_cells"])
+    assert t["trees"] == 2 * K and t["levels"] >= 3 * t["trees"]
     if loop:
         assert sb == 4 < smax
         assert t["level_segments"] <= t["scan_slots"] <= t["level_segments"] + t["levels"] * (sb - 1)
@@ -590,6 +621,19 @@ def test_scan_slots_counts_what_the_split_search_visited(tmp_path, monkeypatch, 
     else:
         assert sb == smax == 16
         assert t["scan_slots"] == t["levels"] * smax
+
+
+@pytest.mark.parametrize("objective,rows,loop", [("binary", 300, False),
+                                                 ("multiclass", 320, False)])
+def test_the_gradient_pass_counts_its_classes(tmp_path, objective, rows, loop):
+    """`classes` (K, the trees an iteration) and `update_value_rows` (the value
+    rows the gradient pass's histogram dots carry, padded to the sublane tile:
+    8 at K = 1, 6K + 1 = 19 -> 24 at K = 3), beside `trees`, on the span the
+    `multi_update` rooflines read (benchmarks/harness/multi_ops.py)."""
+    t, K = _traced_trees_span(tmp_path, objective, rows, loop)
+    assert t["classes"] == K == (3 if objective == "multiclass" else 1)
+    assert t["update_value_rows"] == (24 if K == 3 else 8)
+    assert t["trees"] == 2 * K
 
 
 def test_serial_trees_from_records_reduce_nothing(traced_chunks):

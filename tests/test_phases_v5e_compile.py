@@ -181,13 +181,18 @@ def test_module_and_matrix(phase_map):
 
 
 @pytest.mark.parametrize("phase", [p for p in PHASES
-                                   if p not in ("canon_reorder", "sample", "score_add",
-                                                "bundle_expand", "rank_grad")])
-def test_the_compiler_keeps_the_phase(phase_map, phase):
-    """Every phase the cells' program runs (it draws no sample, lands no
-    per-class delta, streams no bundle and, since PR 30, reorders nothing at a
-    tree's start) still
-    owns instructions after XLA's passes."""
+                                   if p not in ("canon_reorder", "sample", "bundle_expand",
+                                                "rank_grad")])
+def test_the_compiler_keeps_the_phase(request, phase_map, phase):
+    """Every phase the cells' programs run (they draw no sample and, since PR
+    30, reorder nothing at a tree's start; a bundle's expansion is keyed with
+    the phase around it) still owns instructions after XLA's passes: in the
+    Higgs program, and the two that only K > 1 opens (a class's delta and the
+    K-class gradient pass) in the Covertype one."""
+    if phase in ("score_add", "multi_update"):
+        pm = parse_hlo_phases(request.getfixturevalue("covtype_compiled")[0])
+        assert phase in pm["ops"].values()
+        return
     assert phase in phase_map["ops"].values()
 
 
@@ -845,3 +850,89 @@ def test_mslr_program_moves_only_row_vectors(mslr_compiled):
     assert "rank_grad" in pm["ops"].values()
     for kernel in ("update_and_root_hist", "level_stream", "split_stream", "score_add"):
         assert re.findall(rf'%({kernel}(?:\.\d+)?) = .*custom_call_target="tpu_custom_call"', text)
+
+
+# -- the multiclass configuration (benchmarks/configs/covtype.json) ----------
+COV_ROWS, COV_CLASSES = 4_648_096, 7
+COV_MATRIX = f"s32[40,{COV_ROWS + 1024}]"  # 12 columns (10 + 2 EFB bundles): 8 word rows + a 32-row band
+
+
+def _covtype_trainer(small_rows=8192):
+    """A K = 7 fused trainer on a small table of the cell's shape (10 numeric
+    columns, exactly one of 4 and one of 40 one-hot columns a row, which EFB
+    bundles into 2), then told the cell's row count."""
+    import lightgbm_tpu as lgb
+
+    params = dict(PARAMS, objective="multiclass", num_class=COV_CLASSES)
+    rng = np.random.RandomState(7)
+    X = np.zeros((small_rows, 54))
+    X[:, :10] = np.round(rng.randn(small_rows, 10) * 200)
+    X[np.arange(small_rows), 10 + rng.randint(0, 4, small_rows)] = 1.0
+    X[np.arange(small_rows), 14 + rng.randint(0, 40, small_rows)] = 1.0
+    y = rng.randint(0, COV_CLASSES, small_rows).astype(float)
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y, params=dict(params)))
+    pt = bst.boosting.ptrainer
+    assert pt.K == COV_CLASSES and pt.params.num_cols == 12 and pt.p.shape[0] == 40
+    pt.num_rows = COV_ROWS
+    pt.params = pt.params._replace(num_rows=COV_ROWS)
+    pt.interpret = False
+    return pt
+
+
+@pytest.fixture(scope="module")
+def covtype_compiled(one_chip):
+    """The serial K = 7 chunk program at the cell's shape, 4,648,096 rows x 54
+    columns bundled to 12, compiled for the described chip: (text, argument
+    bytes, temporary bytes)."""
+    with _compiling_for_a_described_chip():
+        return _compiled_chunk_program(_covtype_trainer(), one_chip, COV_ROWS, 54)
+
+
+def _kernel_sites(text, kernel):
+    return re.findall(rf'%({kernel}(?:\.\d+)?) = .*custom_call_target="tpu_custom_call"', text)
+
+
+@pytest.mark.parametrize("kernel,k1", [("level_stream", "level_stream"),
+                                       ("split_stream", "split_stream"),
+                                       ("update_multi_and_hists", "update_and_root_hist")])
+def test_the_seven_class_program_holds_one_grower(covtype_compiled, compiled_text, kernel, k1):
+    """The K trees of an iteration are ONE traced grower in a loop over the
+    classes (``ptrainer.class_trees``): the K = 7 program has as many call
+    sites of each streaming kernel as the K = 1 program (the parent unrolled
+    the loop, 7 of each), and one gradient pass where K = 1 has its own."""
+    text = covtype_compiled[0]
+    assert len(_kernel_sites(text, kernel)) == len(_kernel_sites(compiled_text, k1)) >= 1
+    pm = parse_hlo_phases(text)
+    assert {pm["ops"][c] for c in _kernel_sites(text, "update_multi_and_hists")} == {"multi_update"}
+    # the class step: one band pass a class tree, in the loop, under its own word
+    steps = [c for c in _kernel_sites(text, "score_add") if pm["ops"][c] == "score_add"]
+    assert len(steps) == 1
+
+
+def test_the_seven_class_program_moves_no_matrix(covtype_compiled):
+    """The carry contract at K = 7: the matrix goes loop carry ->
+    aliased kernel -> loop carry through the class loop too, no copy of it and
+    nothing that gathers, scatters, sorts or transposes an array of its size,
+    and one conditional (the replay's); the epilogue puts the 7 score planes
+    back in row order with ONE sort of the planes end to end, no
+    scatter."""
+    text = covtype_compiled[0]
+    pm = parse_hlo_phases(text)
+    assert pm["matrix"] == COV_MATRIX and pm["matrix_copies"] == []
+    assert re.findall(rf" = s32\[40,{COV_ROWS + 1024}\]\S* copy\(", text) == []
+    assert _moves_of_matrix_size(text, 40 * COV_ROWS) == []
+    assert len(re.findall(r" conditional\(", text)) == 1
+    n = COV_CLASSES * COV_ROWS
+    assert _epilogue_moves(text, COV_ROWS) == ([], [f"(s32[{n}], f32[{n}])"])
+
+
+def test_what_seven_class_training_holds(covtype_compiled):
+    """The program's arguments and temporaries at the cell's shape, pinned as
+    the other configurations' are: the 744 MB matrix (40 channel rows) and
+    the temporaries, 897,340,928 bytes in all (compiled for the v5e).
+    benchmarks/configs/covtype.json reckons the run's memory_peak_bytes, set-up's
+    packing included, against the 12.5% floor; PERF.md section 4 has what the
+    chip read.  A change that moves the number says so in PERF.md."""
+    _, args, temps = covtype_compiled
+    assert 40 * (COV_ROWS + 1024) * 4 <= args < 40 * (COV_ROWS + 1024) * 4 + 2 ** 20
+    assert abs(args + temps - 897_340_928) < 2 ** 20
